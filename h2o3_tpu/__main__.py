@@ -120,6 +120,14 @@ def main(argv=None) -> int:
     L.init(dir=args.log_dir or args.ice_root)
     logger = L.get_logger("launcher")
 
+    from h2o3_tpu.util import compile_cache, telemetry
+
+    # before the first jit: a node that owns a chip must not recompile the
+    # training-block programs on every start, and /3/Metrics must count
+    # every XLA compile from the first one on
+    logger.info("compile cache: %s", compile_cache.configure() or "off")
+    telemetry.install_jax_compile_listener()
+
     if args.coordinator:
         # multi-host rendezvous BEFORE any backend use: after this, every
         # process sees the pod's full device set and default_mesh() spans

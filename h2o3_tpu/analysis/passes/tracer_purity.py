@@ -27,7 +27,7 @@ RULES = {
 #: call names whose first positional argument is traced
 TRACING_CALLS = {
     "jax.jit", "jit", "jax.pmap", "pmap", "shard_map",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 }
 
 #: attribute/bare suffixes whose first argument is traced (methods too)

@@ -250,13 +250,24 @@ def register_all(r: RequestServer, server: H2OServer) -> None:
         (h2o3_tpu/cluster/), the single-node shape otherwise."""
         import jax
 
-        from h2o3_tpu import cluster
+        from h2o3_tpu import cluster, native
         from h2o3_tpu.util import telemetry
 
-        try:
-            devices = [str(d) for d in jax.devices()]
-        except Exception:
-            devices = []
+        # a node that cannot reach its devices must say so (5xx), not
+        # answer as a healthy node with none
+        devs = jax.devices()
+        local = {
+            "num_cpus": os.cpu_count(),
+            "devices": [str(d) for d in devs],
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            # bytes_in_use / peak_bytes_in_use / bytes_limit of the first
+            # device, where the backend reports them (the CPU does not)
+            "device_memory": devs[0].memory_stats(),
+            "native": native.available(),
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+        }
         out = {
             "version": __version__,
             "cloud_name": server.name,
@@ -271,8 +282,7 @@ def register_all(r: RequestServer, server: H2OServer) -> None:
                 {
                     "h2o": f"127.0.0.1:{server.port}",
                     "healthy": True,
-                    "num_cpus": os.cpu_count(),
-                    "devices": devices,
+                    **local,
                 }
             ],
         }
@@ -281,8 +291,7 @@ def register_all(r: RequestServer, server: H2OServer) -> None:
             nodes = c.member_schemas()
             for nd in nodes:
                 if nd["name"] == c.info.name:  # only the local node can
-                    nd["devices"] = devices    # name its own devices
-                    nd["num_cpus"] = os.cpu_count()
+                    nd.update(local)           # name its own devices
             out.update({
                 "cloud_name": c.cloud_name,
                 "node_name": c.info.name,

@@ -16,6 +16,7 @@ cluster tests and benches pay milliseconds, not a backend init, per node.
 from __future__ import annotations
 
 import argparse
+import os
 import signal
 import sys
 
@@ -39,6 +40,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    # several of these run beside their harness on one host, and a chip
+    # belongs to one process: a shard a peer executes here runs on the CPU
+    # backend, never on an accelerator the harness (or another peer) holds
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from h2o3_tpu.cluster.membership import CloudJoinError, boot_node
 
     try:

@@ -40,12 +40,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # JAX >= 0.6 top-level API, older fallback
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from h2o3_tpu.frame.devcache import (
     DEVCACHE,

@@ -8,8 +8,12 @@ equivalents, and the runtime around the JAX compute path is native):
   * ``native/codecs.cpp`` — chunk compression codecs (water/fvec/C*Chunk)
     + LSD radix argsort (water/rapids/RadixOrder.java analogue)
 
-Everything here degrades gracefully: if the shared library cannot be built
-(no compiler) or H2O3_TPU_NATIVE=0, callers use the numpy fallbacks.
+The library is built on first use from ``native/csv.cpp``,
+``native/codecs.cpp`` and ``native/Makefile`` — nothing else, and the ``.so``
+is git-ignored, so a clean checkout always builds. If the build fails (no
+compiler) callers use the numpy fallbacks, and the failure is logged once at
+WARNING with the compiler's output; ``available()`` says which one a process
+got. H2O3_TPU_NATIVE=0 selects the fallbacks on purpose.
 """
 
 from __future__ import annotations
@@ -22,6 +26,8 @@ from typing import Optional
 
 import numpy as np
 
+from h2o3_tpu.util.log import get_logger
+
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _LIB_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libh2o3native.so"))
 
@@ -31,14 +37,24 @@ _tried = False
 
 
 def _build() -> bool:
+    """``make`` the library. ``get_lib`` tries once per process, under
+    ``_lock``, so a failure is logged once."""
     try:
         out = subprocess.run(
             ["make", "-C", os.path.abspath(_NATIVE_DIR)],
             capture_output=True, text=True, timeout=120,
         )
-        return out.returncode == 0 and os.path.exists(_LIB_PATH)
-    except Exception:
+    except (OSError, subprocess.TimeoutExpired) as e:
+        get_logger("native").warning(
+            "native build did not run (%s: %s); using the numpy fallbacks",
+            type(e).__name__, e)
         return False
+    if out.returncode != 0 or not os.path.exists(_LIB_PATH):
+        get_logger("native").warning(
+            "native build failed (make exit %d); using the numpy "
+            "fallbacks:\n%s", out.returncode, (out.stderr or out.stdout)[-4000:])
+        return False
+    return True
 
 
 def _stale() -> bool:
@@ -71,7 +87,10 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
+        except OSError as e:
+            get_logger("native").warning(
+                "native library %s did not load (%s); using the numpy "
+                "fallbacks", _LIB_PATH, e)
             return None
         lib.h2o3_count_rows.restype = ctypes.c_int64
         lib.h2o3_count_rows.argtypes = [ctypes.c_char_p, ctypes.c_int64]

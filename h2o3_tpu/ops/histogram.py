@@ -33,12 +33,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from h2o3_tpu.parallel.mesh import DATA_AXIS
 from h2o3_tpu.util import telemetry
@@ -60,7 +56,7 @@ _DEFAULT_NODE_BUCKETS = (8, 64, 512)
 PLAN_CACHE = telemetry.counter(
     "hist_plan_cache_total",
     "histogram level-plan lookups against the padded-bucket jit cache",
-    labels=("result",),
+    labels=("impl", "result"),
 )
 
 _PLAN_LOCK = threading.Lock()
@@ -95,15 +91,17 @@ def _shape_sig(arrays) -> Tuple:
     )
 
 
-def _note_plan(key: Tuple) -> None:
+def _note_plan(key: Tuple, impl: str) -> None:
     """Meter a plan-cache lookup: ``miss`` the first time a jit cache key
     is seen by this process, ``hit`` after — the bench asserts warm tree
-    levels are all hits (compile-free) instead of inferring it from walls."""
+    levels are all hits (compile-free) instead of inferring it from walls.
+    ``impl`` names the implementation the plan was traced with, so a run
+    can prove which one it used."""
     with _PLAN_LOCK:
         seen = key in _PLAN_KEYS
         if not seen:
             _PLAN_KEYS.add(key)
-    PLAN_CACHE.inc(result="hit" if seen else "miss")
+    PLAN_CACHE.inc(impl=impl, result="hit" if seen else "miss")
 
 
 # ---------------------------------------------------------------------------
@@ -308,7 +306,8 @@ def node_totals_sharded(nodes, g, h, n_nodes: int, mesh=None, rw=None):
     traced shape serves every level in a bucket; node ids never reach the
     pad columns, so slicing the real rows back out is bit-identical."""
     k_pad = pad_nodes(n_nodes)
-    _note_plan(("totals", k_pad, _shape_sig((nodes, g, h, rw)), mesh))
+    _note_plan(
+        ("totals", k_pad, _shape_sig((nodes, g, h, rw)), mesh), "scatter")
     if mesh is None:
         out = _shard_node_totals(nodes, g, h, k_pad, rw=rw)
         return out[:n_nodes] if k_pad != n_nodes else out
@@ -405,7 +404,7 @@ def build_histogram_sharded(
     _note_plan((
         "hist", k_pad, n_bins1, _shape_sig((bins, nodes, g, h, bins_fm, rw)),
         mesh, impl, dtype, kernel,
-    ))
+    ), impl)
     out = _build_histogram_jit(
         bins, nodes, g, h, bins_fm, rw, k_pad, n_bins1, mesh, impl, dtype,
         kernel,
@@ -443,27 +442,16 @@ def _build_histogram_jit(
         )
         return jax.lax.psum(part, DATA_AXIS)
 
-    sm_kw = {}
-    if impl == "pallas" and jax.default_backend() != "tpu":
-        # interpreter-mode pallas lowers VMEM scratch to plain arrays
-        # whose varying-axis metadata can't match the shard-varying
-        # values written into them; the check only exists to validate
-        # collective placement, which the real-TPU path still enforces.
-        # (the kwarg is check_vma on jax.shard_map but check_rep on the
-        # jax.experimental fallback — key off the actual signature)
-        import inspect
-
-        params = inspect.signature(_shard_map).parameters
-        if "check_vma" in params:
-            sm_kw["check_vma"] = False
-        elif "check_rep" in params:
-            sm_kw["check_rep"] = False
-
+    # interpreter-mode pallas lowers VMEM scratch to plain arrays whose
+    # varying-axis metadata can't match the shard-varying values written
+    # into them; the check only exists to validate collective placement,
+    # which the real-TPU path still enforces
+    interpreted = impl == "pallas" and jax.default_backend() != "tpu"
     return _shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS))
         + tuple(spec for _, _, spec in extras),
         out_specs=P(),
-        **sm_kw,
+        check_vma=not interpreted,
     )(bins, nodes, g, h, *[a for _, a, _ in extras])

@@ -47,20 +47,10 @@ _C = 4
 
 
 def _out_sds(shape, dtype, vma):
-    """ShapeDtypeStruct carrying the shard-varying axes when this jax
-    version tracks them (the ``vma`` kwarg and ``lax.pvary`` arrived
-    together); older versions have no VMA machinery to inform."""
-    try:
-        return jax.ShapeDtypeStruct(
-            shape, dtype, vma=frozenset(vma) if vma else None)
-    except TypeError:
-        return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _pvary(x, vma):
-    if vma and hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, tuple(vma))
-    return x
+    """Kernel output shape carrying the shard-varying axes ``vma`` (empty
+    outside ``shard_map``)."""
+    return jax.ShapeDtypeStruct(
+        shape, dtype, vma=frozenset(vma) if vma else None)
 
 #: node-matmul kernel applies while K*_C <= this (VMEM budget for the
 #: [Fb*B1, K*C] accumulator + operands; ~16 MB/core on v5e)
@@ -198,7 +188,8 @@ def _build_histogram_nodematmul(
 
     # resident constant: one-hot sublane b (within a feature) covers bin b
     jmod = jnp.asarray(np.arange(n_bins1)[:, None], dtype=jnp.float32)
-    jmod = _pvary(jmod, vma)
+    if vma:
+        jmod = jax.lax.pcast(jmod, tuple(vma), to="varying")
 
     out = pl.pallas_call(
         partial(_nm_kernel, n_feat_b=fb, n_bins1=n_bins1, n_nodes=n_nodes),

@@ -40,7 +40,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from h2o3_tpu.parallel.mesh import DATA_AXIS, default_mesh, pad_rows
@@ -133,7 +133,7 @@ def _sample_sort_program(hi, lo, idx, *, mesh_size: int, n_samples: int):
         shard_fn, mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(DATA_AXIS, None),) * 3,
-        check_rep=False,
+        check_vma=False,
     )(hi, lo, idx)
 
 
@@ -220,7 +220,7 @@ def _searchsorted_program(thi, tlo, qhi, qlo, *, mesh_size: int,
         shard_fn, mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=P(DATA_AXIS),
-        check_rep=False,
+        check_vma=False,
     )(qhi, qlo)
 
 
@@ -241,7 +241,7 @@ def _searchsorted_both_program(thi, tlo, qhi, qlo, *, mesh_size: int):
         shard_fn, mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(DATA_AXIS), P(DATA_AXIS)),
-        check_rep=False,
+        check_vma=False,
     )(qhi, qlo)
 
 
@@ -314,7 +314,7 @@ def _segment_agg_program(codes, vals, valid, *, mesh_size: int,
         shard_fn, mesh=mesh,
         in_specs=(P(DATA_AXIS),) * 3,
         out_specs=(P(),) * 5,
-        check_rep=False,
+        check_vma=False,
     )(codes, vals, valid)
 
 

@@ -49,7 +49,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from h2o3_tpu.cluster import frames as _frames
 from h2o3_tpu.cluster import rpc as _rpc

@@ -28,7 +28,7 @@ Pipeline per candidate region:
 5. **Dispatch**: referenced columns resolve through the PR 3 devcache as
    float64 ``FrameTable``s keyed on per-Column version stamps (an unmutated
    frame re-uploads nothing), merge into one table, and run under
-   ``jax.experimental.enable_x64`` so device arithmetic is true float64.
+   ``jax.enable_x64`` so device arithmetic is true float64.
    Trailing reducers run as a host epilogue through their registered prim.
 
 Anything the lowering cannot prove bit-identical — string/categorical
@@ -52,7 +52,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from h2o3_tpu.compute.mapreduce import (
     FrameTable,

@@ -19,18 +19,14 @@ if "--xla_force_host_platform_device_count" not in flags:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # jax < 0.5.3 has no jax_num_cpu_devices; the XLA_FLAGS
-    # --xla_force_host_platform_device_count set above covers it
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
 # NOTE: the persistent compilation cache is deliberately NOT enabled for
 # the CPU test tier: XLA:CPU AOT executables serialized here carry machine
 # feature sets (prefer-no-scatter et al.) that mismatch the host at load
 # time and intermittently SIGSEGV in compilation_cache.get/put_executable.
-# The TPU bench keeps its own cache (bench.py) where entries are TPU AOT.
+# Processes that may own a chip get their cache from
+# h2o3_tpu/util/compile_cache.py, which leaves CPU-pinned processes alone.
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

@@ -244,7 +244,7 @@ def test_group_rep_device_parity_affine():
     rep = codecs.group_rep(payloads)
     assert rep[0] == "affine"
     _, codes, off, scale, sent = (rep[0], rep[1], rep[2], rep[3], rep[4])
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         x = jnp.asarray(off) + jnp.asarray(codes).astype(
             jnp.float64) * jnp.asarray(scale)
         dev = np.asarray(

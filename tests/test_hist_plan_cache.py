@@ -6,7 +6,7 @@ its node dimension up to a bucket ladder (default 8/64/512, override
 level that lands in the same bucket; the real node rows are sliced back
 out and the result is BIT-identical to the unpadded build, because the
 scatter-add accumulation order does not depend on the destination
-capacity. ``hist_plan_cache_total{result}`` meters lookups against the
+capacity. ``hist_plan_cache_total{impl,result}`` meters lookups against the
 padded-shape plan cache — a warm fit must record zero misses.
 """
 
@@ -101,7 +101,10 @@ def _plan(result):
     from h2o3_tpu.util import telemetry
 
     c = telemetry.REGISTRY.get("hist_plan_cache_total")
-    return 0.0 if c is None else c.value(result=result)
+    if c is None:
+        return 0.0
+    return sum(s["value"] for s in c.snapshot()["series"]
+               if s["labels"]["result"] == result)
 
 
 def test_one_plan_per_bucket(rng):

@@ -33,7 +33,7 @@ distributed_initialize(
 import numpy as np
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 devs = jax.devices()
 assert len(devs) == 8, f"global mesh should see 8 devices, got {{len(devs)}}"
@@ -56,7 +56,7 @@ x = jax.make_array_from_single_device_arrays(
     global_shape, NamedSharding(mesh, P("data")), arrs)
 out = jax.jit(
     shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
-              check_rep=False)
+              check_vma=False)
 )(x)
 got = float(np.asarray(jax.device_get(out))[0] if np.ndim(out) else out)
 want = float(sum(range(1, 9)))
