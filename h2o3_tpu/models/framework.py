@@ -13,6 +13,7 @@ instead of a per-row MRTask, and CV fold models are independent jit programs
 
 from __future__ import annotations
 
+import sys
 import time
 import uuid
 from dataclasses import dataclass, field as dataclass_field
@@ -159,6 +160,8 @@ class Model:
         self.cross_validation_metrics: Optional[Any] = None
         self.scoring_history: List[Dict[str, Any]] = []
         self.run_time: float = 0.0
+        #: where the fit's wall went, by its own spans (:func:`fit_profile`)
+        self.fit_profile: Dict[str, Dict[str, float]] = {}
         DKV.put(self.key, self)
 
     # -- category of the learning problem -----------------------------------
@@ -270,8 +273,9 @@ class Model:
 
     def model_performance(self, frame: Frame) -> Any:
         """Score a frame and build the right ModelMetrics (Model.score + MM builders)."""
-        frame = self._apply_preprocessors(frame)
-        return self._metrics_from_raw(frame, self._predict_raw(frame))
+        with telemetry.Span("model_performance", rows=frame.nrows):
+            frame = self._apply_preprocessors(frame)
+            return self._metrics_from_raw(frame, self._predict_raw(frame))
 
     def _metrics_from_raw(self, frame: Frame, raw: np.ndarray) -> Any:
         """ModelMetrics from an already-computed raw score over an already-
@@ -279,19 +283,20 @@ class Model:
         so the batched REST path never scores the same frame twice."""
         from h2o3_tpu.models.data_info import response_vector
 
-        y = response_vector(self.data_info, frame)
-        w = (
-            frame.col(self.params.weights_column).numeric_view()
-            if self.params.weights_column
-            else None
-        )
-        if not self.is_classifier:
-            return M.regression_metrics(y, raw, weights=w)
-        if self.nclasses == 2:
-            return M.binomial_metrics(y, raw[:, 1], weights=w)
-        return M.multinomial_metrics(
-            y.astype(np.int64), raw, self.data_info.response_domain, weights=w
-        )
+        with telemetry.Span("score_metrics", rows=frame.nrows):
+            y = response_vector(self.data_info, frame)
+            w = (
+                frame.col(self.params.weights_column).numeric_view()
+                if self.params.weights_column
+                else None
+            )
+            if not self.is_classifier:
+                return M.regression_metrics(y, raw, weights=w)
+            if self.nclasses == 2:
+                return M.binomial_metrics(y, raw[:, 1], weights=w)
+            return M.multinomial_metrics(
+                y.astype(np.int64), raw, self.data_info.response_domain, weights=w
+            )
 
     def pojo(self, lang: str = "c") -> str:
         """Standalone scoring source (hex/tree/TreeJCodeGen / water/codegen
@@ -310,6 +315,40 @@ class Model:
 
     def __repr__(self) -> str:
         return f"<{type(self).__name__} {self.key} metrics={self.training_metrics!r}>"
+
+
+def fit_profile(train_span: telemetry.Span) -> Dict[str, Dict[str, float]]:
+    """Where a fit's wall went, from its own spans in the timeline ring:
+    seconds (``s``) and count (``n``) of every kind of span under
+    ``train_span``; a kind met under ``model_performance`` is keyed
+    ``score/<kind>``, so the entry's ``tree_matrix`` and the scoring's stay
+    apart.  Empty where the ring no longer holds the fit."""
+    from h2o3_tpu.util import timeline
+
+    spans = {e["span_id"]: e for e in timeline.snapshot(timeline.CAPACITY)
+             if e.get("trace_id") == train_span.trace_id and "parent_id" in e}
+    out: Dict[str, Dict[str, float]] = {}
+    for e in spans.values():
+        scoring, up = False, e
+        while up is not None and up["span_id"] != train_span.span_id:
+            scoring = scoring or up["kind"] == "model_performance"
+            up = spans.get(up["parent_id"])
+        if up is None or e["span_id"] == train_span.span_id:
+            continue  # not under this fit (the enclosing request's spans)
+        key = e["kind"]
+        if scoring and key != "model_performance":
+            key = "score/" + key
+        slot = out.setdefault(key, {"s": 0.0, "n": 0})
+        slot["s"] = round(slot["s"] + e["duration_ms"] / 1e3, 6)
+        slot["n"] += 1
+    return out
+
+
+def _profile_text(profile: Dict[str, Dict[str, float]]) -> str:
+    """``tree_block 35.89s x4, score/apply_bins 9.52s, ...`` longest first."""
+    return ", ".join(
+        "%s %.2fs%s" % (k, v["s"], " x%d" % v["n"] if v["n"] > 1 else "")
+        for k, v in sorted(profile.items(), key=lambda kv: -kv[1]["s"]))
 
 
 class ModelBuilder:
@@ -400,6 +439,10 @@ class ModelBuilder:
         DKV.scope_enter()
         keep = [self.job.key]
         try:
+            # so the fit's spans say which of them built or loaded a program
+            # (a host-only fit does not import the backend for it)
+            if "jax" in sys.modules:
+                telemetry.install_jax_compile_listener()
             with telemetry.Span(
                 "train", algo=self.algo_name, rows=frame.nrows
             ) as span:
@@ -411,6 +454,7 @@ class ModelBuilder:
                 iters = getattr(model, "iterations", None)
                 if isinstance(iters, (int, float)):
                     span.set(iterations=int(iters))
+            model.fit_profile = fit_profile(span)
             _FIT_SECONDS.observe(model.run_time, algo=self.algo_name)
             _FITS.inc(algo=self.algo_name, outcome="ok")
             self.job.done()
@@ -425,9 +469,11 @@ class ModelBuilder:
                 from h2o3_tpu.cluster import serving as _serving
 
                 _serving.home_model(model)
+            # the phase split rides the line, so an untraced run that
+            # stalls says where
             log.info(
-                "%s train done in %.2fs -> %s", self.algo_name,
-                model.run_time, model.key,
+                "%s train done in %.2fs -> %s [%s]", self.algo_name,
+                model.run_time, model.key, _profile_text(model.fit_profile),
             )
             return model
         except BaseException as e:

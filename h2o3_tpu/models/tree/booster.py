@@ -48,6 +48,7 @@ from h2o3_tpu.ops.histogram import (
     make_bins,
 )
 from h2o3_tpu.parallel.mesh import default_mesh, row_sharding
+from h2o3_tpu.util.telemetry import Span
 
 #: boosting rounds fused into one XLA program when no monitor is active
 #: (overridable via H2O3_TPU_TREE_BLOCK); also the deadline-check cadence
@@ -357,11 +358,12 @@ def _predict_stacked(bins, feat, split_bin, default_left, is_split, leaf, max_de
         tf, tb, tdl, tsp, tlf = tree
         return carry + _tree_walk(bins, tf, tb, tdl, tsp, tlf, max_depth, n_bins1_arr), None
 
-    out, _ = jax.lax.scan(
-        one_tree,
-        jnp.zeros(bins.shape[0], jnp.float32),
-        (feat, split_bin, default_left, is_split, leaf),
-    )
+    with jax.named_scope("score_traverse"):
+        out, _ = jax.lax.scan(
+            one_tree,
+            jnp.zeros(bins.shape[0], jnp.float32),
+            (feat, split_bin, default_left, is_split, leaf),
+        )
     return out
 
 
@@ -421,40 +423,46 @@ def _build_one_tree(
 
     tf_l, tb_l, tdl_l, tsp_l, tlf_l = [], [], [], [], []
     prev_hist = prev_can = prev_left_small = prev_wl = prev_wr = None
+    # every level and phase carries a named scope (metadata only, no
+    # instruction): the profiler's device operations are summed by them
     for d in range(D + 1):
         K = 2**d
         lo = K - 1
-        local = pos - lo
-        in_lvl = (local >= 0) & (local < K)
-        hist_nodes = jnp.where(in_lvl & sample, local, -1).astype(jnp.int32)
+        lvl = f"L{d:02d}"
+        with jax.named_scope(f"{lvl}/hist_nodes"):
+            local = pos - lo
+            in_lvl = (local >= 0) & (local < K)
+            hist_nodes = jnp.where(in_lvl & sample, local, -1).astype(jnp.int32)
         if d == D:
-            if subtract and prev_wl is not None:  # D=0 has no parent split
-                # terminal leaves straight from the parent split's child
-                # stats: child(2k+0) = wl[k], child(2k+1) = wr[k] — the
-                # level-(D-1) cumsum stats cover exactly the rows each
-                # child receives, so no totals pass is needed at all
-                raw_leaf = jnp.stack([prev_wl, prev_wr], axis=1).reshape(K)
-            else:
-                # terminal level: no split is possible, so the full
-                # [K, F, B+1, 3] histogram (the widest of the tree) is pure
-                # waste — per-node (Σg, Σh) totals give the leaf values
-                from h2o3_tpu.ops.histogram import node_totals_sharded
+            with jax.named_scope("leaf"):
+                if subtract and prev_wl is not None:  # D=0 has no parent split
+                    # terminal leaves straight from the parent split's child
+                    # stats: child(2k+0) = wl[k], child(2k+1) = wr[k] — the
+                    # level-(D-1) cumsum stats cover exactly the rows each
+                    # child receives, so no totals pass is needed at all
+                    raw_leaf = jnp.stack([prev_wl, prev_wr], axis=1).reshape(K)
+                else:
+                    # terminal level: no split is possible, so the full
+                    # [K, F, B+1, 3] histogram (the widest of the tree) is
+                    # pure waste — per-node (Σg, Σh) totals give the leaf
+                    # values
+                    from h2o3_tpu.ops.histogram import node_totals_sharded
 
-                tot = node_totals_sharded(
-                    hist_nodes, g, h, K, mesh=mesh, rw=rw)
-                G, H = tot[:, 0], tot[:, 1]
-                t = jnp.sign(G) * jnp.maximum(
-                    jnp.abs(G) - jnp.float32(p.reg_alpha), 0.0
-                )
-                raw_leaf = -t / jnp.maximum(
-                    H + jnp.float32(p.reg_lambda), 1e-12)
-            if mono:
-                raw_leaf = jnp.clip(raw_leaf, b_lo, b_hi)
-            tf_l.append(jnp.zeros(K, jnp.int32))
-            tb_l.append(jnp.zeros(K, jnp.int32))
-            tdl_l.append(jnp.zeros(K, bool))
-            tsp_l.append(jnp.zeros(K, bool))
-            tlf_l.append(jnp.float32(p.learn_rate) * raw_leaf)
+                    tot = node_totals_sharded(
+                        hist_nodes, g, h, K, mesh=mesh, rw=rw)
+                    G, H = tot[:, 0], tot[:, 1]
+                    t = jnp.sign(G) * jnp.maximum(
+                        jnp.abs(G) - jnp.float32(p.reg_alpha), 0.0
+                    )
+                    raw_leaf = -t / jnp.maximum(
+                        H + jnp.float32(p.reg_lambda), 1e-12)
+                if mono:
+                    raw_leaf = jnp.clip(raw_leaf, b_lo, b_hi)
+                tf_l.append(jnp.zeros(K, jnp.int32))
+                tb_l.append(jnp.zeros(K, jnp.int32))
+                tdl_l.append(jnp.zeros(K, bool))
+                tsp_l.append(jnp.zeros(K, bool))
+                tlf_l.append(jnp.float32(p.learn_rate) * raw_leaf)
             break
         if subtract and d > 0:
             # build ONLY each parent's smaller child (one kernel slot per
@@ -463,56 +471,61 @@ def _build_one_tree(
             # half is all-zero by the in_lvl mask and their big half is
             # masked to zero by prev_can.
             Kp = K // 2
-            par = jnp.clip(local // 2, 0, Kp - 1)
-            parity = local % 2
-            small_parity = jnp.where(prev_left_small, 0, 1)  # [Kp]
-            sp_row = _sel_table(small_parity.astype(jnp.int32), par)
-            half_nodes = jnp.where(
-                in_lvl & sample & (parity == sp_row), par, -1
-            ).astype(jnp.int32)
-            hist_small = build_histogram_sharded(
-                bins, half_nodes, g, h, n_nodes=Kp, n_bins1=n_bins1,
-                mesh=mesh, bins_fm=bins_fm, rw=rw,
-            )
-            can_m = prev_can[:, None, None, None]
-            hist_big = jnp.where(can_m, prev_hist - hist_small, 0.0)
-            ls_m = prev_left_small[:, None, None, None]
-            left = jnp.where(ls_m, hist_small, hist_big)
-            right = jnp.where(ls_m, hist_big, hist_small)
-            hist = jnp.stack([left, right], axis=1).reshape(
-                K, *hist_small.shape[1:]
-            )
+            with jax.named_scope(f"{lvl}/hist_nodes"):
+                par = jnp.clip(local // 2, 0, Kp - 1)
+                parity = local % 2
+                small_parity = jnp.where(prev_left_small, 0, 1)  # [Kp]
+                sp_row = _sel_table(small_parity.astype(jnp.int32), par)
+                half_nodes = jnp.where(
+                    in_lvl & sample & (parity == sp_row), par, -1
+                ).astype(jnp.int32)
+            with jax.named_scope(f"{lvl}/hist"):
+                hist_small = build_histogram_sharded(
+                    bins, half_nodes, g, h, n_nodes=Kp, n_bins1=n_bins1,
+                    mesh=mesh, bins_fm=bins_fm, rw=rw,
+                )
+            with jax.named_scope(f"{lvl}/subtract"):
+                can_m = prev_can[:, None, None, None]
+                hist_big = jnp.where(can_m, prev_hist - hist_small, 0.0)
+                ls_m = prev_left_small[:, None, None, None]
+                left = jnp.where(ls_m, hist_small, hist_big)
+                right = jnp.where(ls_m, hist_big, hist_small)
+                hist = jnp.stack([left, right], axis=1).reshape(
+                    K, *hist_small.shape[1:]
+                )
         else:
-            hist = build_histogram_sharded(
-                bins, hist_nodes, g, h, n_nodes=K, n_bins1=n_bins1,
-                mesh=mesh, bins_fm=bins_fm, rw=rw,
+            with jax.named_scope(f"{lvl}/hist"):
+                hist = build_histogram_sharded(
+                    bins, hist_nodes, g, h, n_nodes=K, n_bins1=n_bins1,
+                    mesh=mesh, bins_fm=bins_fm, rw=rw,
+                )
+        with jax.named_scope(f"{lvl}/split"):
+            if p.mtries > 0:
+                key, sub = jax.random.split(key)
+                r = jax.random.uniform(sub, (K, F))
+                thresh = jnp.sort(r, axis=1)[:, p.mtries - 1][:, None]
+                node_feat_mask = (r <= thresh) & feat_mask[None, :]
+            else:
+                node_feat_mask = feat_mask
+            out = _split_search(
+                hist,
+                jnp.float32(p.reg_lambda),
+                jnp.float32(p.reg_alpha),
+                jnp.float32(p.gamma),
+                jnp.float32(p.learn_rate),
+                node_feat_mask,
+                min_rows=float(p.min_rows),
+                n_bins1=n_bins1,
+                constraints=constraints if mono else None,
+                node_lo=b_lo if mono else None,
+                node_hi=b_hi if mono else None,
+                child_stats=subtract,
             )
-        if p.mtries > 0:
-            key, sub = jax.random.split(key)
-            r = jax.random.uniform(sub, (K, F))
-            thresh = jnp.sort(r, axis=1)[:, p.mtries - 1][:, None]
-            node_feat_mask = (r <= thresh) & feat_mask[None, :]
-        else:
-            node_feat_mask = feat_mask
-        out = _split_search(
-            hist,
-            jnp.float32(p.reg_lambda),
-            jnp.float32(p.reg_alpha),
-            jnp.float32(p.gamma),
-            jnp.float32(p.learn_rate),
-            node_feat_mask,
-            min_rows=float(p.min_rows),
-            n_bins1=n_bins1,
-            constraints=constraints if mono else None,
-            node_lo=b_lo if mono else None,
-            node_hi=b_hi if mono else None,
-            child_stats=subtract,
-        )
-        if mono or subtract:
-            bf, bb, dl, gain, leaf, bwl, bwr, left_small = out
-        else:
-            bf, bb, dl, gain, leaf = out
-        can = (gain > max(p.min_split_improvement, 0.0)) & jnp.isfinite(gain) & (d < D)
+            if mono or subtract:
+                bf, bb, dl, gain, leaf, bwl, bwr, left_small = out
+            else:
+                bf, bb, dl, gain, leaf = out
+            can = (gain > max(p.min_split_improvement, 0.0)) & jnp.isfinite(gain) & (d < D)
         tf_l.append(bf)
         tb_l.append(bb)
         tdl_l.append(dl)
@@ -521,7 +534,7 @@ def _build_one_tree(
         if subtract:
             prev_hist, prev_can, prev_left_small = hist, can, left_small
             prev_wl, prev_wr = bwl, bwr
-        if d < D:
+        with jax.named_scope(f"{lvl}/route"):
             k = jnp.clip(local, 0, K - 1)
             f, sb, dlk, cank = _sel_tables((bf, bb, dl, can), k)
             b = _sel_cols(bins, f)
@@ -539,15 +552,16 @@ def _build_one_tree(
                 b_lo = jnp.stack([lo_left, lo_right], axis=1).reshape(2 * K)
                 b_hi = jnp.stack([hi_left, hi_right], axis=1).reshape(2 * K)
 
-    # per-level concatenation IS the heap layout: node (d, i) -> 2^d - 1 + i
-    tree = (
-        jnp.concatenate(tf_l),
-        jnp.concatenate(tb_l),
-        jnp.concatenate(tdl_l),
-        jnp.concatenate(tsp_l),
-        jnp.concatenate(tlf_l),
-    )
-    pred = _sel_table(tree[4], pos)
+    with jax.named_scope("leaf"):
+        # per-level concatenation IS the heap layout: node (d, i) -> 2^d - 1 + i
+        tree = (
+            jnp.concatenate(tf_l),
+            jnp.concatenate(tb_l),
+            jnp.concatenate(tdl_l),
+            jnp.concatenate(tsp_l),
+            jnp.concatenate(tlf_l),
+        )
+        pred = _sel_table(tree[4], pos)
     return tree, pred
 
 
@@ -575,33 +589,39 @@ def _make_block_fn(
     @partial(jax.jit, donate_argnums=(3,))
     def block_fn(bins, y, valid, margin, keys, bins_fm, w, mono):
         def one_round(margin, key_t):
-            g_all, h_all = grad_hess_device(objective, y, margin)
-            if weighted:
-                # fold row weights into (g, h): every Σg/Σh a histogram sees
-                # becomes the weighted sum (DHistogram's Σw-scaled stats)
-                g_all = g_all * w[:, None]
-                h_all = h_all * w[:, None]
-            kr, kc, kt = jax.random.split(key_t, 3)
-            active = valid
-            if p.sample_rate < 1.0:
-                active = active & (
-                    jax.random.uniform(kr, active.shape) < p.sample_rate
-                )
-            F = bins.shape[1]
-            if p.col_sample_rate_per_tree < 1.0:
-                ncols = max(1, int(round(p.col_sample_rate_per_tree * F)))
-                r = jax.random.uniform(kc, (F,))
-                thresh = jnp.sort(r)[ncols - 1]
-                feat_mask = r <= thresh
-            else:
-                feat_mask = jnp.ones((F,), bool)
+            with jax.named_scope("grad"):
+                g_all, h_all = grad_hess_device(objective, y, margin)
+                if weighted:
+                    # fold row weights into (g, h): every Σg/Σh a histogram
+                    # sees becomes the weighted sum (DHistogram's Σw-scaled
+                    # stats)
+                    g_all = g_all * w[:, None]
+                    h_all = h_all * w[:, None]
+            with jax.named_scope("sample"):
+                kr, kc, kt = jax.random.split(key_t, 3)
+                active = valid
+                if p.sample_rate < 1.0:
+                    active = active & (
+                        jax.random.uniform(kr, active.shape) < p.sample_rate
+                    )
+                F = bins.shape[1]
+                if p.col_sample_rate_per_tree < 1.0:
+                    ncols = max(1, int(round(p.col_sample_rate_per_tree * F)))
+                    r = jax.random.uniform(kc, (F,))
+                    thresh = jnp.sort(r)[ncols - 1]
+                    feat_mask = r <= thresh
+                else:
+                    feat_mask = jnp.ones((F,), bool)
 
             outs = []
             for c in range(C):
+                with jax.named_scope("grad"):
+                    g_c = g_all[:, c].astype(jnp.float32)
+                    h_c = h_all[:, c].astype(jnp.float32)
                 tree, pred = _build_one_tree(
                     bins,
-                    g_all[:, c].astype(jnp.float32),
-                    h_all[:, c].astype(jnp.float32),
+                    g_c,
+                    h_c,
                     active,
                     feat_mask,
                     jax.random.fold_in(kt, c),
@@ -613,7 +633,8 @@ def _make_block_fn(
                     subtract=subtract,
                 )
                 # margin update from this tree (full data, not just the sample)
-                margin = margin.at[:, c].add(pred)
+                with jax.named_scope("margin"):
+                    margin = margin.at[:, c].add(pred)
                 outs.append(tree)
             stacked = tuple(
                 jnp.stack([outs[c][i] for c in range(C)]) for i in range(5)
@@ -652,21 +673,24 @@ class BoostedTrees:
     def predict_margin(self, X: np.ndarray) -> np.ndarray:
         """Raw margins [N, C] from raw features (re-binned with stored edges)."""
         t0 = self.trees_per_class[0]
-        bins = jnp.asarray(apply_bins(X, t0.edges))
+        codes = apply_bins(X, t0.edges)
         cols = []
-        for c, trees in enumerate(self.trees_per_class):
-            if trees.ntrees == 0:
-                cols.append(np.full(X.shape[0], self.init_margin[c], dtype=np.float64))
-                continue
-            s = _predict_stacked(
-                bins, *trees.stacked(), max_depth=trees.max_depth,
-                n_bins1_arr=jnp.int32(trees.n_bins1),
-            )
-            s = np.asarray(jax.device_get(s), dtype=np.float64)
-            if self.average:
-                s = s / trees.ntrees
-            cols.append(self.init_margin[c] + s)
-        return np.stack(cols, axis=1)
+        # the codes' upload, every class's traversal and its read-back
+        with Span("score_traverse", rows=X.shape[0], trees=t0.ntrees):
+            bins = jnp.asarray(codes)
+            for c, trees in enumerate(self.trees_per_class):
+                if trees.ntrees == 0:
+                    cols.append(np.full(X.shape[0], self.init_margin[c], dtype=np.float64))
+                    continue
+                s = _predict_stacked(
+                    bins, *trees.stacked(), max_depth=trees.max_depth,
+                    n_bins1_arr=jnp.int32(trees.n_bins1),
+                )
+                s = np.asarray(jax.device_get(s), dtype=np.float64)
+                if self.average:
+                    s = s / trees.ntrees
+                cols.append(self.init_margin[c] + s)
+            return np.stack(cols, axis=1)
 
 
 def train_boosted(
@@ -680,7 +704,6 @@ def train_boosted(
     monitor: Optional[Callable[[int, np.ndarray], bool]] = None,
     score_interval: int = 1,
     mesh=None,
-    timings: Optional[dict] = None,
     resume_from: Optional["BoostedTrees"] = None,
     weights: Optional[np.ndarray] = None,
     offset: Optional[np.ndarray] = None,
@@ -724,21 +747,32 @@ def train_boosted(
         return _dist_hist.train_boosted_dist(
             X, objective, y, n_class_trees, init_margin, params,
             average=average, monitor=monitor,
-            score_interval=score_interval, timings=timings,
+            score_interval=score_interval,
             weights=weights, offset=offset)
 
-    import time as _time
+    if mesh is None:
+        mesh = default_mesh()
+    with Span("train_boosted", objective=objective, rows=X.shape[0],
+              nshards=mesh.devices.size):
+        return _train_boosted(
+            X, objective, y, n_class_trees, init_margin, params, average,
+            monitor, score_interval, mesh, resume_from, weights, offset,
+            monotone, cache_token, cache_frame_key)
 
+
+def _train_boosted(
+    X, objective, y, n_class_trees, init_margin, params, average, monitor,
+    score_interval, mesh, resume_from, weights, offset, monotone,
+    cache_token, cache_frame_key,
+) -> BoostedTrees:
+    """The single-host body of :func:`train_boosted`, under its span."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from h2o3_tpu.ops.histogram import _hist_impl
     from h2o3_tpu.parallel.mesh import DATA_AXIS
 
-    _t0 = _time.time()
     n, F = X.shape
     p = params
-    if mesh is None:
-        mesh = default_mesh()
     nshards = mesh.devices.size
 
     if resume_from is not None:
@@ -748,19 +782,12 @@ def train_boosted(
         if resume_from.trees_per_class[0].n_bins1 != p.nbins + 1:
             raise ValueError("checkpoint nbins mismatch")
     else:
-        edges = make_bins(X, p.nbins, seed=p.seed)
+        with Span("make_bins", nbins=p.nbins):
+            edges = make_bins(X, p.nbins, seed=p.seed)
     n_bins1 = p.nbins + 1
-    # pallas path: pad every shard to the kernel row tile so the prepared
-    # feature-major copy needs no per-level realignment
-    use_pallas = _hist_impl(None) == "pallas"
-    if use_pallas:
-        from h2o3_tpu.ops.pallas_histogram import _ROW_TILE
-
-        mult = nshards * _ROW_TILE
-    else:
-        mult = nshards
 
     def _place_bins():
+        resident.set(hit=False)
         bins_host = apply_bins(X, edges)
         padn = (-n) % mult
         if padn:
@@ -769,10 +796,9 @@ def train_boosted(
             )
         else:
             bh = bins_host
-        bins_d = jax.device_put(bh, row_sharding(mesh, 2))
         n_pad = bh.shape[0]
-        valid_d = jax.device_put(np.arange(n_pad) < n, row_sharding(mesh, 1))
-        bins_fm_d = None
+        valid_h = np.arange(n_pad) < n
+        bfm_host = None
         if use_pallas:
             from h2o3_tpu.ops.pallas_histogram import _FEAT_BLOCK
 
@@ -780,9 +806,17 @@ def train_boosted(
             Fp = F + (-F) % fb
             bfm_host = np.zeros((Fp, n_pad), dtype=np.int32)
             bfm_host[:F] = bh.T
-            bins_fm_d = jax.device_put(
-                bfm_host, NamedSharding(mesh, P(None, DATA_AXIS))
-            )
+        nbytes = bh.nbytes + valid_h.nbytes + (
+            bfm_host.nbytes if bfm_host is not None else 0)
+        with Span("bins_upload", bytes=nbytes):
+            bins_d = jax.device_put(bh, row_sharding(mesh, 2))
+            valid_d = jax.device_put(valid_h, row_sharding(mesh, 1))
+            bins_fm_d = None
+            if bfm_host is not None:
+                bins_fm_d = jax.device_put(
+                    bfm_host, NamedSharding(mesh, P(None, DATA_AXIS))
+                )
+            jax.block_until_ready((bins_d, valid_d, bins_fm_d))
         return bins_d, valid_d, bins_fm_d, n_pad
 
     # bin codes are a pure function of (X provenance, edges, padding
@@ -792,48 +826,65 @@ def train_boosted(
 
     from h2o3_tpu.frame import devcache as _devcache
 
-    edges_digest = hashlib.sha1(
-        np.ascontiguousarray(edges).tobytes()
-    ).hexdigest()
-    bins_d, valid_d, bins_fm_d, n_pad = _devcache.cached(
-        "tree_bins", cache_token, (edges_digest, p.nbins, mult), mesh,
-        _place_bins, frame_key=cache_frame_key,
-    )
+    # the span holds the whole lookup: the histogram implementation (a
+    # process's first fit imports the Pallas modules here), the padding
+    # it asks for, the edges' digest and the cache's answer
+    with Span("bins_resident", hit=True) as resident:
+        # pallas path: pad every shard to the kernel row tile so the
+        # prepared feature-major copy needs no per-level realignment
+        use_pallas = _hist_impl(None) == "pallas"
+        if use_pallas:
+            from h2o3_tpu.ops.pallas_histogram import _ROW_TILE
+
+            mult = nshards * _ROW_TILE
+        else:
+            mult = nshards
+        edges_digest = hashlib.sha1(
+            np.ascontiguousarray(edges).tobytes()
+        ).hexdigest()
+        bins_d, valid_d, bins_fm_d, n_pad = _devcache.cached(
+            "tree_bins", cache_token, (edges_digest, p.nbins, mult), mesh,
+            _place_bins, frame_key=cache_frame_key,
+        )
 
     C = n_class_trees
-    if objective == "fixed":
-        targets = np.asarray(y, dtype=np.float32)
-        if targets.ndim == 1:
-            targets = targets[:, None]
-        y_host = np.zeros((n_pad, targets.shape[1]), np.float32)
-        y_host[:n] = targets
-        y_d = jax.device_put(y_host, row_sharding(mesh, 2))
-    else:
-        y_host = np.zeros(n_pad, np.float32)
-        y_host[:n] = np.asarray(y, dtype=np.float32)
-        y_d = jax.device_put(y_host, row_sharding(mesh, 1))
+    with Span("state_upload") as upload:
+        if objective == "fixed":
+            targets = np.asarray(y, dtype=np.float32)
+            if targets.ndim == 1:
+                targets = targets[:, None]
+            y_host = np.zeros((n_pad, targets.shape[1]), np.float32)
+            y_host[:n] = targets
+            y_d = jax.device_put(y_host, row_sharding(mesh, 2))
+        else:
+            y_host = np.zeros(n_pad, np.float32)
+            y_host[:n] = np.asarray(y, dtype=np.float32)
+            y_d = jax.device_put(y_host, row_sharding(mesh, 1))
 
-    if resume_from is not None and objective != "fixed":
-        m0 = resume_from.predict_margin(X).astype(np.float32)  # [n, C]
-        margin_host = np.tile(
-            np.asarray(init_margin, dtype=np.float32), (n_pad, 1)
-        )
-        margin_host[:n] = m0
-    else:
-        margin_host = np.tile(
-            np.asarray(init_margin, dtype=np.float32), (n_pad, 1)
-        )
-    if offset is not None:
-        if C != 1:
-            raise ValueError("offset_column requires a single-margin objective")
-        margin_host[:n, 0] += np.asarray(offset, dtype=np.float32)
-    margin = jax.device_put(margin_host, row_sharding(mesh, 2))
+        if resume_from is not None and objective != "fixed":
+            m0 = resume_from.predict_margin(X).astype(np.float32)  # [n, C]
+            margin_host = np.tile(
+                np.asarray(init_margin, dtype=np.float32), (n_pad, 1)
+            )
+            margin_host[:n] = m0
+        else:
+            margin_host = np.tile(
+                np.asarray(init_margin, dtype=np.float32), (n_pad, 1)
+            )
+        if offset is not None:
+            if C != 1:
+                raise ValueError("offset_column requires a single-margin objective")
+            margin_host[:n, 0] += np.asarray(offset, dtype=np.float32)
+        margin = jax.device_put(margin_host, row_sharding(mesh, 2))
 
-    w_d = None
-    if weights is not None:
-        w_host = np.zeros(n_pad, np.float32)
-        w_host[:n] = np.asarray(weights, dtype=np.float32)
-        w_d = jax.device_put(w_host, row_sharding(mesh, 1))
+        w_d = None
+        if weights is not None:
+            w_host = np.zeros(n_pad, np.float32)
+            w_host[:n] = np.asarray(weights, dtype=np.float32)
+            w_d = jax.device_put(w_host, row_sharding(mesh, 1))
+        jax.block_until_ready((y_d, margin, w_d))
+        upload.set(bytes=y_host.nbytes + margin_host.nbytes
+                   + (w_host.nbytes if w_d is not None else 0))
     mono_d = None
     if monotone is not None and np.any(np.asarray(monotone) != 0):
         mono_d = jnp.asarray(np.asarray(monotone, dtype=np.int32))
@@ -851,16 +902,12 @@ def train_boosted(
             dst.is_split = list(src.is_split)
             dst.leaf = list(src.leaf)
     key = jax.random.PRNGKey(p.seed)
-    jax.block_until_ready(margin)
-    _t_prep = _time.time()
 
     # the block program depends on neither ntrees nor seed — normalize them
     # out of the compile-cache key
     from dataclasses import replace as _dc_replace
 
     p_key = _dc_replace(p, ntrees=0, seed=0)
-
-    from h2o3_tpu.util import timeline
 
     built = 0
     default_block = tree_block_size()
@@ -881,7 +928,7 @@ def train_boosted(
         keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(
             jnp.arange(tree_offset + built, tree_offset + built + block)
         )
-        with timeline.timed(
+        with Span(
             "tree_block", objective=objective, trees=block, rows=n,
             first_tree=tree_offset + built,
         ):
@@ -889,20 +936,20 @@ def train_boosted(
                 bins_d, y_d, valid_d, margin, keys, bins_fm_d, w_d, mono_d
             )
             jax.block_until_ready(margin)
-        tf, tb, tdl, tsp, tlf = jax.device_get(trees_dev)  # [block, C, M] each
-        for t in range(block):
-            for c in range(C):
-                trees_per_class[c].append(
-                    tf[t, c], tb[t, c], tdl[t, c], tsp[t, c], tlf[t, c]
-                )
+        with Span("tree_readback", trees=block):
+            tf, tb, tdl, tsp, tlf = jax.device_get(trees_dev)  # [block, C, M] each
+            for t in range(block):
+                for c in range(C):
+                    trees_per_class[c].append(
+                        tf[t, c], tb[t, c], tdl[t, c], tsp[t, c], tlf[t, c]
+                    )
         built += block
         if monitor is not None:
-            margin_host = np.asarray(jax.device_get(margin), np.float64)[:n]
-            if monitor(built - 1, margin_host):
+            with Span("budget_check") as check:
+                margin_host = np.asarray(jax.device_get(margin), np.float64)[:n]
+                stop = bool(monitor(built - 1, margin_host))
+                check.set(stop=stop)
+            if stop:
                 break
 
-    if timings is not None:
-        jax.block_until_ready(margin)
-        timings["prep_s"] = _t_prep - _t0
-        timings["train_s"] = _time.time() - _t_prep
     return BoostedTrees(trees_per_class, np.asarray(init_margin, np.float64), p, average=average)

@@ -21,6 +21,7 @@ from h2o3_tpu.frame.frame import ColType, Frame
 from h2o3_tpu.models.data_info import DataInfo, _align_codes, build_data_info
 from h2o3_tpu.models.framework import Model
 from h2o3_tpu.models import metrics as M
+from h2o3_tpu.util.telemetry import Span
 
 
 def tree_data_info(frame: Frame, y: str, ignored=()) -> DataInfo:
@@ -65,25 +66,28 @@ def tree_matrix(
     one_hot_explicit: one 0/1 column per level; an NA row is NaN across the
     whole block so NA routing still learns a default direction per split.
     """
-    cols = []
-    for name in info.predictor_names:
-        col = frame.col(name)
-        if name in info.cat_domains:
-            codes = _align_codes(col, info.cat_domains[name])
-            if encoding == "one_hot_explicit":
-                dom = info.cat_domains[name]
-                block = (codes[:, None] == np.arange(len(dom))[None, :]).astype(
-                    np.float32
-                )
-                block[codes < 0] = np.nan
-                cols.append(block)
+    with Span("tree_matrix", rows=frame.nrows) as span:
+        cols = []
+        for name in info.predictor_names:
+            col = frame.col(name)
+            if name in info.cat_domains:
+                codes = _align_codes(col, info.cat_domains[name])
+                if encoding == "one_hot_explicit":
+                    dom = info.cat_domains[name]
+                    block = (codes[:, None] == np.arange(len(dom))[None, :]).astype(
+                        np.float32
+                    )
+                    block[codes < 0] = np.nan
+                    cols.append(block)
+                else:
+                    cols.append(
+                        np.where(codes >= 0, codes.astype(np.float32), np.nan)[:, None]
+                    )
             else:
-                cols.append(
-                    np.where(codes >= 0, codes.astype(np.float32), np.nan)[:, None]
-                )
-        else:
-            cols.append(col.numeric_view().astype(np.float32)[:, None])
-    return np.concatenate(cols, axis=1)
+                cols.append(col.numeric_view().astype(np.float32)[:, None])
+        X = np.concatenate(cols, axis=1)
+        span.set(features=X.shape[1])
+    return X
 
 
 # -- distributions (hex/Distribution.java gradient/hessian families) ---------
@@ -389,40 +393,45 @@ def tree_fit_setup(frame: Frame, p, model_cls, use_offset: bool):
         # ineligible combination (knob off, checkpoint, monotone, custom
         # objective, explicit one-hot): materialize and run the legacy path
 
-    ignored = list(p.ignored_columns)
-    aux_cols = [p.weights_column] + ([p.offset_column] if use_offset else [])
-    for aux in aux_cols:
-        if aux and aux not in ignored:
-            ignored.append(aux)
-    info = tree_data_info(frame, p.response_column, ignored)
-    y = response_vector(info, frame)
-    nclasses = len(info.response_domain) if info.response_domain else 1
-    dist = auto_distribution(nclasses) if p.distribution == "auto" else p.distribution
+    with Span("tree_setup") as span:
+        ignored = list(p.ignored_columns)
+        aux_cols = [p.weights_column] + ([p.offset_column] if use_offset else [])
+        for aux in aux_cols:
+            if aux and aux not in ignored:
+                ignored.append(aux)
+        info = tree_data_info(frame, p.response_column, ignored)
+        y = response_vector(info, frame)
+        nclasses = len(info.response_domain) if info.response_domain else 1
+        dist = auto_distribution(nclasses) if p.distribution == "auto" else p.distribution
 
-    model = model_cls(p, info, dist)
-    enc = model.tree_encoding
-    X = tree_matrix(info, frame, encoding=enc)
-    keep = ~np.isnan(y)
-    weights = extract_weights(frame, p, keep)
-    offset = None
-    if use_offset and p.offset_column:
-        offset = frame.col(p.offset_column).numeric_view().astype(np.float64)
-        keep &= ~np.isnan(offset)
-    X, y = X[keep], y[keep]
-    if weights is not None:
-        weights = weights[keep]
-    if offset is not None:
-        offset = offset[keep]
+        model = model_cls(p, info, dist)
+        enc = model.tree_encoding
+        X = tree_matrix(info, frame, encoding=enc)
+        # the rows a fit keeps: a copy of the whole matrix
+        with Span("tree_rows") as rows:
+            keep = ~np.isnan(y)
+            weights = extract_weights(frame, p, keep)
+            offset = None
+            if use_offset and p.offset_column:
+                offset = frame.col(p.offset_column).numeric_view().astype(np.float64)
+                keep &= ~np.isnan(offset)
+            X, y = X[keep], y[keep]
+            if weights is not None:
+                weights = weights[keep]
+            if offset is not None:
+                offset = offset[keep]
+            rows.set(rows=X.shape[0], dropped=int(keep.size - X.shape[0]))
+        span.set(rows=X.shape[0], features=X.shape[1])
 
-    objective = resolve_objective(dist, p, y)
-    f0 = init_margin(objective, y, nclasses, weights=weights)
-    n_class_trees = nclasses if dist == "multinomial" else 1
-    mono = monotone_array(getattr(p, "monotone_constraints", None), info, enc)
-    if mono is not None and dist == "multinomial":
-        # softmax normalization voids per-margin monotonicity; the
-        # reference rejects this combination too (GBM.java validation)
-        raise ValueError("monotone_constraints not supported for multinomial")
-    return model, X, y, weights, offset, objective, f0, n_class_trees, mono
+        objective = resolve_objective(dist, p, y)
+        f0 = init_margin(objective, y, nclasses, weights=weights)
+        n_class_trees = nclasses if dist == "multinomial" else 1
+        mono = monotone_array(getattr(p, "monotone_constraints", None), info, enc)
+        if mono is not None and dist == "multinomial":
+            # softmax normalization voids per-margin monotonicity; the
+            # reference rejects this combination too (GBM.java validation)
+            raise ValueError("monotone_constraints not supported for multinomial")
+        return model, X, y, weights, offset, objective, f0, n_class_trees, mono
 
 
 def make_tree_monitor(model, p, objective, y, weights, history):
@@ -563,22 +572,24 @@ class TreeModelBase(Model):
     def _predict_raw(self, frame: Frame) -> np.ndarray:
         X = tree_matrix(self.data_info, frame, encoding=self.tree_encoding)
         margin = self.booster.predict_margin(X)
-        off = getattr(self.params, "offset_column", None)
-        if off:
-            # Model.score: the offset column of the SCORING frame shifts the
-            # margin (hex/Model.java adaptTestForTrain offset handling)
-            if off not in frame.names:
-                raise ValueError(
-                    f"offset_column {off!r} must be present in the scoring frame"
-                )
-            off_vals = frame.col(off).numeric_view()
-            if np.isnan(off_vals).any():
-                # match the MOJO scorer: loud, not silently-NaN predictions
-                raise ValueError(
-                    f"offset_column {off!r} has NA values in the scoring frame"
-                )
-            margin = margin + off_vals[:, None]
-        return self._raw_from_margin(margin)
+        # margin to raw scores: the offset, then the inverse link
+        with Span("score_link", rows=margin.shape[0]):
+            off = getattr(self.params, "offset_column", None)
+            if off:
+                # Model.score: the offset column of the SCORING frame shifts
+                # the margin (hex/Model.java adaptTestForTrain offset handling)
+                if off not in frame.names:
+                    raise ValueError(
+                        f"offset_column {off!r} must be present in the scoring frame"
+                    )
+                off_vals = frame.col(off).numeric_view()
+                if np.isnan(off_vals).any():
+                    # match the MOJO scorer: loud, not silently-NaN predictions
+                    raise ValueError(
+                        f"offset_column {off!r} has NA values in the scoring frame"
+                    )
+                margin = margin + off_vals[:, None]
+            return self._raw_from_margin(margin)
 
     def _raw_from_margin(self, margin: np.ndarray) -> np.ndarray:
         """Raw scores (probabilities / inverse-linked response) from the
@@ -595,7 +606,8 @@ class TreeModelBase(Model):
         if ev is not None and frame is ev["frame"]:
             # the distributed fit already holds this frame's final margins
             # (over its kept rows) — score them without materializing rows
-            return self._metrics_from_dist(ev)
+            with Span("model_performance", rows=len(ev["y"])):
+                return self._metrics_from_dist(ev)
         return super().model_performance(frame)
 
     def _metrics_from_dist(self, ev: dict) -> Any:

@@ -997,15 +997,13 @@ def dist_drf_front(frame, p, model_cls):
 def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                        init_margin, params, average: bool = False,
                        monitor=None, score_interval: int = 1,
-                       timings: Optional[dict] = None, weights=None,
-                       offset=None):
+                       weights=None, offset=None):
     """``train_boosted`` over a :class:`DistTreeMatrix`: the level loop
     fans ``hist_level`` ops, merges float64 partials in canonical group
     order, and runs the existing ``_split_search`` caller-side — the
     result is a plain :class:`BoostedTrees` plus a ``dist_eval`` handle
     for materialization-free scoring."""
     from h2o3_tpu.models.tree import booster as _booster
-    _t0 = time.time()
     p = params
     n_bins1 = p.nbins + 1
     C = int(n_class_trees)
@@ -1029,19 +1027,12 @@ def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                             classes=C, rows=int(Xd.n_total)):
             Xd._bind(f0, C, objective, p.seed, p.sample_rate,
                      use_offset=Xd.off_all is not None)
-            _t_prep = time.time()
             trees_per_class = [
                 _booster.Trees(D, n_bins1, Xd.edges) for _ in range(C)]
-            level_walls: List[float] = []
-            levels_n = 0
             built = 0
 
-            def _timed_op(op):
-                nonlocal levels_n
-                t0 = time.perf_counter()
+            def _level_op(op):
                 outs = Xd._op(op)
-                level_walls.append(time.perf_counter() - t0)
-                levels_n += 1
                 _LEVELS.inc()
                 return outs
 
@@ -1055,7 +1046,7 @@ def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                     op = {"kind": "level", "r": r, "d": d,
                           "c0": c0, "c1": c1,
                           "subtract": bool(subtract), "routes": routes}
-                    parts = _timed_op(op)
+                    parts = _level_op(op)
                     merged = np.zeros_like(np.asarray(parts[0], np.float64))
                     for part in parts:
                         merged = merged + np.asarray(part, np.float64)
@@ -1133,7 +1124,7 @@ def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                 else:
                     op = {"kind": "totals", "r": r, "d": D,
                           "c0": c0, "c1": c1, "routes": routes}
-                    parts = _timed_op(op)
+                    parts = _level_op(op)
                     tot = np.zeros_like(np.asarray(parts[0], np.float64))
                     for part in parts:
                         tot = tot + np.asarray(part, np.float64)
@@ -1212,11 +1203,6 @@ def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
             average=average)
         bt.dist_eval = {"frame": Xd.frame, "y": Xd.y_all, "w": Xd.w_all,
                         "margin": margin_score}
-        if timings is not None:
-            timings["prep_s"] = _t_prep - _t0
-            timings["train_s"] = time.time() - _t_prep
-            timings["level_walls"] = level_walls
-            timings["levels"] = levels_n
         return bt
     finally:
         Xd._finish()

@@ -192,13 +192,14 @@ def apply_bins(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
     nbins = edges.shape[1] + 1
     if n == 0 or F == 0:
         return np.empty((n, F), dtype=np.int32)
-    if F > 32 * max(n, 1):  # wide-short: loop overhead dominates
-        out = _apply_bins_batched(X, edges)
-    else:
-        out = np.empty((n, F), dtype=np.int32)
-        for f in range(F):
-            out[:, f] = np.searchsorted(edges[f], X[:, f], side="right")
-    out[np.isnan(X)] = nbins  # NA bucket (DHistogram NA bin at end)
+    with telemetry.Span("apply_bins", rows=n, features=F):
+        if F > 32 * max(n, 1):  # wide-short: loop overhead dominates
+            out = _apply_bins_batched(X, edges)
+        else:
+            out = np.empty((n, F), dtype=np.int32)
+            for f in range(F):
+                out[:, f] = np.searchsorted(edges[f], X[:, f], side="right")
+        out[np.isnan(X)] = nbins  # NA bucket (DHistogram NA bin at end)
     return out
 
 
@@ -440,7 +441,8 @@ def _build_histogram_jit(
             b, nd, gg, hh, n_nodes, n_bins1, impl, vma=(DATA_AXIS,),
             dtype=dtype, kernel=kernel, **kw
         )
-        return jax.lax.psum(part, DATA_AXIS)
+        with jax.named_scope("hist_psum"):
+            return jax.lax.psum(part, DATA_AXIS)
 
     # interpreter-mode pallas lowers VMEM scratch to plain arrays whose
     # varying-axis metadata can't match the shard-varying values written

@@ -465,10 +465,26 @@ def build_histogram_pallas(
         dtype = "bf16" if _resolve_hist_dtype("auto") == jnp.bfloat16 else "f32"
     if kernel == "auto" and n_nodes * _C <= _fact_max_kc():
         kernel = "factorized"
-    return _build_histogram_pallas_jit(
-        bins, nodes, g, h, n_nodes, n_bins1, row_tile, interpret,
-        vma, kernel, bins_fm, rw, dtype,
-    )
+    # the scope sits OUTSIDE the jit: XLA names the kernel's custom-call
+    # after the innermost component of its name stack, and the benchmark's
+    # accepted readers find the kernels by ``_build_histogram_pallas_jit``
+    with jax.named_scope("hist_" + _kernel_choice(kernel, n_nodes)):
+        return _build_histogram_pallas_jit(
+            bins, nodes, g, h, n_nodes, n_bins1, row_tile, interpret,
+            vma, kernel, bins_fm, rw, dtype,
+        )
+
+
+def _kernel_choice(kernel: str, n_nodes: int) -> str:
+    """Which of the three kernels builds this level: ``factorized`` or
+    ``nodematmul`` as asked, else the node-matmul kernel while its
+    all-nodes output fits VMEM and the sorted tile-per-node kernel past
+    that."""
+    if kernel in ("factorized", "nodematmul"):
+        return kernel
+    if kernel == "auto" and n_nodes * _C <= _NODE_MATMUL_MAX_KC:
+        return "nodematmul"
+    return "sorted"
 
 
 @partial(
@@ -482,16 +498,15 @@ def _build_histogram_pallas_jit(
     row_tile, interpret: bool, vma: tuple,
     kernel: str, bins_fm, rw, dtype: str,
 ):
-    if kernel == "factorized":
+    choice = _kernel_choice(kernel, n_nodes)
+    if choice == "factorized":
         return _build_histogram_factorized(
             bins, nodes, g, h, n_nodes, n_bins1,
             row_tile=row_tile or _ROW_TILE, feat_block=_FEAT_BLOCK,
             interpret=interpret, vma=vma, bins_fm=bins_fm, rw=rw,
             dtype=_resolve_hist_dtype(dtype),
         )
-    if kernel == "nodematmul" or (
-        kernel == "auto" and n_nodes * _C <= _NODE_MATMUL_MAX_KC
-    ):
+    if choice == "nodematmul":
         return _build_histogram_nodematmul(
             bins, nodes, g, h, n_nodes, n_bins1,
             row_tile=row_tile or _ROW_TILE, feat_block=_FEAT_BLOCK,
@@ -502,10 +517,11 @@ def _build_histogram_pallas_jit(
     r = row_tile or 512  # sorted kernel keeps its original tile height
     t_max = (n + r - 1) // r + n_nodes  # ≤ R-1 pad rows per node
 
-    bins_p, vals_p, item_node, item_first = _prep_padded(
-        bins, nodes, g, h, n_nodes, r, t_max, rw=rw,
-        dtype=_resolve_hist_dtype(dtype),
-    )
+    with jax.named_scope("sorted_prep"):
+        bins_p, vals_p, item_node, item_first = _prep_padded(
+            bins, nodes, g, h, n_nodes, r, t_max, rw=rw,
+            dtype=_resolve_hist_dtype(dtype),
+        )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

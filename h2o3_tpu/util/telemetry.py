@@ -17,8 +17,15 @@ dispatches, bytes ingested or store churn.  This module is that layer:
   under an open span inherits the span's trace ids via the trace provider
   hook installed below;
 * a ``jax.monitoring`` listener that counts XLA backend compiles process-wide
-  (``jit_compiles_total`` / ``jit_compile_seconds_total``), the substrate for
-  per-dispatch jit cache hit/miss accounting in ``compute/mapreduce.py``.
+  (``jit_compiles_total`` / ``jit_compile_seconds_total``; a program the
+  persistent cache served counts under ``jit_cache_loads_total`` instead), the
+  substrate for per-dispatch jit cache hit/miss accounting in
+  ``compute/mapreduce.py`` and for the ``compiles`` / ``cache_loads`` fields
+  of a span inside which the calling thread built or loaded a program.
+
+Under a ``jax.profiler`` session every :class:`Span` is also a
+``TraceAnnotation`` on its thread's line of the trace, so program spans and
+device operations share the profiler's clock.
 
 The TPU-native story (SURVEY.md §5): ``jax.profiler`` owns the device-side
 trace; this registry owns the host-side control-plane numbers that DrJAX-style
@@ -31,6 +38,7 @@ from __future__ import annotations
 import math
 import random
 import re
+import sys
 import threading
 import time
 from typing import (
@@ -717,15 +725,35 @@ from h2o3_tpu.util import log as _log  # noqa: E402  (import-light, no cycle)
 _log.set_trace_provider(current_trace_context)
 
 
+def _trace_annotation(kind: str, **ids: Any):
+    """An open ``jax.profiler.TraceAnnotation`` named ``kind`` with ``ids``
+    as its arguments, or None in a process that has not imported jax
+    (telemetry never imports the backend itself).  With no profiler session
+    running the annotation costs one atomic read."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(kind, **ids)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """Context manager: a unit of traced work.
 
     The outermost span mints a fresh ``trace_id``; nested spans inherit it and
     point at their parent via ``parent_id``. On exit one enriched event lands
-    in the timeline ring (kind + duration_ms + ok + ids + node + fields) — the
-    same shape ``timeline.timed`` wrote, now correlatable across layers. Spans
+    in the timeline ring (kind + start_ns + duration_ms + ok + ids + node +
+    fields; ``ns`` is the end) — the same shape ``timeline.timed`` wrote, now
+    correlatable across layers. Spans
     are thread-local: a REST handler thread's trace does not leak into a
     concurrently training thread.
+
+    Under a ``jax.profiler`` session the span is also an event of the same
+    name on its thread's line of the trace, with ``span_id``, ``trace_id``
+    and ``parent_id`` as its arguments.  A span inside which the calling
+    thread built or loaded XLA programs reports ``compiles``,
+    ``cache_loads`` and ``compile_s`` (never written when zero).
 
     ``trace_id``/``parent_id`` may be passed explicitly to continue a trace
     that started somewhere else — another thread (a fan-out worker joining
@@ -734,7 +762,7 @@ class Span:
     wins over the thread-local parent."""
 
     __slots__ = ("kind", "fields", "span_id", "trace_id", "parent_id",
-                 "_explicit", "t0")
+                 "_explicit", "t0", "start_ns", "_ann", "_built0")
 
     def __init__(self, kind: str, *, trace_id: Optional[str] = None,
                  parent_id: Optional[str] = None, **fields: Any) -> None:
@@ -745,6 +773,9 @@ class Span:
         self.parent_id: Optional[str] = parent_id
         self._explicit = trace_id is not None
         self.t0 = 0.0
+        self.start_ns = 0
+        self._ann = None
+        self._built0 = (0, 0, 0.0)
 
     def set(self, **fields: Any) -> "Span":
         """Attach fields discovered mid-span (iterations, rows, ...)."""
@@ -764,11 +795,19 @@ class Span:
                 # the span header) — a fresh trace starts at a root
                 self.parent_id = None
         _span_stack().append(self)
+        self._built0 = _thread_builds()
+        self._ann = _trace_annotation(
+            self.kind, span_id=self.span_id, trace_id=self.trace_id,
+            parent_id=self.parent_id or "")
+        self.start_ns = time.time_ns()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         duration_ms = round((time.perf_counter() - self.t0) * 1e3, 3)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         stack = _span_stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -776,6 +815,7 @@ class Span:
             stack.remove(self)
         evt = {
             "kind": self.kind,
+            "start_ns": self.start_ns,
             "duration_ms": duration_ms,
             "ok": exc_type is None,
             "trace_id": self.trace_id,
@@ -789,6 +829,14 @@ class Span:
             evt["node"] = node
         if self.fields:
             evt.update(self.fields)
+        builds0, loads0, secs0 = self._built0
+        builds, loads, secs = _thread_builds()
+        if builds != builds0:
+            evt["compiles"] = builds - builds0
+        if loads != loads0:
+            evt["cache_loads"] = loads - loads0
+        if builds != builds0 or loads != loads0:
+            evt["compile_s"] = round(secs - secs0, 6)
         timeline.record_event(evt)
 
 
@@ -797,16 +845,21 @@ class Span:
 
 _JIT_COMPILES = counter(
     "jit_compiles_total",
-    "XLA backend compiles observed process-wide (jax.monitoring)",
+    "XLA programs built by the backend compiler, process-wide "
+    "(jax.monitoring; persistent-cache loads are jit_cache_loads_total)",
+)
+_JIT_CACHE_LOADS = counter(
+    "jit_cache_loads_total",
+    "XLA programs served by the persistent compilation cache, process-wide",
 )
 _JIT_COMPILE_SECS = counter(
     "jit_compile_seconds_total",
-    "total wall seconds spent in XLA backend compiles",
+    "total wall seconds spent building or loading XLA programs",
 )
 
 _jit_listener_lock = threading.Lock()
 _jit_listener_installed = False
-#: per-thread compile count: XLA compiles run synchronously on the thread
+#: per-thread counts: XLA compiles run synchronously on the thread
 #: that triggered them, so a thread-local delta attributes cache misses to
 #: the right dispatch even when builds run concurrently (a global delta
 #: would blame thread A for thread B's compile)
@@ -827,31 +880,53 @@ def install_jax_compile_listener() -> bool:
         except Exception:  # pragma: no cover - jax is baked into the image
             return False
 
+        def _on_event(name: str, **kw: Any) -> None:
+            # recorded inside the backend_compile_duration it belongs to,
+            # on the same thread: the duration that follows is a load
+            if name == "/jax/compilation_cache/cache_hits":
+                _tls_compiles.cache_hit = True
+
         def _on_duration(name: str, secs: float, **kw: Any) -> None:
             if name.endswith("backend_compile_duration"):
-                _JIT_COMPILES.inc()
+                t = _tls_compiles
+                if getattr(t, "cache_hit", False):
+                    t.cache_hit = False
+                    _JIT_CACHE_LOADS.inc()
+                    t.loads = getattr(t, "loads", 0) + 1
+                else:
+                    _JIT_COMPILES.inc()
+                    t.builds = getattr(t, "builds", 0) + 1
                 _JIT_COMPILE_SECS.inc(secs)
-                _tls_compiles.count = getattr(_tls_compiles, "count", 0) + 1
-                _tls_compiles.seconds = (
-                    getattr(_tls_compiles, "seconds", 0.0) + secs)
+                t.seconds = getattr(t, "seconds", 0.0) + secs
 
+        monitoring.register_event_listener(_on_event)
         monitoring.register_event_duration_secs_listener(_on_duration)
         _jit_listener_installed = True
         return True
 
 
 def jit_compile_count() -> float:
-    """Total compiles observed process-wide (the bench/summary number)."""
+    """Programs built process-wide (the bench/summary number)."""
     return _JIT_COMPILES.total()
 
 
+def _thread_builds() -> Tuple[int, int, float]:
+    """(programs built, programs loaded from the persistent cache, seconds
+    in either) on the CALLING thread since it started."""
+    t = _tls_compiles
+    return (getattr(t, "builds", 0), getattr(t, "loads", 0),
+            getattr(t, "seconds", 0.0))
+
+
 def thread_compile_count() -> int:
-    """Compiles observed on the CALLING thread — per-dispatch deltas give
-    correct cache hit/miss attribution under concurrent builds."""
-    return getattr(_tls_compiles, "count", 0)
+    """Programs that reached the backend on the CALLING thread, built or
+    loaded — either way the in-process jit cache missed, so per-dispatch
+    deltas give correct plan hit/miss attribution under concurrent builds."""
+    builds, loads, _ = _thread_builds()
+    return builds + loads
 
 
 def thread_compile_seconds() -> float:
     """Compile wall seconds observed on the CALLING thread; the cost
     ledger charges per-dispatch deltas of this to the open trace."""
-    return getattr(_tls_compiles, "seconds", 0.0)
+    return _thread_builds()[2]
