@@ -13,16 +13,25 @@ level of a depth ≤ 6 tree): rows NEVER move. Grid over (feature-block,
 row-tile); each step computes ``one_hot(bins)[R, Fb·B1]ᵀ ⊗
 node_masked_vals[R, K·C]`` as ONE dot_general on the MXU and accumulates
 into a VMEM-resident [Fb·B1, K·C] block revisited across row tiles. There
-is no sort, no scatter, no partition maintenance — the per-level prep the
-sorted kernel needs (and its O(N log N) bitonic argsort on TPU) vanishes.
-The histogram for ALL nodes of the level materializes in one pass.
+is no sort, no scatter, no partition maintenance, no row is moved. The
+histogram for ALL nodes of the level materializes in one pass.
 
 **Sorted tile-per-node kernel** (fallback for deep levels, K·C > 512,
-where the all-nodes output exceeds VMEM): stable-sort row ids by node, pad
-each node's segment to a row-tile multiple, then a 1-D grid with
+where the all-nodes output exceeds VMEM): a 1-D grid with
 ``pltpu.PrefetchScalarGridSpec`` where the output BlockSpec's index map
 reads the prefetched node id — each grid step's output block IS that
-node's (F, C, B) slab, accumulated in VMEM across that node's tiles.
+node's (F, C, B) slab, accumulated in VMEM across that node's tiles. Its
+operands are every node's rows in stable order, each node padded to a
+row-tile multiple, and building them (``_prep_gathered``) is what a sorted
+level costs. On a v5e at 6M x 28 rows the key-value sort takes 11-14 ms
+and the kernel 44 ms, but MOVING the rows is priced by the row and by each
+group of 8 int32 columns of it, not by its bytes: a gather of 6.26M rows
+takes 44-73 ms for up to 8 columns and 146-215 ms for 28-32, and a scatter
+of the same rows 3.3 times the gather. So the preparation moves every row
+once, by one gather of a narrow row that carries the codes (several to a
+word) and g, h, w together, and holds no scatter, no ``bincount``, no
+zero-filled buffer and no per-row table lookup: the index work is per tile
+(PERF.md section 6, PR 29).
 
 The portable XLA scatter path in ``h2o3_tpu/ops/histogram.py`` is the
 correctness oracle; ``tests/test_pallas_histogram.py`` checks parity in
@@ -344,7 +353,7 @@ def _build_histogram_factorized(
 def _hist_kernel(node_ref, first_ref, bins_ref, vals_ref, out_ref, *, n_feat, n_bins1):
     """One grid step = one row tile of one node.
 
-    bins_ref: [R, F] int32 (VMEM); vals_ref: [R, C] f32 (VMEM);
+    bins_ref: [R, F] int32 (VMEM); vals_ref: [R, C] f32 or bf16 (VMEM);
     out_ref:  [1, F, C, B1] f32 — the current node's slab (revisited across
     consecutive tiles of the same node).
     """
@@ -376,53 +385,130 @@ def _hist_kernel(node_ref, first_ref, bins_ref, vals_ref, out_ref, *, n_feat, n_
         out_ref[...] = out_ref[...] + slab
 
 
-def _prep_padded(bins, nodes, g, h, n_nodes: int, row_tile: int, t_max: int,
-                 rw=None, dtype=jnp.float32):
-    """Sort rows by node, pad each node segment to a row_tile multiple.
+def _code_words(n_bins1: int, n_feat: int):
+    """(bits a code, codes a word, words a row) of the packed row: the bit
+    width of ``n_bins1`` itself, so that value is a spare code."""
+    bits = n_bins1.bit_length()
+    per = 32 // bits
+    return bits, per, -(-n_feat // per)
 
-    Returns (bins_p [T*R, F] int32, vals_p [T*R, C] f32,
-    item_node [T] int32 — dummy slot n_nodes for unused tiles,
+
+def _pack_row(bins, g, h, w, n_bins1: int, dtype):
+    """[N, F] codes and g, h, w -> [N, P] int32, the row the preparation
+    gathers. The chip prices a row gather by the row and by each group of 8
+    int32 columns of it (36-49 ms a group at 6.26M rows on a v5e), so the
+    row is made narrow: codes share a word, ``32 // bits`` of them at the bit
+    width of ``n_bins1`` (whose value itself, which matches no bin, stands
+    for any code out of range: such a code still adds nothing); bfloat16 g
+    and h share one word and w takes one, float32 operands one each."""
+    bits, per, _ = _code_words(n_bins1, bins.shape[1])
+    u32 = jnp.uint32
+    b = bins.astype(jnp.int32)
+    b = jnp.where((b >= 0) & (b < n_bins1), b, n_bins1).astype(u32)
+    words = []
+    for c in range(0, b.shape[1], per):
+        word = b[:, c]
+        for k in range(1, min(per, b.shape[1] - c)):
+            word = word | (b[:, c + k] << (bits * k))
+        words.append(word)
+    if dtype == jnp.bfloat16:
+        def u16(x):
+            return jax.lax.bitcast_convert_type(
+                x.astype(jnp.bfloat16), jnp.uint16).astype(u32)
+        words += [u16(g) | (u16(h) << 16), u16(w)]
+    else:
+        words += [jax.lax.bitcast_convert_type(x.astype(jnp.float32), u32)
+                  for x in (g, h, w)]
+    return jax.lax.bitcast_convert_type(jnp.stack(words, axis=1), jnp.int32)
+
+
+def _unpack_row(rows, n_feat: int, n_bins1: int, dtype):
+    """Inverse of ``_pack_row`` on gathered rows: [T*R, P] int32 ->
+    (codes [T*R, F] int32, vals [T*R, C] ``dtype`` of (g, h, w, 0)), bit
+    for bit."""
+    bits, per, n_words = _code_words(n_bins1, n_feat)
+    u = jax.lax.bitcast_convert_type(rows, jnp.uint32)
+    codes = jnp.stack(
+        [(u[:, c // per] >> (bits * (c % per))) & ((1 << bits) - 1)
+         for c in range(n_feat)], axis=1).astype(jnp.int32)
+    v = u[:, n_words:]
+    if dtype == jnp.bfloat16:
+        def bf16(x):
+            return jax.lax.bitcast_convert_type(
+                x.astype(jnp.uint16), jnp.bfloat16)
+        cols = [bf16(v[:, 0] & 0xFFFF), bf16(v[:, 0] >> 16),
+                bf16(v[:, 1] & 0xFFFF)]
+    else:
+        cols = [jax.lax.bitcast_convert_type(v[:, i], jnp.float32)
+                for i in range(3)]
+    return codes, jnp.stack(cols + [jnp.zeros_like(cols[0])], axis=1)
+
+
+def _prep_gathered(bins, nodes, g, h, n_nodes: int, n_bins1: int,
+                   row_tile: int, t_max: int, rw=None, dtype=jnp.float32):
+    """Operands of the sorted kernel in the node-padded layout (each node's
+    rows in stable order, padded to a row_tile multiple, at least one tile
+    a node), built by gathers alone: every destination row reads its source.
+
+    One key-value sort gives the sorted node ids and the row order; the
+    per-node offsets are a binary search in the sorted ids (no bincount:
+    that is a scatter-add); per TILE, not per row, small-table lookups give
+    its node, the position in ``order`` where its rows start and where the
+    node's rows end. A tile's R source rows are R consecutive entries of
+    ``order`` (one slice a tile), and ONE row gather moves codes and g, h, w
+    together (``_pack_row``). A pad row reads whatever row follows in
+    ``order`` (the next node's, or an inactive one: distinct rows gather
+    faster than one row repeated) and only its g, h, w are zeroed, so it
+    adds nothing whatever its codes; inactive rows (node < 0) sort past the
+    last node and are never a tile's valid row, so g, h need no mask.
+
+    Returns (bins_p [T*R, F] int32, vals_p [T*R, C] ``dtype``,
+    item_node [T] int32 — dummy slot n_nodes for unused tiles —,
     item_first [T] int32).
     """
-    n, _ = bins.shape
+    n, n_feat = bins.shape
     r = row_tile
-    total = t_max * r
-    # inactive rows (node < 0) -> dummy node n_nodes, dropped by OOB scatter
-    nd = jnp.where(nodes >= 0, nodes, n_nodes)
-    order = jnp.argsort(nd, stable=True)
-    nd_s = nd[order]
-
-    counts = jnp.bincount(nd, length=n_nodes + 1)[:n_nodes]
+    i32 = jnp.int32
+    # inactive rows (node < 0) -> dummy node n_nodes, past every tile's limit
+    nd = jnp.where(nodes >= 0, nodes, n_nodes).astype(i32)
+    nd_s, order = jax.lax.sort(
+        (nd, jnp.arange(n, dtype=i32)), num_keys=1, is_stable=True)
+    sort_off = jnp.searchsorted(
+        nd_s, jnp.arange(n_nodes + 1, dtype=i32), side="left").astype(i32)
+    counts = sort_off[1:] - sort_off[:-1]
     # every node gets >= 1 tile so empty nodes' slabs are zero-initialized,
     # never left undefined
-    padded = jnp.maximum((counts + r - 1) // r, 1) * r
-    pad_off = jnp.concatenate([jnp.zeros((1,), padded.dtype), jnp.cumsum(padded)])
-    sort_off = jnp.concatenate([jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)])
+    tiles = jnp.maximum((counts + r - 1) // r, 1)
+    tile_off = jnp.concatenate([jnp.zeros((1,), i32), jnp.cumsum(tiles)])
 
-    rank = jnp.arange(n) - sort_off[jnp.clip(nd_s, 0, n_nodes - 1)]
-    dest = jnp.where(
-        nd_s < n_nodes, pad_off[jnp.clip(nd_s, 0, n_nodes - 1)] + rank, total
-    ).astype(jnp.int32)
-
-    bins_p = jnp.zeros((total, bins.shape[1]), jnp.int32).at[dest].set(
-        bins[order].astype(jnp.int32), mode="drop"
-    )
-    w = (nodes >= 0).astype(jnp.float32)
-    cw = w if rw is None else w * rw.astype(jnp.float32)
-    vals = jnp.stack(
-        [g.astype(jnp.float32) * w, h.astype(jnp.float32) * w, cw,
-         jnp.zeros_like(w)], axis=1
-    ).astype(dtype)
-    vals_p = jnp.zeros((total, _C), dtype).at[dest].set(vals[order], mode="drop")
-
-    # tile t belongs to the node whose padded segment contains row t*r
-    tile_starts = jnp.arange(t_max) * r
-    item_node = jnp.searchsorted(pad_off[1:], tile_starts, side="right").astype(jnp.int32)
+    # tile t belongs to the node whose run of tiles contains it
+    t = jnp.arange(t_max, dtype=i32)
+    item_node = jnp.searchsorted(tile_off[1:], t, side="right").astype(i32)
     item_node = jnp.minimum(item_node, n_nodes)  # trailing unused tiles -> dummy slab
     item_first = jnp.concatenate(
-        [jnp.ones((1,), jnp.int32),
-         (item_node[1:] != item_node[:-1]).astype(jnp.int32)]
+        [jnp.ones((1,), i32), (item_node[1:] != item_node[:-1]).astype(i32)]
     )
+    node_t = jnp.minimum(item_node, n_nodes - 1)
+    base = sort_off[node_t] + (t - tile_off[node_t]) * r
+    limit = jnp.where(item_node < n_nodes, sort_off[node_t + 1], 0)
+    valid = jnp.arange(r, dtype=i32)[None, :] < (limit - base)[:, None]
+
+    # a tile's window of ``order``; r entries of slack so that the last
+    # node's last tile may start less than r short of the end
+    order_p = jnp.concatenate([order, jnp.zeros((r,), i32)])
+    src = jax.lax.gather(
+        order_p, jnp.minimum(base, n)[:, None],
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,)),
+        (r,), mode="promise_in_bounds",
+    ).reshape(t_max * r)
+
+    w = jnp.ones_like(g) if rw is None else rw
+    rows = _pack_row(bins, g, h, w, n_bins1, dtype)
+    bins_p, vals_p = _unpack_row(
+        rows.at[src].get(mode="promise_in_bounds"), n_feat, n_bins1, dtype)
+    vals_p = jnp.where(
+        valid.reshape(t_max * r, 1), vals_p, jnp.zeros((), dtype))
     return bins_p, vals_p, item_node, item_first
 
 
@@ -518,8 +604,8 @@ def _build_histogram_pallas_jit(
     t_max = (n + r - 1) // r + n_nodes  # ≤ R-1 pad rows per node
 
     with jax.named_scope("sorted_prep"):
-        bins_p, vals_p, item_node, item_first = _prep_padded(
-            bins, nodes, g, h, n_nodes, r, t_max, rw=rw,
+        bins_p, vals_p, item_node, item_first = _prep_gathered(
+            bins, nodes, g, h, n_nodes, n_bins1, r, t_max, rw=rw,
             dtype=_resolve_hist_dtype(dtype),
         )
 
