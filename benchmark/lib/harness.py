@@ -3,10 +3,22 @@
 The window drives the program's normal Python entry — the builder class the
 configuration names, ``Builder(**params, response_column=..., seed=...)
 .train(frame)`` — for each request the traffic mix generates.  From the
-program the harness takes that entry, ``util/compile_cache.configure``, the
-``tree_block`` timeline spans, the ``hist_plan_cache_total`` counter and
-``tree_block_size()``; everything else (table, work counts, peaks, trace
-reduction, reference, comparison) is the benchmark's own.
+program the harness takes that entry, ``frame.Frame`` / ``Column`` /
+``ColType`` to hand it a table, ``util/compile_cache.configure``, the
+``tree_block`` timeline spans, the ``hist_plan_cache_total`` counter,
+``tree_block_size()`` and ``devcache.DEVCACHE.clear``; everything else
+(table, work counts, peaks, trace reduction, reference, comparison) is the
+benchmark's own.
+
+What is particular to a deployment is data, found by the names the
+configuration gives: the table generator and its typed columns
+(``benchmark/tables/``), the traffic mix (``benchmark/traffic/``), the
+reference with its extraction and comparison (``benchmark/references/``,
+through ``lib/checks.py``), the limits, the metric readers.  This file no
+longer knows what a tree's arrays are or which reference judges them; what
+is still reached for inside ``models/tree/booster`` (``_make_block_fn``,
+``_predict_stacked``, ``BoostedTrees.params``) is reached for in
+``lib/programs.py`` alone.
 """
 
 from __future__ import annotations
@@ -28,6 +40,8 @@ from . import trace as trace_mod
 from . import work as work_mod
 
 WINDOW_MARKER = "timed_window"
+#: scoring programs built ahead for one budgeted request, at most
+LADDER_MAX = 32
 
 
 # ---------------------------------------------------------------------------
@@ -37,20 +51,34 @@ WINDOW_MARKER = "timed_window"
 class CompileMeter:
     """Counts program builds that reached the backend (``builds``), how many
     of them the persistent cache served (``cache_hits``) and the seconds
-    spent; ``compiles`` = builds the cache did not serve."""
+    spent; ``compiles`` = builds the cache did not serve.  ``names`` keeps
+    the name of every build in order, so a run can say which program was
+    built inside its window; ``spells`` keeps when a program was being
+    traced, lowered or built, so a span can say how much of it was not
+    execution (``building_s``)."""
+
+    #: jax.monitoring's three stages of making a program
+    STAGES = ("jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+              "backend_compile_duration")
 
     def __init__(self) -> None:
         self.builds = 0
         self.cache_hits = 0
         self.seconds = 0.0
+        self.names: List[str] = []
+        self.spells: List[tuple] = []  # (start_ns, end_ns) on the host clock
 
     def install(self) -> None:
         from jax import monitoring
 
         def on_duration(name: str, secs: float, **kw) -> None:
+            if name.endswith(self.STAGES):
+                end = time.time_ns()
+                self.spells.append((end - int(secs * 1e9), end))
             if name.endswith("backend_compile_duration"):
                 self.builds += 1
                 self.seconds += secs
+                self.names.append(str(kw.get("fun_name")))
 
         def on_event(name: str, **kw) -> None:
             if name == "/jax/compilation_cache/cache_hits":
@@ -58,6 +86,18 @@ class CompileMeter:
 
         monitoring.register_event_duration_secs_listener(on_duration)
         monitoring.register_event_listener(on_event)
+
+    def building_s(self, t0_ns: int, t1_ns: int) -> float:
+        """Seconds of [t0, t1] in which some program was being traced,
+        lowered or built: the union of the spells, because an inner jit's
+        lie inside its caller's."""
+        total, reach = 0, t0_ns
+        for a, b in sorted(self.spells):
+            a, b = max(a, reach), min(b, t1_ns)
+            if b > a:
+                total += b - a
+                reach = b
+        return total / 1e9
 
     def snapshot(self) -> Dict[str, float]:
         return {"builds": self.builds, "cache_hits": self.cache_hits,
@@ -76,7 +116,10 @@ class CompileMeter:
 def generate_requests(traffic: dict, seconds: float, block: int) -> List[dict]:
     """Expand a traffic file into the list of requests of one run, the same
     for every seed.  ``"window"`` stands for ``--seconds`` and
-    ``"one_block"`` for the program's tree block size."""
+    ``"one_block"`` for the program's tree block size.  A fixed ``ntrees``
+    (no budget beside it) is a whole number of blocks: a shorter last block
+    is a training program of its own, which set-up builds for no one, and it
+    would compile inside the window."""
 
     def resolve(params: dict) -> dict:
         named = {"window": seconds, "one_block": block}
@@ -87,7 +130,12 @@ def generate_requests(traffic: dict, seconds: float, block: int) -> List[dict]:
     for req in traffic["requests"]:
         if req["op"] != "train":
             raise SystemExit(f"traffic op {req['op']!r} has no driver yet")
-        out.extend({"op": req["op"], "params": resolve(req.get("params", {}))}
+        params = resolve(req.get("params", {}))
+        if ("ntrees" in params and "max_runtime_secs" not in params
+                and int(params["ntrees"]) % block):
+            raise SystemExit(f"traffic asks for a fixed ntrees of {params['ntrees']}: "
+                             f"give a whole number of blocks of {block} trees")
+        out.extend({"op": req["op"], "params": dict(params)}
                    for _ in range(int(req.get("repeat", 1))))
     return out
 
@@ -101,11 +149,47 @@ def load_builder(path: str):
     return getattr(importlib.import_module(module), cls)
 
 
-def make_frame(X: np.ndarray, y: np.ndarray, config: dict):
-    from h2o3_tpu.frame.frame import ColType, Column, Frame
+def make_table(root: str, config: dict, rows: int, seed: int) -> dict:
+    """The configuration's table from the seed, by its generator: ``X``,
+    ``y``, ``classes`` and ``columns`` — the generator's own
+    ``columns(spec)``, one ``{"name", "type": "num"|"cat", "domain"}`` a
+    feature, or None where it states none (then every feature is numeric)."""
+    spec = config["table"]
+    gen = load_named(root, "tables", spec["generator"])
+    X, y = gen.make(spec, rows, seed)
+    columns = gen.columns(spec) if hasattr(gen, "columns") else None
+    if columns is not None:
+        if len(columns) != X.shape[1]:
+            raise SystemExit(f"generator {spec['generator']} states {len(columns)} "
+                             f"columns and makes {X.shape[1]}")
+        for col in columns:
+            if col.get("type") not in ("num", "cat") or (
+                    col["type"] == "cat" and not col.get("domain")):
+                raise SystemExit(f"column {col.get('name')!r} of generator "
+                                 f"{spec['generator']}: the type is \"num\", or "
+                                 "\"cat\" with its domain")
+    return {"X": X, "y": y, "classes": int(spec["classes"]), "columns": columns}
+
+
+def make_frame(X: np.ndarray, y: np.ndarray, config: dict,
+               columns: Optional[List[dict]] = None):
+    """The ``Frame`` handed to ``train()``.  A feature's type comes from
+    ``columns`` alone (a categorical's values are its level codes, NaN its
+    NA); with none stated the features are ``f0..``, float64.  The response
+    is categorical where the table has classes."""
+    from h2o3_tpu.frame.frame import NA_CAT, ColType, Column, Frame
 
     classes = int(config["table"]["classes"])
-    cols = [Column(f"f{i}", X[:, i].astype(np.float64)) for i in range(X.shape[1])]
+    if columns is None:
+        cols = [Column(f"f{i}", X[:, i].astype(np.float64)) for i in range(X.shape[1])]
+    else:
+        cols = []
+        for i, col in enumerate(columns):
+            if col["type"] == "cat":
+                codes = np.where(np.isnan(X[:, i]), NA_CAT, X[:, i]).astype(np.int32)
+                cols.append(Column(col["name"], codes, ColType.CAT, list(col["domain"])))
+            else:
+                cols.append(Column(col["name"], X[:, i].astype(np.float64)))
     if classes >= 2:
         cols.append(Column(config["response_column"], y.astype(np.int32),
                            ColType.CAT, [str(c) for c in range(classes)]))
@@ -156,25 +240,6 @@ def timed_window(builder, config: dict, frame, seed: int, requests: List[dict]) 
     return [fit(builder, config, frame, seed, r["params"]) for r in requests]
 
 
-def extract_model(model, ref, reported: List[str]) -> dict:
-    """The program's answer as plain arrays: init margin, bin edges, trees,
-    and the ``training_metrics`` entries named in ``reported``."""
-    b = model.booster
-    trees = []
-    for tpc in b.trees_per_class:
-        trees.append([
-            ref.Tree(np.asarray(tpc.feat[i]), np.asarray(tpc.split_bin[i]),
-                     np.asarray(tpc.default_left[i]), np.asarray(tpc.is_split[i]),
-                     np.asarray(tpc.leaf[i], np.float64))
-            for i in range(tpc.ntrees)])
-    tm = model.training_metrics
-    return {"init_margin": np.asarray(b.init_margin, np.float64),
-            "edges": np.asarray(b.trees_per_class[0].edges, np.float64),
-            "trees": trees,
-            "reported": {k: float(getattr(tm, k)) for k in reported
-                         if getattr(tm, k, None) is not None}}
-
-
 def span_summary(served: dict) -> dict:
     """Where one fit's wall went, by the program's spans (for the reader of
     a result line; the per-layer metrics read the same spans)."""
@@ -220,14 +285,16 @@ def check_devices(devices, peaks: dict, chips: int, rehearse: bool) -> Optional[
 
 
 def load_named(root: str, kind: str, name: str):
-    """The module ``benchmark/<kind>/<name>.py``: a per-layer metric's reader
-    or a table generator, found by the name the data gives."""
+    """The module ``benchmark/<kind>/<name>.py``: a per-layer metric's
+    reader, a table generator or a reference, found by the name the data
+    gives."""
     path = os.path.join(root, "benchmark", kind, name + ".py")
     if not os.path.exists(path):
         raise SystemExit(f"no benchmark/{kind}/{name}.py")
     spec = importlib.util.spec_from_file_location(
         kind + "_" + name.replace("-", "_").replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # a dataclass looks its module up by name
     spec.loader.exec_module(mod)
     return mod
 
@@ -266,19 +333,24 @@ def run(*, cell: dict, config: dict, traffic: dict, seed: int,
     classes = int(config["table"]["classes"])
     builder = load_builder(config["builder"])
     block = tree_block_size()
+    # a configuration whose limits or reference cannot decide `correct` is
+    # refused here, not after the window
+    limits = checks_mod.load_limits(root, cell["config"])
+    ref = checks_mod.load_reference(root, config, limits)
 
     # -- set-up: table, frame, warm-up fit of one block -----------------------
-    X, y = load_named(root, "tables", config["table"]["generator"]).make(
-        config["table"], rows, seed)
-    frame = make_frame(X, y, config)
+    table = make_table(root, config, rows, seed)
+    frame = make_frame(table["X"], table["y"], config, table["columns"])
     warm_req = generate_requests(
         {"requests": [dict(traffic["warmup"], repeat=1)]}, seconds, block)[0]
-    before_warm = meter.snapshot()
     warm = fit(builder, config, frame, seed, warm_req["params"])
     warm_model = warm.pop("model")
     requests = generate_requests(traffic, seconds, block)
-    prewarm_scoring(warm_model, warm, meter.snapshot()["seconds"] - before_warm["seconds"],
-                    requests, rows, features, block)
+    block_program = warm_block_program(warm_model, config)
+    last = warm["blocks"][-1] if warm["blocks"] else None
+    prewarmed = prewarm_scoring(
+        warm_model, warm, meter.building_s(last["start_ns"], last["end_ns"]) if last else 0.0,
+        requests, rows, features, block)
     del warm_model
     gc.collect()
     setup_compile = meter.snapshot()
@@ -296,22 +368,19 @@ def run(*, cell: dict, config: dict, traffic: dict, seed: int,
         if trace:
             jax.profiler.stop_trace()
     window_compile = CompileMeter.delta(setup_compile, meter.snapshot())
+    window_built = meter.names[int(setup_compile["builds"]):]
     # level plans are counted when a program is traced, so the window of a
     # warm program adds none: the run's whole count says which one it ran
     plans = hist_plans()
     alloc_peak = allocator_peak(devices)
 
     # -- after the window: memory, trace, then free the program and compare ---
-    from . import reference as ref
-
-    limits = checks_mod.load_limits(root, cell["config"])
-    answers = [extract_model(s.pop("model"), ref, checks_mod.reported_metrics(limits))
-               for s in served]
+    answers = [ref.extract(s.pop("model"), list(limits)) for s in served]
     program_bytes = None
     if not rehearse:
         from . import programs
 
-        program_bytes = programs.attached_footprint(config, rows, features, classes, block)
+        program_bytes = programs.attached_footprint(block_program, rows, features, block)
     reduced = None
     if trace:
         device_ev, host_ev = trace_mod.read_xplane(trace_dir, WINDOW_MARKER)
@@ -332,15 +401,14 @@ def run(*, cell: dict, config: dict, traffic: dict, seed: int,
     problems: List[str] = []
     if window_compile["compiles"] > 0:
         failed += 1
-        problems.append(f"{window_compile['compiles']} program(s) compiled inside the window")
+        problems.append(f"{window_compile['compiles']} program(s) compiled inside the "
+                        f"window, of those built there: {window_built}")
     if not rehearse and plans.get("scatter", 0) > 0:
         failed += 1
         problems.append("this run planned the scatter histogram: not the path the cell is about")
     t_check = time.time()
-    compared = checks_mod.compare(ref, config, seed, X, y, classes, answers, block,
-                                  list(limits))
+    checks = checks_mod.decide(ref, limits, config, seed, table, answers, block)
     check_s = time.time() - t_check
-    checks = {k: (v, limits[k]) for k, v in compared.items()}
     correct = not problems and all(v <= lim for v, lim in checks.values())
     for msg in problems:
         print("FAILED: " + msg, file=sys.stderr)
@@ -354,8 +422,9 @@ def run(*, cell: dict, config: dict, traffic: dict, seed: int,
         "window_compile": window_compile, "warmup": warm, "served": served,
         "trees_built": trees_built,
         "wall_s": sum(s["wall_s"] for s in served),
-        "trace": reduced, "peak": peak,
-        "work": work_mod.tree_work(rows, features, classes, config["params"]),
+        "trace": reduced, "peak": peak, "block_program": block_program,
+        "work": work_mod.tree_work(rows, features, classes, config["params"],
+                                   table["columns"]),
     }
     out_metrics = {}
     if not rehearse:
@@ -376,10 +445,12 @@ def run(*, cell: dict, config: dict, traffic: dict, seed: int,
         "window": {"wall_s": run_ctx["wall_s"], "trees_built": trees_built,
                    "blocks": sum(len(s["blocks"]) for s in served),
                    "spans_s": [span_summary(s) for s in served],
-                   "builds": window_compile["builds"],
+                   "builds": window_compile["builds"], "built": window_built,
                    "cache_hits": window_compile["cache_hits"],
-                   "hist_plans": plans},
-        "memory": {"allocator_peak_bytes": alloc_peak, "program": program_bytes},
+                   "prewarmed": prewarmed, "hist_plans": plans},
+        # "allocator_only" under-reads: it leaves out a program's temporaries
+        "memory": {"allocator_peak_bytes": alloc_peak, "program": program_bytes,
+                   "source": "block_program" if program_bytes else "allocator_only"},
     }
     if reduced is not None:
         result["device"]["busy_s"] = reduced["busy_s"]
@@ -390,25 +461,50 @@ def run(*, cell: dict, config: dict, traffic: dict, seed: int,
     return result
 
 
-def prewarm_scoring(model, warm: dict, warm_compile_s: float, requests: List[dict],
-                    rows: int, features: int, block: int) -> None:
-    """The post-fit scoring program has the number of trees in its shapes,
-    so a budgeted fit that ends on another count than the warm-up's would
-    compile inside the window.  The warm-up's block time (less what it spent
-    compiling) says which counts the budget can end on; the programs from
-    half to twice that many blocks are built here, in set-up."""
-    budgets = [r["params"]["max_runtime_secs"] for r in requests
-               if "max_runtime_secs" in r["params"]]
-    if not budgets or not warm["blocks"]:
-        return
-    b = warm["blocks"][-1]
-    span = (b["end_ns"] - b["start_ns"]) / 1e9
-    block_s = max(span - warm_compile_s, 0.2 * span)
+def warm_block_program(model, config: dict) -> Optional[dict]:
+    """What the training block was compiled from, off the warm-up's model
+    (``programs.block_spec``); None, with a note, where it cannot be read."""
     from . import programs
 
-    counts = set()
-    for budget in budgets:
-        k = int(budget // block_s) + 1
-        counts.update(block * j for j in range(max(1, k // 2), 2 * k + 3))
+    try:
+        return programs.block_spec(model, config)
+    except Exception as e:  # the program's internals moved, or a new builder
+        print(f"note: the fitted model does not say what its block was "
+              f"compiled from ({e!r})", file=sys.stderr)
+        return None
+
+
+def prewarm_scoring(model, warm: dict, warm_building_s: float, requests: List[dict],
+                    rows: int, features: int, block: int) -> List[int]:
+    """The post-fit scoring program has the number of trees in its shapes,
+    so a fit that ends on another count than the warm-up's would compile
+    inside the window.  A request with a literal ``ntrees`` and no budget
+    ends on that count (a whole number of blocks: ``generate_requests``
+    refuses another).  For a budgeted request the warm-up's block time says
+    which counts the budget can end on: from half to twice that many blocks.
+    The block time is the warm-up block's span less ``warm_building_s``, the
+    part of it in which its program was traced, lowered and built or loaded
+    (8-9 of 14 s at depth 10 with every program in the cache: left in, the
+    ladder ended one block above the count the window really ends on).  All
+    are built here, in set-up, at most ``LADDER_MAX`` a budget (the nearest
+    to the estimate: a block span that says nothing, as of a step that
+    returns before its work is done, must not ask for thousands); returns
+    the counts."""
+    counts = {int(r["params"]["ntrees"]) for r in requests
+              if "ntrees" in r["params"] and "max_runtime_secs" not in r["params"]}
+    budgets = [r["params"]["max_runtime_secs"] for r in requests
+               if "max_runtime_secs" in r["params"]]
+    if budgets and warm["blocks"]:
+        b = warm["blocks"][-1]
+        span = (b["end_ns"] - b["start_ns"]) / 1e9
+        block_s = max(span - warm_building_s, 0.05 * span)
+        for budget in budgets:
+            k = int(budget // block_s) + 1
+            rungs = sorted(range(max(1, k // 2), 2 * k + 3), key=lambda j: abs(j - k))
+            counts.update(block * j for j in rungs[:LADDER_MAX])
     counts.discard(warm["trees_built"])
-    programs.build_scoring_programs(model, rows, features, sorted(counts))
+    if counts:
+        from . import programs
+
+        programs.build_scoring_programs(model, rows, features, sorted(counts))
+    return sorted(counts)

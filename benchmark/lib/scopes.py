@@ -8,7 +8,7 @@ Ops`` line carries its HLO text as its name (``%fusion.928 = s32[...]
 fusion(...)``) and three stats (``device_offset_ps``, ``device_duration_ps``,
 ``Time Scale Multiplier``), no ``op_name``.  So an event is mapped to its
 scope through the compiled block's text: the block is lowered again from
-the configuration's shapes for the attached devices (as
+its shapes for the attached devices (``programs.compile_block``, as
 ``programs.block_footprint`` lowers it; the persistent cache serves the
 compile) and every instruction's ``metadata={op_name="..."}`` is read.  An
 event that does carry the name as a stat (``tf_op`` / ``op_name``, as other
@@ -285,43 +285,15 @@ def _config_of(root: str, cell: str) -> dict:
         return json.load(f)
 
 
-def block_text(config: dict, rows: int, features: int, classes: int,
-               block: int, devices: Sequence) -> str:
-    """The compiled text of the training block for ``devices``: the same
-    lowering as ``programs.block_footprint`` (which returns the sizes only
-    and may not be edited here), so the same program as the window ran and
-    the same instruction names."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from h2o3_tpu.models.tree import booster
-    from h2o3_tpu.ops.pallas_histogram import _FEAT_BLOCK, _ROW_TILE
-    from h2o3_tpu.parallel.mesh import DATA_AXIS
-
+def block_text(spec: dict, rows: int, features: int, block: int,
+               devices: Sequence) -> str:
+    """The compiled text of the training block for ``devices``: the lowering
+    ``programs.block_footprint`` reads the sizes of, so the same program as
+    the window ran and the same instruction names.  ``spec`` is
+    ``programs.block_spec`` of a fitted model (a run's ``block_program``)."""
     from . import programs
 
-    mesh = Mesh(np.array(list(devices)), (DATA_AXIS,))
-    n = rows + (-rows) % (len(devices) * _ROW_TILE)
-    fb = min(_FEAT_BLOCK, features)
-    fp = features + (-features) % fb
-    dist = config["params"]["distribution"]
-    c = classes if dist == "multinomial" else 1
-    row = NamedSharding(mesh, P(DATA_AXIS))
-    row2 = NamedSharding(mesh, P(DATA_AXIS, None))
-    S = jax.ShapeDtypeStruct
-    fn = booster._make_block_fn(
-        dist, c, block, programs._tree_params(config), mesh,
-        subtract=booster._tree_subtract_enabled())
-    return fn.lower(
-        S((n, features), jnp.int32, sharding=row2),
-        S((n,), jnp.float32, sharding=row),
-        S((n,), jnp.bool_, sharding=row),
-        S((n, c), jnp.float32, sharding=row2),
-        S((block, 2), jnp.uint32, sharding=NamedSharding(mesh, P())),
-        S((fp, n), jnp.int32, sharding=NamedSharding(mesh, P(None, DATA_AXIS))),
-        None, None).compile().as_text()
+    return programs.compile_block(spec, rows, features, block, devices)[0].as_text()
 
 
 # ---------------------------------------------------------------------------
@@ -358,8 +330,8 @@ def window_scopes(run: dict, root: Optional[str] = None,
                 from h2o3_tpu.models.tree.booster import tree_block_size
 
                 op_names = scopes_from_text(block_text(
-                    _config_of(root, run["cell"]), run["rows"], run["features"],
-                    run["classes"], tree_block_size(), jax.devices()))
+                    run["block_program"], run["rows"], run["features"],
+                    tree_block_size(), jax.devices()))
             except Exception as e:  # the program's internals moved
                 print(f"note: the training block could not be lowered again "
                       f"({e!r}); the by-scope device metrics are left out",

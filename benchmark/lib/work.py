@@ -6,18 +6,23 @@ must not change the count, or a share of the roofline could rise while the
 fit slows.
 
 Per tree level that searches splits (``max_depth`` of them) every sampled
-row is read once: its sampled features' bin codes at the narrowest integer
-width that holds ``nbins + 1`` values, its gradient, hessian and node id
-(4 bytes each), and each (row, feature) makes two additions (gradient and
-hessian into its bin).  Per tree the gradient pass reads response and
-margin and writes gradient and hessian (16 bytes, 8 operations a row), and
-the margin pass reads margin and leaf id and writes the margin (12 bytes, 1
-operation a row).  A round builds one tree per class tree.
+row is read once: its sampled features' bin codes, each at the narrowest
+integer width that holds the feature's bins and the NA bucket (``nbins``
+bins, or, for a column its generator types ``cat``, a bin a level up to
+``nbins_cats``), its gradient, hessian and node id (4 bytes each), and each
+(row, feature) makes two additions (gradient and hessian into its bin).
+Per tree the gradient pass reads response and margin and writes gradient
+and hessian (16 bytes, 8 operations a row), and the margin pass reads margin
+and leaf id and writes the margin (12 bytes, 1 operation a row).  A round
+builds one tree per class tree.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, List, Optional
+
+#: H2O's default cap on the bins of a categorical column
+NBINS_CATS = 1024
 
 
 def code_bytes(nbins: int) -> int:
@@ -31,16 +36,30 @@ def class_trees(distribution: str, classes: int) -> int:
     return classes if distribution == "multinomial" else 1
 
 
-def tree_work(rows: int, features: int, classes: int, params: dict) -> Dict[str, float]:
-    """Operations and bytes of ONE boosting round (all its class trees),
-    split into the histogram levels and the per-tree passes."""
-    depth = int(params["max_depth"])
+def feature_bins(features: int, params: dict,
+                 columns: Optional[List[dict]] = None) -> List[int]:
+    """Bins of each feature: ``nbins``, or a categorical's levels capped by
+    ``nbins_cats`` where the table's ``columns`` type it so."""
     nbins = int(params["nbins"])
+    if columns is None:
+        return [nbins] * features
+    cap = int(params.get("nbins_cats", NBINS_CATS))
+    return [min(len(c["domain"]), cap) if c["type"] == "cat" else nbins
+            for c in columns]
+
+
+def tree_work(rows: int, features: int, classes: int, params: dict,
+              columns: Optional[List[dict]] = None) -> Dict[str, float]:
+    """Operations and bytes of ONE boosting round (all its class trees),
+    split into the histogram levels and the per-tree passes.  A column
+    sample takes the mean code width of the features."""
+    depth = int(params["max_depth"])
+    widths = [code_bytes(b) for b in feature_bins(features, params, columns)]
     sampled_rows = rows * float(params.get("sample_rate", 1.0))
     rate = float(params.get("col_sample_rate_per_tree", 1.0))
     sampled_feats = features if rate >= 1.0 else max(1, int(round(rate * features)))
     c = class_trees(params.get("distribution", "gaussian"), classes)
-    level_bytes = sampled_rows * (sampled_feats * code_bytes(nbins) + 12)
+    level_bytes = sampled_rows * (sampled_feats * (sum(widths) / len(widths)) + 12)
     level_ops = 2.0 * sampled_rows * sampled_feats
     hist_bytes = c * depth * level_bytes
     hist_ops = c * depth * level_ops

@@ -1,6 +1,31 @@
-"""The plain reference: histogram gradient boosting in numpy float64.
+"""The plain reference ``hist-gbm``: histogram gradient boosting in numpy
+float64, with the extraction and the comparison that decide ``correct`` for
+a configuration that names it (``"reference": "hist-gbm"``).
 
-It imports nothing of the program.  It is used in two ways:
+It imports nothing of the program.  What the harness asks of a reference
+file, and finds here: ``NUMBERS`` (every number it can compute),
+``extract(model, numbers)`` (the program's answer as plain arrays) and
+``compare(config, seed, table, answers, block, numbers)`` (the worst reading
+of each number over the window's answers).  Which of ``NUMBERS`` a
+configuration is held to is data: the keys of its limits file
+(``benchmark/limits/<config>.json``; PERF.md gives the readings each limit
+was set from).
+
+Compared, for every ``train()`` the window served, at the timed size:
+
+* ``bin_rank_gap`` — the model's bin edges are quantile edges of the data;
+* ``init_margin_gap`` — the model's starting margin against the reference's
+  own, from the response alone;
+* ``split_gap``, ``gain_forgone``, ``leaf_gap``, ``leaf_gap_mean`` — the
+  trees of up to three boosting rounds (``judged_rounds``), judged by
+  teacher forcing (``judge``);
+* ``<metric>_gap`` (``logloss_gap``, ``auc_gap``, ``mse_gap``, ``rmse_gap``)
+  — a ``training_metrics`` entry the fit reported (the program's own scoring
+  traversal over every tree it built) against the reference's float64 walk
+  of the same trees over every row: relative, but for ``auc``, which is
+  absolute.
+
+The arithmetic is used in two ways:
 
 * ``grow(..., follow=None)`` BUILDS trees from its own argmax — the control
   (gradients rounded to a lower precision first) and the planted faults run
@@ -14,11 +39,14 @@ It imports nothing of the program.  It is used in two ways:
   forcing: near-tied splits flip under any rounding, so trees are never
   compared node for node, only choice against the reference's ranking.
 
-Semantics implemented (the configuration files state them):
-quantile bin codes ``code = #edges <= x`` with NaN -> ``nbins``; per boosting
-round t the row sample ``uniform(k_r) < sample_rate`` and the column sample
-``rank(uniform(k_c)) < round(rate * F)`` with ``k_r, k_c, _ =
-split(fold_in(PRNGKey(seed), t), 3)``; bernoulli/gaussian/multinomial
+Semantics implemented (the configuration files state them): threshold
+splits on ordered bin codes (a categorical column is its level codes, as
+the program's ``label_encoder`` has it; set-valued splits need a reference
+of their own); quantile bin codes ``code = #edges <= x`` with NaN ->
+``nbins``; per boosting round t the row sample ``uniform(k_r) <
+sample_rate`` and the column sample ``rank(uniform(k_c)) < round(rate * F)``
+with ``k_r, k_c, _ = split(fold_in(PRNGKey(seed), t), 3)``;
+bernoulli/gaussian/multinomial
 gradients; gain ``0.5 * (GL^2/HL + GR^2/HR - G^2/H)`` with both children
 holding at least ``min_rows`` sampled rows and the NA bucket tried on both
 sides; a node splits while ``gain > min_split_improvement`` and depth is
@@ -28,6 +56,7 @@ left; Newton leaves ``-learn_rate * G / H`` over the sampled rows; every row
 
 from __future__ import annotations
 
+import sys
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
@@ -35,6 +64,15 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 _THREADS = 8
+
+JUDGED = ("split_gap", "gain_forgone", "leaf_gap", "leaf_gap_mean")
+#: the ``training_metrics`` entries ``score`` computes, each compared as
+#: ``<metric>_gap``: a share of the reference's value, but for those of
+#: ``ABSOLUTE``, compared as a difference
+REPORTED = ("logloss", "auc", "mse", "rmse")
+ABSOLUTE = ("auc",)
+NUMBERS = ("bin_rank_gap", "init_margin_gap") + JUDGED + tuple(
+    m + "_gap" for m in REPORTED)
 
 
 @dataclass(frozen=True)
@@ -461,3 +499,84 @@ def auc(y: np.ndarray, s: np.ndarray) -> float:
     pos = y > 0.5
     n1, n0 = int(pos.sum()), int((~pos).sum())
     return float((ranks[pos].sum() - n1 * (n1 + 1) / 2.0) / (n1 * n0))
+
+
+# ---------------------------------------------------------------------------
+# what the harness calls: the program's answer, and its comparison
+
+
+def reported_metrics(numbers) -> List[str]:
+    """Names of the ``training_metrics`` entries that ``numbers`` ask for."""
+    return [m for m in REPORTED if m + "_gap" in numbers]
+
+
+def extract(model, numbers) -> dict:
+    """The program's answer as plain arrays: init margin, bin edges, trees,
+    and the ``training_metrics`` entries that ``numbers`` ask for."""
+    b = model.booster
+    trees = []
+    for tpc in b.trees_per_class:
+        trees.append([
+            Tree(np.asarray(tpc.feat[i]), np.asarray(tpc.split_bin[i]),
+                 np.asarray(tpc.default_left[i]), np.asarray(tpc.is_split[i]),
+                 np.asarray(tpc.leaf[i], np.float64))
+            for i in range(tpc.ntrees)])
+    tm = model.training_metrics
+    return {"init_margin": np.asarray(b.init_margin, np.float64),
+            "edges": np.asarray(b.trees_per_class[0].edges, np.float64),
+            "trees": trees,
+            "reported": {k: float(getattr(tm, k)) for k in reported_metrics(numbers)
+                         if getattr(tm, k, None) is not None}}
+
+
+def judged_rounds(built: int, block: int) -> List[int]:
+    """Round 0, a round in the middle of the first block (the state handed
+    from tree to tree inside a block, at a round where gradients are no
+    longer two-valued) and, where a second block was built, its first round
+    (the state handed from block to block).  Two rounds for three where
+    only one block was built keeps the check shorter than the window."""
+    rounds = [0, block // 2] + ([block] if built > block else [])
+    return [r for r in rounds if r < built] or [0]
+
+
+def compare_one(config: dict, seed: int, X, y, classes: int,
+                answer: dict, block: int, numbers) -> Dict[str, float]:
+    p = RefParams.from_config(config["params"], seed)
+    yf = y.astype(np.float64)
+    built = len(answer["trees"][0])
+    if built == 0:
+        return {k: float("inf") for k in numbers}
+    codes = bin_codes(X, answer["edges"])
+    f0 = init_margin(p.distribution, yf, classes)
+    out = {"bin_rank_gap": bin_rank_gap(codes, p.nbins),
+           "init_margin_gap": float(np.abs(answer["init_margin"] - f0).max())}
+    judged = judge(codes, yf, p, answer, judged_rounds(built, block), classes)
+    for key, rep in judged["by_round"].items():
+        print(f"judged round.class {key}: " + " ".join(
+            f"{k}={v:.4g}" for k, v in rep.items()), file=sys.stderr)
+    out.update({k: judged[k] for k in JUDGED})
+    mine = score(codes, yf, p, answer, classes)
+    theirs = answer["reported"]
+    for name in reported_metrics(numbers):
+        if name not in mine:
+            raise SystemExit(f"the reference computes no {name!r} for "
+                             f"{p.distribution}: it has {sorted(mine)}")
+        gap = abs(theirs.get(name, float("inf")) - mine[name])
+        out[name + "_gap"] = gap if name in ABSOLUTE else gap / abs(mine[name])
+    unknown = [k for k in numbers if k not in out]
+    if unknown:
+        raise SystemExit(f"no way to compute the limits' numbers {unknown}")
+    return {k: float(out[k]) if np.isfinite(out[k]) else float("inf") for k in numbers}
+
+
+def compare(config: dict, seed: int, table: dict, answers: List[dict],
+            block: int, numbers) -> Dict[str, float]:
+    """Worst reading of each of ``numbers`` over the window's answers;
+    ``table`` holds ``X``, ``y``, ``classes`` and ``columns``."""
+    worst = {k: 0.0 for k in numbers}
+    for answer in answers:
+        one = compare_one(config, seed, table["X"], table["y"], table["classes"],
+                          answer, block, numbers)
+        for k, v in one.items():
+            worst[k] = max(worst[k], v)
+    return worst

@@ -16,11 +16,12 @@ import os
 import numpy as np
 import pytest
 
-from lib import checks, harness, reference as ref
+from lib import checks, harness
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(HERE)
 CELLS = ["gbm-higgs-d6-b256", "gbm-higgs-automl-d10"]
+ref = harness.load_named(ROOT, "references", "hist-gbm")
 
 
 def higgs_synth(rows, features, seed):
@@ -48,7 +49,7 @@ def small(request):
     return request.param, p, codes, y.astype(np.float64)
 
 
-JUDGED = checks.JUDGED
+JUDGED = ref.JUDGED
 
 
 def test_control_precision(small):
@@ -196,20 +197,23 @@ def test_other_distributions_are_data(distribution, classes, metrics, monkeypatc
     generator look-up, frame, entry, extraction and comparison, the limits'
     keys naming the metrics; and a fault planted in the program is seen."""
     config = {"builder": "h2o3_tpu.models.tree.gbm:GBM", "response_column": "y",
+              "reference": "hist-gbm",
               "table": {"generator": "linear-synth", "features": 10, "classes": classes},
               "params": {"distribution": distribution, "max_depth": 4, "nbins": 32,
                          "learn_rate": 0.1, "min_rows": 5.0, "min_split_improvement": 1e-5,
                          "sample_rate": 0.8, "col_sample_rate_per_tree": 0.8}}
     numbers = ["bin_rank_gap", "init_margin_gap", *JUDGED, *(m + "_gap" for m in metrics)]
-    X, y = harness.load_named(ROOT, "tables", "linear-synth").make(config["table"], 20_000, 7)
+    table = harness.make_table(ROOT, config, 20_000, 7)
+    X, y = table["X"], table["y"]
     builder = harness.load_builder(config["builder"])
+    named = checks.load_reference(ROOT, config, dict.fromkeys(numbers, 1.0))
 
     def read():
         served = harness.fit(builder, config, harness.make_frame(X, y, config), 7,
                              {"ntrees": 20})
-        answer = harness.extract_model(served["model"], ref, checks.reported_metrics(numbers))
+        answer = named.extract(served["model"], numbers)
         assert len(answer["trees"]) == max(classes, 1) and sorted(answer["reported"]) == metrics
-        return checks.compare(ref, config, 7, X, y, classes, [answer], 16, numbers)
+        return named.compare(config, 7, table, [answer], 16, numbers)
 
     sound = read()
     assert max(sound.values()) < 1e-3 and sound[metrics[0] + "_gap"] < 1e-6, sound

@@ -43,18 +43,25 @@ def test_a_table_generator_is_a_file_found_by_name():
         harness.load_named(ROOT, "tables", "covtype")
 
 
+def load_config(name):
+    with open(os.path.join(HERE, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
 def test_the_limits_file_names_the_numbers_compared():
     limits = checks.load_limits(ROOT, "gbm-higgs-d6-b256")
+    ref = checks.load_reference(ROOT, load_config("gbm-higgs-d6-b256"), limits)
     assert list(limits)[:2] == ["bin_rank_gap", "init_margin_gap"]
-    assert checks.reported_metrics(limits) == ["logloss", "auc"]
-    assert checks.reported_metrics(["leaf_gap", "mse_gap"]) == ["mse"]
+    assert ref.reported_metrics(limits) == ["logloss", "auc"]
+    assert ref.reported_metrics(["leaf_gap", "mse_gap"]) == ["mse"]
 
 
 @pytest.mark.parametrize("built,block,rounds", [
     (64, 16, [0, 8, 16]), (17, 16, [0, 8, 16]), (16, 16, [0, 8]), (5, 16, [0]),
     (1, 16, [0]), (10, 5, [0, 2, 5]), (5, 5, [0, 2])])
 def test_judged_rounds(built, block, rounds):
-    assert checks.judged_rounds(built, block) == rounds
+    ref = harness.load_named(ROOT, "references", "hist-gbm")
+    assert ref.judged_rounds(built, block) == rounds
 
 
 def run_ctx(trace):
@@ -111,5 +118,6 @@ def test_every_metric_of_the_benchmark_has_a_reader_and_every_cell_its_files():
         assert callable(harness.load_reader(ROOT, m["name"]))
     for cell in bench["workloads"]:
         assert os.path.exists(os.path.join(HERE, "traffic", cell["traffic"] + ".json"))
-        assert os.path.exists(os.path.join(HERE, "configs", cell["config"] + ".json"))
-        assert set(checks.JUDGED) < set(checks.load_limits(ROOT, cell["config"]))
+        limits = checks.load_limits(ROOT, cell["config"])
+        ref = checks.load_reference(ROOT, load_config(cell["config"]), limits)
+        assert set(limits) <= set(ref.NUMBERS)

@@ -51,13 +51,34 @@ def test_code_width_and_class_trees():
     assert seven["bytes"] == pytest.approx(7 * one["bytes"])
 
 
+def test_a_feature_is_as_wide_as_its_own_bins():
+    """A column the generator types ``cat`` has a bin a level, capped by
+    ``nbins_cats``; every other feature, and every feature of a generator
+    that states no columns, has ``nbins``."""
+    p = {"max_depth": 4, "nbins": 20, "distribution": "gaussian"}
+    cols = [{"name": "a", "type": "num"},
+            {"name": "origin", "type": "cat", "domain": [str(i) for i in range(300)]},
+            {"name": "carrier", "type": "cat", "domain": [str(i) for i in range(22)]}]
+    assert work.feature_bins(3, p, cols) == [20, 300, 22]
+    assert work.feature_bins(3, dict(p, nbins_cats=64), cols) == [20, 64, 22]
+    assert work.feature_bins(3, p) == [20, 20, 20]
+    wide = work.tree_work(1000, 3, 1, p, cols)
+    assert wide["hist_bytes"] == 4 * 1000 * ((1 + 2 + 1) + 12)
+    assert wide["hist_ops"] == work.tree_work(1000, 3, 1, p)["hist_ops"]
+    for name in ("gbm-higgs-d6-b256", "gbm-higgs-automl-d10"):
+        c = load(name)
+        numeric = [{"name": f"f{i}", "type": "num"} for i in range(28)]
+        assert (work.tree_work(c["table"]["rows"], 28, 2, c["params"], numeric)
+                == work.tree_work(c["table"]["rows"], 28, 2, c["params"]))
+
+
 def test_work_ignores_what_the_program_chose():
     """Only shapes go in: there is no argument for a kernel, a padding, a
     subtraction flag or a stored dtype."""
     import inspect
 
     assert list(inspect.signature(work.tree_work).parameters) == [
-        "rows", "features", "classes", "params"]
+        "rows", "features", "classes", "params", "columns"]
     c = load("gbm-higgs-d6-b256")
     base = work.tree_work(4_000_000, 28, 2, c["params"])
     assert work.tree_work(4_000_000, 28, 2, dict(c["params"], ntrees=7, learn_rate=0.5)) == base

@@ -67,9 +67,11 @@ def main() -> int:
         import jax
         from h2o3_tpu.models.tree.booster import tree_block_size
 
+        from lib import programs
+
         text = scopes.block_text(
-            config, int(config["table"]["rows"]), int(config["table"]["features"]),
-            int(config["table"]["classes"]), tree_block_size(), jax.devices())
+            programs.tiny_fit_spec(config, ROOT), int(config["table"]["rows"]),
+            int(config["table"]["features"]), tree_block_size(), jax.devices())
         if args.save_hlo:
             with open(args.save_hlo, "w") as f:
                 f.write(text)

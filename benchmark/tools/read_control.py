@@ -3,10 +3,12 @@ faults, at the cell's own size, through the plain reference alone.
 
     python benchmark/tools/read_control.py <config> --seeds 31 32 33 --rounds 0 2 5 [--rows N]
 
-The reference is put in the program's place (``reference.boost``, from its
-own argmax) once per variant — gradients and hessians rounded to the stated
-precision (bfloat16, for comparison), to the control's (fp8), and float64
-with one fault planted — and judged at ``--rounds`` as a run's answer is.
+The reference the configuration names (``benchmark/references/hist-gbm.py``
+or another that offers the same ``boost`` / ``judge``) is put in the
+program's place (``boost``, from its own argmax) once per variant —
+gradients and hessians rounded to the stated precision (bfloat16, for
+comparison), to the control's (fp8), and float64 with one fault planted —
+and judged at ``--rounds`` as a run's answer is.
 It is host numpy float64 and never touches a device.  One JSON line a seed
 and variant; PERF.md section 6 keeps the smallest of each.
 """
@@ -35,11 +37,12 @@ def main() -> int:
     ap.add_argument("--rows", type=int)
     args = ap.parse_args()
 
-    from lib import checks, harness, reference as ref
+    from lib import harness
 
     with open(os.path.join(HERE, "configs", args.config + ".json")) as f:
         config = json.load(f)
     root = os.path.dirname(HERE)
+    ref = harness.load_named(root, "references", config["reference"])
     rows = args.rows or int(config["table"]["rows"])
     classes = int(config["table"]["classes"])
     make = harness.load_named(root, "tables", config["table"]["generator"]).make
@@ -64,7 +67,7 @@ def main() -> int:
             judged = ref.judge(codes, yf, p, model, args.rounds, classes)
             print(json.dumps({"config": args.config, "seed": seed, "rows": rows,
                               "variant": fault or precision, "rounds": args.rounds,
-                              **{k: judged[k] for k in checks.JUDGED},
+                              **{k: judged[k] for k in ref.JUDGED},
                               "seconds": round(time.time() - t0, 1)}), flush=True)
     return 0
 

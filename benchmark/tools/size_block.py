@@ -5,8 +5,10 @@
 Compiles the block program (``booster._make_block_fn``) with the TPU
 compiler for a chip that is described, not attached, and prints the bytes
 of temporaries and arguments per device and their share of the chip: the
-probe the cells of this benchmark were sized with.  Nothing runs; a compile
-that passes is not a chip run.
+probe the cells of this benchmark were sized with.  What the block is
+compiled from is read off a one-tree fit of 2,000 rows by the
+configuration's own builder, on the CPU (``programs.tiny_fit_spec``); of
+the block itself nothing runs, and a compile that passes is not a chip run.
 """
 
 import argparse
@@ -39,6 +41,8 @@ def main() -> int:
     with open(os.path.join(HERE, "lib", "peaks.json")) as f:
         hbm = json.load(f)["TPU v5 lite"]["hbm_bytes"]
     rows = args.rows or int(config["table"]["rows"])
+    # before the backend is described as a TPU: this fit runs on the CPU
+    spec = programs.tiny_fit_spec(config, os.path.dirname(HERE))
     # an entry written for a described chip cannot be read back without one
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -46,8 +50,7 @@ def main() -> int:
     # ops/histogram asks jax.default_backend() to choose Pallas and bf16
     jax.default_backend = lambda: "tpu"
     mem = programs.block_footprint(
-        config, rows, int(config["table"]["features"]),
-        int(config["table"]["classes"]), args.block,
+        spec, rows, int(config["table"]["features"]), args.block,
         list(topo.devices)[:args.chips])
     mem["share_of_chip"] = mem["total"] / hbm
     mem["bytes_per_row"] = mem["total"] / (mem["padded_rows"] / args.chips)
