@@ -1750,6 +1750,12 @@ def register_all(r: RequestServer, server: H2OServer) -> None:
         trees = b.trees_per_class[cls]
         if not 0 <= t < trees.ntrees:
             raise RestError(404, f"tree {t} out of range (ntrees={trees.ntrees})")
+        from h2o3_tpu.models.tree.booster import refuse_sets
+
+        try:
+            refuse_sets(trees, "/3/Tree (a tree's thresholds)")
+        except NotImplementedError as e:
+            raise RestError(400, str(e))
         names = tree_feature_names(m.data_info, m.tree_encoding)
         feat = trees.feat[t]
         is_split = trees.is_split[t]

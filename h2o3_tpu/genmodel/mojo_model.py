@@ -334,7 +334,10 @@ class GlmMojoModel(MojoModel):
 
 class TreeMojoModel(MojoModel):
     """hex/genmodel/algos/tree/SharedTreeMojoModel.java — heap-layout walk
-    identical to models/tree/booster.py:_predict_stacked."""
+    identical to models/tree/booster.py:_predict_stacked. A model with
+    set-valued splits (``cat_levels`` in its meta) carries every node's set
+    of codes that go left as bits and is walked by membership, as
+    ``_predict_chunk_sets`` walks it."""
 
     algo = "tree"
 
@@ -346,10 +349,16 @@ class TreeMojoModel(MojoModel):
         edges = self._arrays["edges"]  # [F, B-1]
         n_bins1 = int(m["n_bins1"])
         nbins = n_bins1 - 1
-        # apply_bins (ops/histogram.py): searchsorted right, NA -> nbins
+        cat_levels = m.get("cat_levels") or []
+        # apply_bins (ops/histogram.py): searchsorted right, NA -> nbins; a
+        # categorical's code is its level, an unknown level is NA
         n, F = X.shape
         bins = np.empty((n, F), dtype=np.int64)
         for f in range(F):
+            if cat_levels and cat_levels[f]:
+                known = (X[:, f] >= 0) & (X[:, f] < cat_levels[f])
+                bins[:, f] = np.where(known, X[:, f], nbins)
+                continue
             bins[:, f] = np.searchsorted(edges[f], X[:, f], side="right")
             bins[np.isnan(X[:, f]), f] = nbins
         init_margin = self._arrays["init_margin"]
@@ -384,6 +393,7 @@ class TreeMojoModel(MojoModel):
             default_left = self._arrays[f"default_left_{c}"]
             is_split = self._arrays[f"is_split_{c}"]
             leaf = self._arrays[f"leaf_{c}"]
+            split_set = self._arrays[f"split_set_{c}"] if cat_levels else None
             T = feat.shape[0]
             total = np.zeros(n, dtype=np.float64)
             for t in range(T):
@@ -392,7 +402,12 @@ class TreeMojoModel(MojoModel):
                     f_ = feat[t][idx]
                     b = bins[np.arange(n), f_]
                     is_na = b >= n_bins1 - 1
-                    go_left = np.where(is_na, default_left[t][idx], b <= split_bin[t][idx])
+                    if split_set is None:
+                        in_set = b <= split_bin[t][idx]
+                    else:
+                        bc = np.minimum(b, nbins - 1)
+                        in_set = (split_set[t][idx, bc >> 5] >> (bc & 31).astype(np.uint32)) & 1 == 1
+                    go_left = np.where(is_na, default_left[t][idx], in_set)
                     nxt = 2 * idx + np.where(go_left, 1, 2)
                     idx = np.where(is_split[t][idx], nxt, idx)
                 total += leaf[t][idx]

@@ -317,12 +317,15 @@ class Model:
         return f"<{type(self).__name__} {self.key} metrics={self.training_metrics!r}>"
 
 
-def fit_profile(train_span: telemetry.Span) -> Dict[str, Dict[str, float]]:
+def fit_profile(train_span: telemetry.Span,
+                counts: Tuple[str, ...] = ()) -> Dict[str, Dict[str, float]]:
     """Where a fit's wall went, from its own spans in the timeline ring:
     seconds (``s``) and count (``n``) of every kind of span under
     ``train_span``; a kind met under ``model_performance`` is keyed
     ``score/<kind>``, so the entry's ``tree_matrix`` and the scoring's stay
-    apart.  Empty where the ring no longer holds the fit."""
+    apart; the fields named in ``counts`` (the builder's
+    ``profile_counts``) are summed beside them.  Empty where the ring no
+    longer holds the fit."""
     from h2o3_tpu.util import timeline
 
     spans = {e["span_id"]: e for e in timeline.snapshot(timeline.CAPACITY)
@@ -341,13 +344,17 @@ def fit_profile(train_span: telemetry.Span) -> Dict[str, Dict[str, float]]:
         slot = out.setdefault(key, {"s": 0.0, "n": 0})
         slot["s"] = round(slot["s"] + e["duration_ms"] / 1e3, 6)
         slot["n"] += 1
+        for name in counts:
+            if name in e:
+                slot[name] = slot.get(name, 0) + int(e[name])
     return out
 
 
 def _profile_text(profile: Dict[str, Dict[str, float]]) -> str:
     """``tree_block 35.89s x4, score/apply_bins 9.52s, ...`` longest first."""
     return ", ".join(
-        "%s %.2fs%s" % (k, v["s"], " x%d" % v["n"] if v["n"] > 1 else "")
+        "%s %.2fs%s%s" % (k, v["s"], " x%d" % v["n"] if v["n"] > 1 else "",
+                          "".join(" %s=%d" % (c, x) for c, x in v.items() if c not in ("s", "n")))
         for k, v in sorted(profile.items(), key=lambda kv: -kv[1]["s"]))
 
 
@@ -370,6 +377,10 @@ class ModelBuilder:
     #: worst user-facing behavior; this guard makes them structurally
     #: impossible).
     SUPPORTED_COMMON: frozenset = frozenset()
+
+    #: fields of the fit's spans that are counts: ``fit_profile`` sums them
+    #: beside a kind's seconds
+    profile_counts: Tuple[str, ...] = ()
 
     #: guarded field -> its dataclass default
     _GUARDED_DEFAULTS = {
@@ -454,7 +465,7 @@ class ModelBuilder:
                 iters = getattr(model, "iterations", None)
                 if isinstance(iters, (int, float)):
                     span.set(iterations=int(iters))
-            model.fit_profile = fit_profile(span)
+            model.fit_profile = fit_profile(span, self.profile_counts)
             _FIT_SECONDS.observe(model.run_time, algo=self.algo_name)
             _FITS.inc(algo=self.algo_name, outcome="ok")
             self.job.done()

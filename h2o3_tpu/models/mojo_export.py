@@ -81,6 +81,9 @@ def _payload(model: Model) -> Payload:
             "max_depth": int(t0.max_depth),
             "average": bool(b.average),
             "tree_encoding": getattr(model, "tree_encoding", "label_encoder"),
+            # per tree feature, the levels of a categorical that splits on
+            # sets of them (categorical_encoding="enum"), 0 for any other
+            "cat_levels": [int(v) for v in getattr(t0, "cat_levels", ())],
             # offset models shift the margin by the scoring frame's offset
             # column (Model.java offset handling) — the MOJO must too
             "offset_column": getattr(model.params, "offset_column", None),
@@ -95,6 +98,10 @@ def _payload(model: Model) -> Payload:
             arrays[f"default_left_{c}"] = np.stack(trees.default_left).astype(bool)
             arrays[f"is_split_{c}"] = np.stack(trees.is_split).astype(bool)
             arrays[f"leaf_{c}"] = np.stack(trees.leaf).astype(np.float32)
+            if getattr(trees, "cat_levels", ()):
+                # [T, M, ceil(B/32)]: bit b & 31 of a node's word b >> 5 set
+                # iff code b goes left
+                arrays[f"split_set_{c}"] = np.stack(trees.split_set).astype(np.uint32)
         return meta, arrays
 
     if isinstance(model, KMeansModel):

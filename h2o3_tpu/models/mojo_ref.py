@@ -19,9 +19,11 @@ Reference (format spec, mirrored byte-for-byte):
 The writer emits GBM models in this exact layout; ``read_mojo`` is an
 INDEPENDENT decoder implementing the ``SharedTreeMojoModel.scoreTree``
 byte-walk, used by the parity tests (write -> decode -> score must
-equal in-framework predict). It handles float splits only; bitset
-categorical splits are rejected loudly — this framework's boosters
-label-encode categoricals, so the writer never emits them.
+equal in-framework predict). A split on a set of a categorical's levels
+(``categorical_encoding="enum"``) is written as the reference's bitset
+split: nodeType equal bits 12, then 2B bit offset, 4B bit count and the
+bytes of the levels that go RIGHT (``GenmodelBitSet.fill3`` / ``contains``);
+a level outside the bitset follows the NA direction, as at fit.
 """
 
 from __future__ import annotations
@@ -60,7 +62,15 @@ def _encode_subtree(trees, t: int, i: int, edges, raw_thresh=None) -> bytes:
     if not is_split[i]:
         return struct.pack("<f", float(trees.leaf[t][i]))
     f = int(trees.feat[t][i])
-    if raw_thresh is not None:
+    cats = getattr(trees, "cat_levels", ())
+    levels = cats[f] if cats and raw_thresh is None else 0
+    if levels:
+        # the levels that go right, a bit a level from bit offset 0
+        left = np.unpackbits(np.ascontiguousarray(
+            trees.split_set[t][i].astype("<u4")).view(np.uint8), bitorder="little")[:levels]
+        split = (struct.pack("<H", 0) + struct.pack("<i", levels)
+                 + np.packbits(left == 0, bitorder="little").tobytes())
+    elif raw_thresh is not None:
         thr = float(raw_thresh[i])
     else:
         sb = int(trees.split_bin[t][i])
@@ -73,7 +83,7 @@ def _encode_subtree(trees, t: int, i: int, edges, raw_thresh=None) -> bytes:
     left_leaf = not is_split[2 * i + 1]
     right_leaf = not is_split[2 * i + 2]
 
-    node_type = 0  # equal == 0: float compare
+    node_type = 12 if levels else 0  # equal == 0: float compare; 12: bitset
     if left_leaf:
         node_type |= 48
         offset = b""
@@ -91,7 +101,7 @@ def _encode_subtree(trees, t: int, i: int, edges, raw_thresh=None) -> bytes:
     out.append(node_type)
     out += struct.pack("<H", f)
     out.append(na_dir)
-    out += struct.pack("<f", thr)
+    out += split if levels else struct.pack("<f", thr)
     out += offset
     out += left
     out += right
@@ -1219,8 +1229,13 @@ def write_mojo(model, path: str) -> str:
     supervised = True
     columns = list(names) + [model.params.response_column]
     cat_domains: Dict[int, List[str]] = {}
-    # label-encoded tree features are numeric to the MOJO; only the
-    # response carries a domain
+    # label-encoded tree features are numeric to the MOJO: only the response
+    # and the columns that split on sets of their levels carry a domain
+    enc = getattr(model, "tree_encoding", "label_encoder")
+    if enc == "enum":
+        for ci, name in enumerate(names):
+            if name in model.data_info.cat_domains:
+                cat_domains[ci] = list(model.data_info.cat_domains[name])
     if dom:
         cat_domains[len(columns) - 1] = list(dom)
 
@@ -1264,10 +1279,9 @@ def write_mojo(model, path: str) -> str:
         info.append(("binomial_double_trees", "false"))
     # mojo_version >= 1.40 readers call readkv("_genmodel_encoding")
     # .toString() unconditionally (SharedTreeMojoReader.java:25-28)
-    enc = getattr(model, "tree_encoding", "label_encoder")
     info.append(("_genmodel_encoding",
-                 "OneHotExplicit" if enc == "one_hot_explicit"
-                 else "LabelEncoder"))
+                 {"one_hot_explicit": "OneHotExplicit", "enum": "Enum"}.get(
+                     enc, "LabelEncoder")))
     lines = ["[info]"]
     lines += [f"{k} = {v}" for k, v in info]
     lines.append("")
@@ -1315,8 +1329,8 @@ class RefMojo:
         return int(self.info.get("n_classes", 1))
 
     def score_tree(self, tree: bytes, row: np.ndarray) -> float:
-        """Exact scoreTree walk (SharedTreeMojoModel.java:130-215),
-        float-split subset."""
+        """Exact scoreTree walk (SharedTreeMojoModel.java:130-215): float
+        splits and large bitset splits (equal bits 12)."""
         pos = 0
         while True:
             node_type = tree[pos]; pos += 1
@@ -1328,20 +1342,31 @@ class RefMojo:
             leftward = na_dir in (2, 4)
             lmask = node_type & 51
             equal = node_type & 12
-            if equal != 0:
+            if equal not in (0, 12):
                 raise ValueError(
-                    "bitset categorical splits are not supported by this "
-                    "reader (label-encoded models use float splits)")
+                    "small bitset splits (equal bits 8) are not supported by "
+                    "this reader: the writer emits float and large bitset splits")
             split_val = None
             if not na_vs_rest:
-                split_val = struct.unpack_from("<f", tree, pos)[0]; pos += 4
+                if equal == 0:
+                    split_val = struct.unpack_from("<f", tree, pos)[0]; pos += 4
+                else:  # GenmodelBitSet.fill3
+                    bitoff = struct.unpack_from("<H", tree, pos)[0]; pos += 2
+                    nbits = struct.unpack_from("<i", tree, pos)[0]; pos += 4
+                    bits_at = pos
+                    pos += ((nbits - 1) >> 3) + 1
             d = row[col_id]
-            if np.isnan(d):
+            in_range = not np.isnan(d) and (
+                equal == 0 or na_vs_rest or 0 <= int(d) - bitoff < nbits)
+            if not in_range:  # NA, and a level the bitset does not know
                 go_right = not leftward
             elif na_vs_rest:
                 go_right = False
-            else:
+            elif equal == 0:
                 go_right = d >= split_val
+            else:  # GenmodelBitSet.contains
+                idx = int(d) - bitoff
+                go_right = bool(tree[bits_at + (idx >> 3)] & (1 << (idx & 7)))
             if go_right:
                 if lmask <= 3:
                     n = int.from_bytes(tree[pos:pos + lmask + 1], "little")

@@ -48,9 +48,12 @@ def _tree_tables(model):
     """Flatten the booster into per-class per-tree node tables with raw
     float thresholds (bin edge at the split bin; +inf when the split only
     separates NA from non-NA)."""
+    from h2o3_tpu.models.tree.booster import refuse_sets
+
     b = model.booster
     out = []
     for trees in b.trees_per_class:
+        refuse_sets(trees, "pojo (POJO/C code generation)")
         edges = trees.edges  # [F, B-1]
         cls_trees = []
         for t in range(trees.ntrees):

@@ -204,8 +204,12 @@ class RuleFit(ModelBuilder):
 def _extract_rules(tree_model, info) -> List[Rule]:
     """Every root→node path of every tree becomes a rule
     (hex/rulefit/RuleExtractor.java walks all nodes, not just leaves)."""
+    from h2o3_tpu.models.tree.booster import refuse_sets
+
     out: List[Rule] = []
     booster = tree_model.booster
+    for trees in booster.trees_per_class:
+        refuse_sets(trees, "rulefit (rule extraction)")
     edges = booster.trees_per_class[0].edges
     names = info.coef_names
     for trees in booster.trees_per_class:

@@ -46,9 +46,18 @@ from h2o3_tpu.ops.histogram import (
     apply_bins,
     build_histogram_sharded,
     make_bins,
+    na_code,
 )
 from h2o3_tpu.parallel.mesh import default_mesh, row_sharding
+from h2o3_tpu.util import telemetry
 from h2o3_tpu.util.telemetry import Span
+
+TREE_SPLITS = telemetry.counter(
+    "tree_splits_total",
+    "split nodes of the trees read back from the device, by what the split "
+    "tests: membership in a set of a categorical's levels, or a threshold",
+    labels=("kind",),
+)
 
 #: boosting rounds fused into one XLA program when no monitor is active
 #: (overridable via H2O3_TPU_TREE_BLOCK); also the deadline-check cadence
@@ -76,6 +85,17 @@ class TreeParams:
     col_sample_rate_per_tree: float = 1.0
     mtries: int = -1  # features per split; -1 = all (DRF uses sqrt/thirds)
     seed: int = 42
+    #: per tree feature, the levels of a categorical column that splits on
+    #: sets of its levels (categorical_encoding="enum") and 0 for a feature
+    #: that splits on a threshold; () where no feature is categorical
+    cat_levels: Tuple[int, ...] = ()
+
+    @property
+    def n_bins1(self) -> int:
+        """Width of every feature's bin axis, the NA bucket included: all
+        features are padded to the widest (``nbins``, or a categorical's
+        levels where it has more)."""
+        return na_code(self.nbins, self.cat_levels) + 1
 
 
 class Trees:
@@ -84,28 +104,62 @@ class Trees:
     Per tree: feat[M] int32, split_bin[M] int32, default_left[M] bool,
     is_split[M] bool, leaf[M] f32 (learn-rate scaled), with
     M = 2^(max_depth+1)-1. Stored stacked: [T, M] per field.
+
+    Where a feature is categorical (``cat_levels``, as ``TreeParams`` has
+    it) every node also holds ``split_set`` [M, W] uint32, W = ceil(B/32):
+    bit ``b & 31`` of word ``b >> 5`` is set iff a row whose code of the
+    split feature is b goes left. For a split on a categorical that is the
+    set of levels the split search chose, with the NA side's bit for a level
+    no row of the node had; for a threshold split it is the range
+    ``0..split_bin``; the NA bucket itself follows ``default_left``.
+    ``split_bin`` of a set-valued split is the length of the chosen prefix of
+    the node's level order, less one, and says nothing without that order:
+    read the set. A numeric ensemble holds the five arrays and no sets.
     """
 
-    def __init__(self, max_depth: int, n_bins1: int, edges: np.ndarray):
+    #: an ensemble saved before there were sets has neither field
+    cat_levels: Tuple[int, ...] = ()
+    split_set: Optional[List[np.ndarray]] = None
+
+    def __init__(self, max_depth: int, n_bins1: int, edges: np.ndarray,
+                 cat_levels: Tuple[int, ...] = ()):
         self.max_depth = max_depth
         self.n_bins1 = n_bins1
         self.edges = edges  # [F, B-1] for re-binning at predict time
+        self.cat_levels = tuple(int(v) for v in cat_levels)
         self.feat: List[np.ndarray] = []
         self.split_bin: List[np.ndarray] = []
         self.default_left: List[np.ndarray] = []
         self.is_split: List[np.ndarray] = []
         self.leaf: List[np.ndarray] = []
+        if self.cat_levels:
+            self.split_set: List[np.ndarray] = []
 
-    def append(self, feat, split_bin, default_left, is_split, leaf) -> None:
+    def append(self, feat, split_bin, default_left, is_split, leaf,
+               split_set=None) -> None:
         self.feat.append(np.asarray(feat))
         self.split_bin.append(np.asarray(split_bin))
         self.default_left.append(np.asarray(default_left))
         self.is_split.append(np.asarray(is_split))
         self.leaf.append(np.asarray(leaf))
+        if self.cat_levels:
+            self.split_set.append(np.asarray(split_set))
+
+    def extend(self, other: "Trees") -> None:
+        """Take over another ensemble's trees (checkpoint-continue)."""
+        if other.cat_levels != self.cat_levels:
+            raise ValueError("checkpoint categorical levels mismatch")
+        for name in ("feat", "split_bin", "default_left", "is_split", "leaf") + (
+                ("split_set",) if self.cat_levels else ()):
+            getattr(self, name).extend(getattr(other, name))
 
     @property
     def ntrees(self) -> int:
         return len(self.feat)
+
+    def bin(self, X: np.ndarray) -> np.ndarray:
+        """Raw features to this ensemble's bin codes."""
+        return apply_bins(X, self.edges, self.cat_levels)
 
     def stacked(self):
         return (
@@ -115,6 +169,23 @@ class Trees:
             jnp.asarray(np.stack(self.is_split)),
             jnp.asarray(np.stack(self.leaf)),
         )
+
+
+def no_sets(what: str) -> NotImplementedError:
+    """The refusal of a path that reads a split as a threshold."""
+    return NotImplementedError(
+        f"{what} does not support set-valued splits on categorical "
+        "columns (categorical_encoding='enum'); train with "
+        "categorical_encoding='label_encoder' or 'one_hot_explicit'")
+
+
+def refuse_sets(trees: "Trees", what: str) -> None:
+    """``what`` reads ``split_bin`` as a threshold and bins by the edges
+    alone: it can carry neither a split on a set of a categorical's levels
+    nor the codes of a fit that binned a level a bin, and says so instead
+    of scoring it wrong."""
+    if getattr(trees, "cat_levels", ()):
+        raise no_sets(what)
 
 
 # ---------------------------------------------------------------------------
@@ -191,9 +262,66 @@ def grad_hess_device(objective: str, y, margin):
 # traced level-step pieces
 
 
+def _order_levels(real, cat_idx: Tuple[int, ...], lam):
+    """The categorical features' bins of ``real`` [K, F, B, 3] put in the
+    order their prefixes are searched in: levels that hold a row by
+    Σg/(Σh+λ) ascending, ties by level, levels with no row last. One sort
+    carries the sums along (no gather by the order), over the categoricals'
+    slices alone. Returns ``real`` with those slices reordered, and for the
+    categoricals [K, Fc, B]: every level's key, the sorted keys, the sorted
+    levels, and whether a level holds a row."""
+    F = real.shape[1]
+    rc = jnp.stack([real[:, f] for f in cat_idx], axis=1)  # [K, Fc, B, 3]
+    code = jnp.broadcast_to(jnp.arange(rc.shape[2], dtype=jnp.int32), rc.shape[:3])
+    present = rc[..., 2] > 0
+    ratio = rc[..., 0] / jnp.maximum(rc[..., 1] + lam, 1e-12)
+    key = jnp.where(present, jnp.clip(ratio, -3e38, 3e38), jnp.inf)
+    key_s, code_s, g_s, h_s, c_s = jax.lax.sort(
+        (key, code, rc[..., 0], rc[..., 1], rc[..., 2]), dimension=2, num_keys=2)
+    rc = jnp.stack([g_s, h_s, c_s], axis=-1)
+    # back beside the numeric features' slices, which keep their code order
+    pieces, start = [], 0
+    for j, f in enumerate(cat_idx):
+        if f > start:
+            pieces.append(real[:, start:f])
+        pieces.append(rc[:, j:j + 1])
+        start = f + 1
+    if start < F:
+        pieces.append(real[:, start:])
+    return jnp.concatenate(pieces, axis=1), key, key_s, code_s, present
+
+
+def _winner_set(best_f, best_b, dl, cat_idx, key, key_s, code_s, present):
+    """left [K, B] bool of the chosen split of every node: does a row whose
+    code of the chosen feature is b go left. A level is in the chosen prefix
+    iff its (key, level) is at or before the prefix's last, so no inverse of
+    the order is needed; the chosen feature's rows are taken by masked sums
+    over the few categoricals, not by a gather."""
+    B = key.shape[2]
+    mine = best_f[:, None] == jnp.asarray(cat_idx, jnp.int32)[None, :]  # [K, Fc]
+    is_set = jnp.any(mine, axis=1)
+    at_b = jnp.arange(B, dtype=best_b.dtype)[None, :] == best_b[:, None]  # [K, B]
+
+    def of_best(a):  # [K, Fc, B] -> the chosen feature's [K, B]
+        return jnp.sum(jnp.where(mine[:, :, None], a, jnp.zeros((), a.dtype)), axis=1)
+
+    def at_best(a):  # [K, B] -> the chosen position's [K, 1]
+        return jnp.sum(jnp.where(at_b, a, jnp.zeros((), a.dtype)), axis=1,
+                       keepdims=True)
+
+    key_f = of_best(key)
+    last_key, last_code = at_best(of_best(key_s)), at_best(of_best(code_s))
+    code = jnp.arange(B, dtype=jnp.int32)[None, :]
+    in_prefix = (key_f < last_key) | ((key_f == last_key) & (code <= last_code))
+    absent = of_best((~present).astype(jnp.int32)) > 0
+    in_set = jnp.where(absent, dl[:, None], in_prefix)
+    return jnp.where(is_set[:, None], in_set, code <= best_b[:, None])
+
+
 def _split_search(
     hist, lam, alpha, gamma, lr, feat_mask, min_rows: float, n_bins1: int,
     constraints=None, node_lo=None, node_hi=None, child_stats: bool = False,
+    cat_levels: Tuple[int, ...] = (),
 ):
     """Per-node best split over (feature, bin, NA-direction).
 
@@ -214,6 +342,18 @@ def _split_search(
     clipped into the node's inherited bounds — the same two-sided design as
     the reference's GBM monotone path (hex/tree/gbm/GBM.java) and XGBoost's
     monotone_constraints.
+
+    Set-valued mode (cat_levels, as ``TreeParams`` has it, with a
+    categorical among them): a categorical's levels that hold a row are
+    ordered by Σg/(Σh+λ) ascending, ties by level (for the second-order
+    objective the best two-way partition of the levels is a prefix of this
+    order), and its candidates are the prefixes of that order, with the NA
+    bucket on either side, beside the thresholds of the numeric features.
+    Only the categoricals' slices are sorted. ``bin`` of such a winner is
+    the chosen prefix's length less one, and one more array is returned
+    last: left [K, B] bool, whether a row with code b of the chosen feature
+    goes left — the prefix's levels, ``default_left`` for a level of a
+    categorical that no row of the node has, and ``0..bin`` for a threshold.
     """
     B = n_bins1 - 1
     total = hist.sum(axis=2)  # [K, F, 3] — identical across F
@@ -223,7 +363,11 @@ def _split_search(
 
     real = hist[:, :, :B, :]
     na = hist[:, :, B, :]  # [K, F, 3]
-    cum = jnp.cumsum(real, axis=2)  # bins <= b on the left
+    cat_idx = tuple(f for f, v in enumerate(cat_levels) if v)
+    if cat_idx:
+        with jax.named_scope("sets"):
+            real, key, key_s, code_s, present = _order_levels(real, cat_idx, lam)
+    cum = jnp.cumsum(real, axis=2)  # bins <= b (the order's first b+1) on the left
 
     def thresh(g):
         return jnp.sign(g) * jnp.maximum(jnp.abs(g) - alpha, 0.0)
@@ -280,6 +424,12 @@ def _split_search(
         go_left_better.reshape(go_left_better.shape[0], -1), best[:, None], axis=1
     )[:, 0]
 
+    sets = ()
+    if cat_idx:
+        with jax.named_scope("sets"):
+            sets = (_winner_set(best_f, best_b, dl, cat_idx, key, key_s, code_s,
+                                present),)
+
     # leaf value if this node terminates (Newton step, L1-thresholded, lr-scaled)
     raw_leaf = opt_w(G, H)
     if constraints is not None:
@@ -302,8 +452,8 @@ def _split_search(
         best_wr = opt_w(G - gl_b, H - hl_b)
         left_small = 2.0 * cl_b <= CNT
         return (best_f, best_b, dl, best_gain, lr * raw_leaf,
-                best_wl, best_wr, left_small)
-    return best_f, best_b, dl, best_gain, lr * raw_leaf
+                best_wl, best_wr, left_small) + sets
+    return (best_f, best_b, dl, best_gain, lr * raw_leaf) + sets
 
 
 def _sel_table(table, idx):
@@ -367,6 +517,102 @@ def _predict_stacked(bins, feat, split_bin, default_left, is_split, leaf, max_de
     return out
 
 
+# -- set-valued splits: a node's fields and its set of codes in one lookup ---
+#
+# A row needs, of its node, the split feature, default_left, is_split and
+# ONE bit of the node's set: the bit of the row's own code. The masked sums
+# above would pay one pass over [N, K] for every word of the set (ten for
+# 300 levels). So the node's fields go into a [rows, K] table of bytes and
+# every row's column of it is fetched by one matmul with the one-hot of the
+# row's node: bytes are exact in bfloat16, the one-hot is fused into the
+# product, and the MXU does in one pass what the VPU would do in forty.
+
+
+def _pack_words(left):
+    """left [K, B] bool -> the node's set [K, ceil(B/32)] uint32, bit
+    ``b & 31`` of word ``b >> 5`` standing for code b."""
+    K, B = left.shape
+    W = -(-B // 32)
+    bits = jnp.pad(left, ((0, 0), (0, W * 32 - B))).reshape(K, W, 32)
+    return jnp.sum(bits.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32),
+                   axis=2, dtype=jnp.uint32)
+
+
+def _word_bytes(words):
+    """uint32 [..., W] -> its bytes, low first, int32 in 0..255 [..., 4W]:
+    byte ``b >> 3`` holds code b's bit at ``b & 7``."""
+    by = (words[..., None] >> (8 * jnp.arange(4, dtype=jnp.uint32))) & 0xFF
+    return by.reshape(*words.shape[:-1], 4 * words.shape[-1]).astype(jnp.int32)
+
+
+def _byte_lookup(table, idx):
+    """table[:, idx] for a table [R, K] of bytes (int32 in 0..255) and a big
+    idx [N]: [R, N] bfloat16, exact. One matmul with the one-hot of idx."""
+    K = table.shape[1]
+    onehot = (jnp.arange(K, dtype=idx.dtype)[:, None] == idx[None, :])
+    return jnp.dot(table.astype(jnp.bfloat16), onehot.astype(jnp.bfloat16),
+                   preferred_element_type=jnp.bfloat16)
+
+
+def _set_route(bins, k, feat, default_left, is_split, split_set, n_bins1):
+    """(go_left, is_split) [N] of rows at nodes ``k`` of a level whose K
+    nodes have the fields given ([K] each, split_set [K, W] uint32)."""
+    set_bytes = _word_bytes(split_set)  # [K, 4W]
+    nb = set_bytes.shape[1]
+    table = jnp.concatenate([
+        jnp.stack([feat & 0xFF, feat >> 8, default_left.astype(jnp.int32),
+                   is_split.astype(jnp.int32)]),
+        set_bytes.T])  # [4 + nb, K]
+    r = _byte_lookup(table, k)
+    f = r[0].astype(jnp.int32) + 256 * r[1].astype(jnp.int32)
+    b = _sel_cols(bins, f)
+    mine = (b >> 3)[None, :] == jnp.arange(nb, dtype=b.dtype)[:, None]
+    byte = jnp.sum(jnp.where(mine, r[4:], jnp.zeros((), r.dtype)),
+                   axis=0).astype(jnp.int32)
+    in_set = ((byte >> (b & 7)) & 1) == 1
+    # the NA bucket, and any code past the set (none after binning)
+    go_left = jnp.where(b >= n_bins1 - 1, r[2] > 0, in_set)
+    return go_left, r[3] > 0
+
+
+def _tree_walk_sets(bins, feat, default_left, is_split, leaf, split_set,
+                    max_depth: int, n_bins1: int):
+    """``_tree_walk`` for trees with set-valued splits (arrays [M], split_set
+    [M, W]): level by level, so a row's node is looked up among the 2^d of
+    its level and not among the whole heap."""
+    idx = jnp.zeros(bins.shape[0], dtype=jnp.int32)
+    for d in range(max_depth):
+        K, lo = 2**d, 2**d - 1
+        lvl = slice(lo, lo + K)
+        local = idx - lo  # rows that stopped above stay below lo
+        go_left, sp = _set_route(
+            bins, jnp.clip(local, 0, K - 1), feat[lvl], default_left[lvl],
+            is_split[lvl], split_set[lvl], n_bins1)
+        nxt = 2 * idx + jnp.where(go_left, 1, 2)
+        idx = jnp.where((local >= 0) & sp, nxt, idx)
+    # the leaf's four bytes by the same lookup, over the whole heap
+    u = jax.lax.bitcast_convert_type(leaf, jnp.uint32)
+    r = _byte_lookup(_word_bytes(u[:, None]).T, idx).astype(jnp.uint32)
+    bits = r[0] | (r[1] << 8) | (r[2] << 16) | (r[3] << 24)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+@partial(jax.jit, static_argnames=("max_depth", "n_bins1"), donate_argnums=(0,))
+def _predict_chunk_sets(acc, bins, feat, default_left, is_split, leaf,
+                        split_set, max_depth: int, n_bins1: int):
+    """``acc`` plus the outputs of a chunk of trees with set-valued splits
+    (arrays [T, M], split_set [T, M, W]). The caller hands over the trees a
+    chunk at a time, so the program does not depend on how many a model has."""
+
+    def one_tree(carry, tree):
+        return carry + _tree_walk_sets(bins, *tree, max_depth, n_bins1), None
+
+    with jax.named_scope("score_traverse"):
+        out, _ = jax.lax.scan(
+            one_tree, acc, (feat, default_left, is_split, leaf, split_set))
+    return out
+
+
 # ---------------------------------------------------------------------------
 # the device-resident training block
 
@@ -410,10 +656,17 @@ def _build_one_tree(
     split on a constrained feature inherit the split's midpoint as the
     shared bound) and leaf values are clipped into them.
 
+    Where a feature is categorical (``p.cat_levels``) a sixth heap array
+    holds every node's set of codes that go left (``Trees.split_set``) and
+    rows are routed by membership in it; with none the program is the one
+    it was.
+
     Returns (heap arrays [M], per-row leaf value [N]).
     """
     D = p.max_depth
-    n_bins1 = p.nbins + 1
+    n_bins1 = p.n_bins1
+    sets = any(p.cat_levels)
+    n_words = -(-(n_bins1 - 1) // 32)
     F = bins.shape[1]
     pos = jnp.zeros(bins.shape[0], dtype=jnp.int32)  # absolute heap position
     mono = constraints is not None
@@ -421,7 +674,7 @@ def _build_one_tree(
         b_lo = jnp.full((1,), -jnp.inf, jnp.float32)
         b_hi = jnp.full((1,), jnp.inf, jnp.float32)
 
-    tf_l, tb_l, tdl_l, tsp_l, tlf_l = [], [], [], [], []
+    tf_l, tb_l, tdl_l, tsp_l, tlf_l, tset_l = [], [], [], [], [], []
     prev_hist = prev_can = prev_left_small = prev_wl = prev_wr = None
     # every level and phase carries a named scope (metadata only, no
     # instruction): the profiler's device operations are summed by them
@@ -463,6 +716,8 @@ def _build_one_tree(
                 tdl_l.append(jnp.zeros(K, bool))
                 tsp_l.append(jnp.zeros(K, bool))
                 tlf_l.append(jnp.float32(p.learn_rate) * raw_leaf)
+                if sets:
+                    tset_l.append(jnp.zeros((K, n_words), jnp.uint32))
             break
         if subtract and d > 0:
             # build ONLY each parent's smaller child (one kernel slot per
@@ -520,7 +775,13 @@ def _build_one_tree(
                 node_lo=b_lo if mono else None,
                 node_hi=b_hi if mono else None,
                 child_stats=subtract,
+                cat_levels=p.cat_levels,
             )
+            if sets:
+                with jax.named_scope("sets"):
+                    node_set = _pack_words(out[-1])
+                    tset_l.append(node_set)
+                out = out[:-1]
             if mono or subtract:
                 bf, bb, dl, gain, leaf, bwl, bwr, left_small = out
             else:
@@ -536,9 +797,14 @@ def _build_one_tree(
             prev_wl, prev_wr = bwl, bwr
         with jax.named_scope(f"{lvl}/route"):
             k = jnp.clip(local, 0, K - 1)
-            f, sb, dlk, cank = _sel_tables((bf, bb, dl, can), k)
-            b = _sel_cols(bins, f)
-            go_left = jnp.where(b >= n_bins1 - 1, dlk, b <= sb)
+            if sets:
+                with jax.named_scope("sets"):
+                    go_left, cank = _set_route(
+                        bins, k, bf, dl, can, node_set, n_bins1)
+            else:
+                f, sb, dlk, cank = _sel_tables((bf, bb, dl, can), k)
+                b = _sel_cols(bins, f)
+                go_left = jnp.where(b >= n_bins1 - 1, dlk, b <= sb)
             child = 2 * (lo + k) + jnp.where(go_left, 1, 2)
             pos = jnp.where(in_lvl & cank, child, pos).astype(jnp.int32)
             if mono:
@@ -560,7 +826,7 @@ def _build_one_tree(
             jnp.concatenate(tdl_l),
             jnp.concatenate(tsp_l),
             jnp.concatenate(tlf_l),
-        )
+        ) + ((jnp.concatenate(tset_l),) if sets else ())
         pred = _sel_table(tree[4], pos)
     return tree, pred
 
@@ -582,8 +848,6 @@ def _make_block_fn(
     `weighted`/`monotone` are compile-time flags so the unweighted /
     unconstrained program is byte-identical to before (w/mono are passed as
     None and never touched)."""
-    D = p.max_depth
-    n_bins1 = p.nbins + 1
     C = n_class_trees
 
     @partial(jax.jit, donate_argnums=(3,))
@@ -637,8 +901,9 @@ def _make_block_fn(
                     margin = margin.at[:, c].add(pred)
                 outs.append(tree)
             stacked = tuple(
-                jnp.stack([outs[c][i] for c in range(C)]) for i in range(5)
-            )  # each [C, M]
+                jnp.stack([outs[c][i] for c in range(C)])
+                for i in range(len(outs[0]))
+            )  # each [C, M]; with set-valued splits a sixth, [C, M, W]
             return margin, stacked
 
         margin, trees = jax.lax.scan(one_round, margin, keys)
@@ -673,24 +938,61 @@ class BoostedTrees:
     def predict_margin(self, X: np.ndarray) -> np.ndarray:
         """Raw margins [N, C] from raw features (re-binned with stored edges)."""
         t0 = self.trees_per_class[0]
-        codes = apply_bins(X, t0.edges)
+        codes = t0.bin(X)
         cols = []
         # the codes' upload, every class's traversal and its read-back
-        with Span("score_traverse", rows=X.shape[0], trees=t0.ntrees):
+        with Span("score_traverse", rows=X.shape[0], trees=t0.ntrees) as span:
             bins = jnp.asarray(codes)
             for c, trees in enumerate(self.trees_per_class):
                 if trees.ntrees == 0:
                     cols.append(np.full(X.shape[0], self.init_margin[c], dtype=np.float64))
                     continue
-                s = _predict_stacked(
-                    bins, *trees.stacked(), max_depth=trees.max_depth,
-                    n_bins1_arr=jnp.int32(trees.n_bins1),
-                )
+                if trees.cat_levels:
+                    s, chunks = _predict_sets(bins, trees)
+                    span.set(sets=True, chunks=chunks)
+                else:
+                    s = _predict_stacked(
+                        bins, *trees.stacked(), max_depth=trees.max_depth,
+                        n_bins1_arr=jnp.int32(trees.n_bins1),
+                    )
                 s = np.asarray(jax.device_get(s), dtype=np.float64)
                 if self.average:
                     s = s / trees.ntrees
                 cols.append(self.init_margin[c] + s)
             return np.stack(cols, axis=1)
+
+
+def _predict_sets(bins, trees: Trees):
+    """Sum of the outputs of trees with set-valued splits, and the number of
+    chunks it took: a tree block's worth of trees a call, the last chunk
+    filled up with trees that are one leaf of 0, so one program serves a
+    model of any number of trees (and the one a fit's first scoring built
+    serves every later one)."""
+    chunk = tree_block_size()
+    fields = [np.stack(a) for a in (trees.feat, trees.default_left,
+                                    trees.is_split, trees.leaf, trees.split_set)]
+    short = (-trees.ntrees) % chunk
+    if short:
+        fields = [np.concatenate([a, np.zeros((short,) + a.shape[1:], a.dtype)])
+                  for a in fields]
+    acc = jnp.zeros(bins.shape[0], jnp.float32)
+    starts = range(0, trees.ntrees, chunk)
+    for t in starts:
+        acc = _predict_chunk_sets(
+            acc, bins, *(jnp.asarray(a[t:t + chunk]) for a in fields),
+            max_depth=trees.max_depth, n_bins1=trees.n_bins1)
+    return acc, len(starts)
+
+
+def _count_splits(feat, is_split, cat_levels) -> Tuple[int, int]:
+    """(splits, set-valued splits) of a block's read-back trees, ticked into
+    ``tree_splits_total``."""
+    n_split = int(is_split.sum())
+    n_set = int((is_split & np.asarray(cat_levels, bool)[feat]).sum()
+                ) if cat_levels else 0
+    TREE_SPLITS.inc(n_set, kind="set")
+    TREE_SPLITS.inc(n_split - n_set, kind="threshold")
+    return n_split, n_set
 
 
 def train_boosted(
@@ -740,6 +1042,8 @@ def train_boosted(
     lifecycle eviction. None bypasses the cache entirely.
     """
     if getattr(X, "is_dist_hist", False):
+        if params.cat_levels:
+            raise no_sets("dist_hist (tree training on a chunk-homed frame)")
         # chunk-homed training: the level loop fans hist_level ctx-DTasks
         # to the chunk homes and only histogram partials cross the wire
         from h2o3_tpu.models.tree import dist_hist as _dist_hist
@@ -775,20 +1079,21 @@ def _train_boosted(
     p = params
     nshards = mesh.devices.size
 
+    n_bins1 = p.n_bins1
+    n_cat = sum(1 for v in p.cat_levels if v)
     if resume_from is not None:
         # continue training: reuse the checkpoint's binning + f0 exactly
         init_margin = resume_from.init_margin
         edges = resume_from.trees_per_class[0].edges
-        if resume_from.trees_per_class[0].n_bins1 != p.nbins + 1:
+        if resume_from.trees_per_class[0].n_bins1 != n_bins1:
             raise ValueError("checkpoint nbins mismatch")
     else:
-        with Span("make_bins", nbins=p.nbins):
-            edges = make_bins(X, p.nbins, seed=p.seed)
-    n_bins1 = p.nbins + 1
+        with Span("make_bins", nbins=p.nbins, cat_features=n_cat):
+            edges = make_bins(X, p.nbins, seed=p.seed, cat_levels=p.cat_levels)
 
     def _place_bins():
         resident.set(hit=False)
-        bins_host = apply_bins(X, edges)
+        bins_host = apply_bins(X, edges, p.cat_levels)
         padn = (-n) % mult
         if padn:
             bh = np.concatenate(
@@ -843,7 +1148,9 @@ def _train_boosted(
             np.ascontiguousarray(edges).tobytes()
         ).hexdigest()
         bins_d, valid_d, bins_fm_d, n_pad = _devcache.cached(
-            "tree_bins", cache_token, (edges_digest, p.nbins, mult), mesh,
+            "tree_bins", cache_token,
+            (edges_digest, p.nbins, mult) + ((p.cat_levels,) if n_cat else ()),
+            mesh,
             _place_bins, frame_key=cache_frame_key,
         )
 
@@ -889,18 +1196,13 @@ def _train_boosted(
     if monotone is not None and np.any(np.asarray(monotone) != 0):
         mono_d = jnp.asarray(np.asarray(monotone, dtype=np.int32))
 
-    trees_per_class = [Trees(p.max_depth, n_bins1, edges) for _ in range(C)]
+    trees_per_class = [Trees(p.max_depth, n_bins1, edges, p.cat_levels)
+                       for _ in range(C)]
     tree_offset = 0
     if resume_from is not None:
         tree_offset = resume_from.trees_per_class[0].ntrees
         for c in range(C):
-            src = resume_from.trees_per_class[c]
-            dst = trees_per_class[c]
-            dst.feat = list(src.feat)
-            dst.split_bin = list(src.split_bin)
-            dst.default_left = list(src.default_left)
-            dst.is_split = list(src.is_split)
-            dst.leaf = list(src.leaf)
+            trees_per_class[c].extend(resume_from.trees_per_class[c])
     key = jax.random.PRNGKey(p.seed)
 
     # the block program depends on neither ntrees nor seed — normalize them
@@ -936,13 +1238,14 @@ def _train_boosted(
                 bins_d, y_d, valid_d, margin, keys, bins_fm_d, w_d, mono_d
             )
             jax.block_until_ready(margin)
-        with Span("tree_readback", trees=block):
-            tf, tb, tdl, tsp, tlf = jax.device_get(trees_dev)  # [block, C, M] each
+        with Span("tree_readback", trees=block) as readback:
+            # [block, C, M] each; with set-valued splits a sixth, [block, C, M, W]
+            fields = jax.device_get(trees_dev)
             for t in range(block):
                 for c in range(C):
-                    trees_per_class[c].append(
-                        tf[t, c], tb[t, c], tdl[t, c], tsp[t, c], tlf[t, c]
-                    )
+                    trees_per_class[c].append(*(a[t, c] for a in fields))
+            n_split, n_set = _count_splits(fields[0], fields[3], p.cat_levels)
+            readback.set(splits=n_split, set_splits=n_set)
         built += block
         if monitor is not None:
             with Span("budget_check") as check:

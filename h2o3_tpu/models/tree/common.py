@@ -2,13 +2,21 @@
 
 Reference: trees consume raw (non-standardized) predictors with categorical
 codes; ``hex/tree/SharedTree.java`` + ``hex/DataInfo`` handle the layout and
-``hex/Distribution.java`` the gradient families. Categorical handling:
-``categorical_encoding="label_encoder"`` (the default here) treats
-categorical codes as ordinal bins (the reference's sorted enum mode);
-``"one_hot_explicit"`` expands each level to an indicator feature
-(``hex/DataInfo`` OneHotExplicit) — the tree can then isolate any level
-subset via successive indicator splits, the dense stand-in for the
-reference's set-valued splits (``hex/tree/DTree.java``).
+``hex/Distribution.java`` the gradient families. Categorical handling
+(``categorical_encoding``):
+
+* ``"enum"`` — the reference's own handling (``hex/tree/DTree.java``): a
+  categorical column is binned a bin a level (at most ``nbins_cats``
+  levels; more are refused) and a node splits it on a SET of its levels,
+  the best prefix of the levels ordered by Σg/(Σh+λ); NA, a level the node
+  had no row of and a level unseen at fit follow the split's NA side;
+* ``"label_encoder"`` — level codes as ordinals: quantile-binned like a
+  numeric column and split by a threshold on the code;
+* ``"one_hot_explicit"`` — one indicator feature a level (``hex/DataInfo``
+  OneHotExplicit);
+* ``"auto"`` — ``label_encoder`` (H2O-3's ``auto`` is ``enum`` for GBM and
+  DRF; here the exporters and explainers that read a split as a threshold
+  do not carry a set yet, so ``auto`` keeps to what they can read).
 """
 
 from __future__ import annotations
@@ -25,7 +33,10 @@ from h2o3_tpu.util.telemetry import Span
 
 
 def tree_data_info(frame: Frame, y: str, ignored=()) -> DataInfo:
-    """Layout for tree models: raw numerics, label-encoded categoricals."""
+    """Layout for tree models: raw numerics, and one column of level codes
+    a categorical (``tree_matrix`` expands it under ``one_hot_explicit``;
+    whether the codes are split as ordinals or as sets is
+    ``resolve_tree_encoding``'s word)."""
     return build_data_info(
         frame, y=y, ignored=ignored, standardize=False, use_all_factor_levels=True
     )
@@ -34,12 +45,26 @@ def tree_data_info(frame: Frame, y: str, ignored=()) -> DataInfo:
 TREE_ENCODINGS = ("auto", "enum", "label_encoder", "one_hot_explicit")
 
 
+#: H2O's default cap on the bins of a categorical column
+NBINS_CATS = 1024
+
+#: counts that a tree fit's spans state and its ``fit_profile`` sums (the
+#: tree builders' ``profile_counts``): split nodes read back and how many
+#: test a set of levels (``tree_readback``), the categorical features binned
+#: a level a bin (``make_bins``), scoring walks by sets and their chunks
+#: (``score_traverse``)
+SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks")
+
+
 def resolve_tree_encoding(categorical_encoding: str) -> str:
-    """Map the categorical_encoding param to a tree matrix layout."""
-    if categorical_encoding in ("auto", "enum", "label_encoder"):
+    """Map the categorical_encoding param to how a tree treats a
+    categorical column: ``enum`` (a bin a level, set-valued splits),
+    ``label_encoder`` (ordinal codes, threshold splits; also what ``auto``
+    means) or ``one_hot_explicit``."""
+    if categorical_encoding in ("auto", "label_encoder"):
         return "label_encoder"
-    if categorical_encoding == "one_hot_explicit":
-        return "one_hot_explicit"
+    if categorical_encoding in ("enum", "one_hot_explicit"):
+        return categorical_encoding
     raise ValueError(
         f"categorical_encoding {categorical_encoding!r} not supported for "
         f"tree models; choose from {TREE_ENCODINGS}"
@@ -57,12 +82,34 @@ def tree_feature_names(info: DataInfo, encoding: str = "label_encoder") -> List[
     return names
 
 
+def tree_cat_levels(info: DataInfo, encoding: str,
+                    nbins_cats: int = NBINS_CATS) -> Tuple[int, ...]:
+    """Per tree feature, the levels of a categorical that splits on sets of
+    them and 0 for any other feature (``TreeParams.cat_levels``); () where
+    the encoding is not ``enum`` or no predictor is categorical. A column
+    with more levels than ``nbins_cats`` is refused: the reference groups
+    levels into bins there, and this build does not guess how."""
+    if encoding != "enum" or not info.cat_domains:
+        return ()
+    levels = tuple(len(info.cat_domains.get(name, ()))
+                   for name in info.predictor_names)
+    for name, n in zip(info.predictor_names, levels):
+        if n > nbins_cats:
+            raise ValueError(
+                f"categorical column {name!r} has {n} levels, more than "
+                f"nbins_cats={nbins_cats}: categorical_encoding='enum' "
+                "bins a level a bin and does not group levels; raise "
+                "nbins_cats or choose another categorical_encoding")
+    return levels if any(levels) else ()
+
+
 def tree_matrix(
     info: DataInfo, frame: Frame, encoding: str = "label_encoder"
 ) -> np.ndarray:
     """[N, F] float32 raw-feature matrix; NaN for NA.
 
-    label_encoder: cat codes as ordinals (one column per predictor).
+    label_encoder, enum: cat codes (one column per predictor; a level the
+    training domain lacks is NaN).
     one_hot_explicit: one 0/1 column per level; an NA row is NaN across the
     whole block so NA routing still learns a default direction per split.
     """
@@ -498,9 +545,12 @@ def checkpoint_booster(
         raise ValueError(
             f"checkpoint max_depth={t0.max_depth} differs from requested {p.max_depth}"
         )
-    if t0.n_bins1 != p.nbins + 1:
+    from h2o3_tpu.ops.histogram import na_code
+
+    if t0.n_bins1 != na_code(p.nbins, t0.cat_levels) + 1:
         raise ValueError(
-            f"checkpoint nbins={t0.n_bins1 - 1} differs from requested {p.nbins}"
+            f"checkpoint bin axis of {t0.n_bins1 - 1} (nbins, or the most "
+            f"levels of a categorical) differs from requested nbins {p.nbins}"
         )
     if n_features is not None and t0.edges.shape[0] != n_features:
         raise ValueError(
@@ -560,6 +610,8 @@ def monotone_array(
 class TreeModelBase(Model):
     """Common prediction path for GBM/DRF/XGBoost models."""
 
+    cat_levels: Tuple[int, ...] = ()  # a model saved before there were sets
+
     def __init__(self, params, data_info, distribution: str):
         super().__init__(params, data_info)
         self.distribution = distribution
@@ -568,6 +620,9 @@ class TreeModelBase(Model):
         self.tree_encoding = resolve_tree_encoding(
             getattr(params, "categorical_encoding", "auto")
         )
+        self.cat_levels = tree_cat_levels(
+            data_info, self.tree_encoding,
+            getattr(params, "nbins_cats", NBINS_CATS))
 
     def _predict_raw(self, frame: Frame) -> np.ndarray:
         X = tree_matrix(self.data_info, frame, encoding=self.tree_encoding)

@@ -21,6 +21,7 @@ from h2o3_tpu.models.data_info import response_vector
 from h2o3_tpu.models.framework import ModelBuilder, ModelParameters
 from h2o3_tpu.models.tree.booster import TreeParams, train_boosted
 from h2o3_tpu.models.tree.common import (
+    SPAN_COUNTS,
     TreeModelBase,
     checkpoint_booster as _checkpoint_booster,
     extra_trees as _extra_trees,
@@ -36,6 +37,7 @@ class DRFParameters(ModelParameters):
     ntrees: int = 50
     max_depth: int = 12  # reference default 20; dense level-wise capacity caps this build
     nbins: int = 20
+    nbins_cats: int = 1024  # most levels a categorical may have under enum
     min_rows: float = 1.0
     min_split_improvement: float = 1e-5
     sample_rate: float = 0.632  # reference DRF default (DRFParametersV3)
@@ -62,6 +64,7 @@ class DRF(ModelBuilder):
         {"checkpoint", "weights_column", "categorical_encoding"}
     )
     algo_name = "drf"
+    profile_counts = SPAN_COUNTS
 
     def __init__(self, params: Optional[DRFParameters] = None, **kw) -> None:
         super().__init__(params or DRFParameters(**kw))
@@ -122,6 +125,7 @@ class DRF(ModelBuilder):
             sample_rate=p.sample_rate,
             mtries=mtries,
             seed=p.actual_seed(),
+            cat_levels=model.cat_levels,
         )
 
         # objective='fixed': each tree independently fits the raw targets

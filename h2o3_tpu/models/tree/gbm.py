@@ -16,6 +16,7 @@ from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models.framework import ModelBuilder, ModelParameters
 from h2o3_tpu.models.tree.booster import TreeParams, train_boosted
 from h2o3_tpu.models.tree.common import (
+    SPAN_COUNTS,
     TreeModelBase,
     checkpoint_booster as _checkpoint_booster,
     extra_trees as _extra_trees,
@@ -31,6 +32,7 @@ class GBMParameters(ModelParameters):
     max_depth: int = 5
     learn_rate: float = 0.1
     nbins: int = 20  # reference GBM default nbins=20 (GBMParametersV3)
+    nbins_cats: int = 1024  # most levels a categorical may have under enum
     min_rows: float = 10.0
     min_split_improvement: float = 1e-5
     sample_rate: float = 1.0
@@ -60,6 +62,7 @@ class GBM(ModelBuilder):
         }
     )
     algo_name = "gbm"
+    profile_counts = SPAN_COUNTS
 
     def __init__(self, params: Optional[GBMParameters] = None, **kw) -> None:
         super().__init__(params or GBMParameters(**kw))
@@ -82,6 +85,7 @@ class GBM(ModelBuilder):
             sample_rate=p.sample_rate,
             col_sample_rate_per_tree=p.col_sample_rate_per_tree,
             seed=p.actual_seed(),
+            cat_levels=model.cat_levels,
         )
 
         history = []

@@ -161,6 +161,7 @@ def predict_contributions(
 
     Local accuracy: rows sum (plus init margin) to predict_margin exactly.
     """
+    from h2o3_tpu.models.tree.booster import refuse_sets
     from h2o3_tpu.models.tree.common import tree_matrix
     from h2o3_tpu.ops.histogram import apply_bins
 
@@ -170,6 +171,7 @@ def predict_contributions(
             "predict_contributions supports regression/binomial models"
         )
     trees = b.trees_per_class[0]
+    refuse_sets(trees, "shap (predict_contributions)")
     X = tree_matrix(model.data_info, frame, encoding=model.tree_encoding)
     bins = apply_bins(X, trees.edges)
     if background_frame is None:

@@ -21,6 +21,7 @@ from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models.framework import ModelBuilder, ModelParameters
 from h2o3_tpu.models.tree.booster import TreeParams, train_boosted
 from h2o3_tpu.models.tree.common import (
+    SPAN_COUNTS,
     TreeModelBase,
     checkpoint_booster as _checkpoint_booster,
     extra_trees as _extra_trees,
@@ -36,6 +37,7 @@ class XGBoostParameters(ModelParameters):
     max_depth: int = 6
     learn_rate: float = 0.3  # eta
     nbins: int = 256  # max_bins (hist/gpu_hist default)
+    nbins_cats: int = 1024  # most levels a categorical may have under enum
     min_rows: float = 1.0  # min_child_weight analogue on row counts
     min_split_improvement: float = 0.0
     reg_lambda: float = 1.0
@@ -66,6 +68,7 @@ class XGBoost(ModelBuilder):
         }
     )
     algo_name = "xgboost"
+    profile_counts = SPAN_COUNTS
 
     def __init__(self, params: Optional[XGBoostParameters] = None, **kw) -> None:
         super().__init__(params or XGBoostParameters(**kw))
@@ -103,6 +106,7 @@ class XGBoost(ModelBuilder):
             sample_rate=p.sample_rate,
             col_sample_rate_per_tree=p.col_sample_rate_per_tree,
             seed=p.actual_seed(),
+            cat_levels=model.cat_levels,
         )
 
         history = []

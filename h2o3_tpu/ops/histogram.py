@@ -108,11 +108,26 @@ def _note_plan(key: Tuple, impl: str) -> None:
 # quantile binning (GlobalQuantilesCalc / XGBoost sketch analogue)
 
 
+def na_code(nbins: int, cat_levels=()) -> int:
+    """The NA bucket's code, the last of the bin axis: ``nbins`` for a
+    frame of numeric features, and the widest feature's bin count where a
+    categorical column has more levels than that (every feature is padded
+    to the widest, so one code stands for NA in all of them)."""
+    return max([int(nbins), *(int(v) for v in cat_levels)])
+
+
 def make_bins(
-    X: np.ndarray, nbins: int = 256, sample: int = 200_000, seed: int = 0
+    X: np.ndarray, nbins: int = 256, sample: int = 200_000, seed: int = 0,
+    cat_levels=(),
 ) -> np.ndarray:
     """Per-feature bin edges from (sampled) quantiles. Returns [F, nbins-1]
-    interior edges; value -> bin = searchsorted(edges, v, 'right')."""
+    interior edges; value -> bin = searchsorted(edges, v, 'right').
+
+    cat_levels: per feature, the number of levels of a categorical column
+    that is binned a bin a level (``categorical_encoding="enum"``) and 0 for
+    a numeric one; empty for a frame with none.  A categorical feature has
+    no sketch: its code is its level, and its row of edges is never read
+    (+inf throughout)."""
     n, F = X.shape
     if n > sample:
         idx = np.random.default_rng(seed).choice(n, sample, replace=False)
@@ -122,6 +137,9 @@ def make_bins(
     qs = np.linspace(0, 1, nbins + 1)[1:-1]
     edges = np.empty((F, nbins - 1), dtype=np.float64)
     for f in range(F):
+        if len(cat_levels) and cat_levels[f]:
+            edges[f] = np.inf
+            continue
         col = Xs[:, f]
         col = col[~np.isnan(col)]
         if col.size == 0:
@@ -173,8 +191,13 @@ def _apply_bins_batched(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
     return out
 
 
-def apply_bins(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
-    """Quantize raw features to bin codes [N, F] int8-range; NA -> nbins.
+def apply_bins(X: np.ndarray, edges: np.ndarray, cat_levels=()) -> np.ndarray:
+    """Quantize raw features to bin codes [N, F] int8-range; NA -> the last
+    bucket (``na_code``: ``nbins`` where no feature is categorical).
+
+    cat_levels (see ``make_bins``): a categorical feature's code is its
+    level; NA, and a level the fit did not know (a code outside
+    ``0..levels-1``), take the NA bucket.
 
     Implementation is measurement-dispatched (single-core CPU numbers, see
     PR notes): for tall matrices — the booster shape, e.g. 1M x 28 — the
@@ -189,17 +212,23 @@ def apply_bins(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
     """
     X = np.asarray(X)
     n, F = X.shape
-    nbins = edges.shape[1] + 1
+    cats = {f for f in range(F) if len(cat_levels) and cat_levels[f]}
+    na = na_code(edges.shape[1] + 1, cat_levels)
     if n == 0 or F == 0:
         return np.empty((n, F), dtype=np.int32)
     with telemetry.Span("apply_bins", rows=n, features=F):
-        if F > 32 * max(n, 1):  # wide-short: loop overhead dominates
+        if F > 32 * max(n, 1) and not cats:  # wide-short: loop overhead dominates
             out = _apply_bins_batched(X, edges)
         else:
             out = np.empty((n, F), dtype=np.int32)
             for f in range(F):
-                out[:, f] = np.searchsorted(edges[f], X[:, f], side="right")
-        out[np.isnan(X)] = nbins  # NA bucket (DHistogram NA bin at end)
+                if f in cats:
+                    col = X[:, f]
+                    known = (col >= 0) & (col < cat_levels[f])  # NaN: False
+                    out[:, f] = np.where(known, col, na)
+                else:
+                    out[:, f] = np.searchsorted(edges[f], X[:, f], side="right")
+        out[np.isnan(X)] = na  # NA bucket (DHistogram NA bin at end)
     return out
 
 

@@ -148,12 +148,16 @@ def _counter(name, **labels):
 
 
 def _wire_bytes():
+    """Data-plane wire bytes: everything but the heartbeats and replica
+    checks, which tick in the background by the clock whatever the
+    workload (as ``test_rapids_dist._data_wire_bytes`` leaves them out)."""
     from h2o3_tpu.util import telemetry
 
     c = telemetry.REGISTRY.get("rpc_payload_bytes_total")
     if c is None:
         return 0.0
-    return sum(s["value"] for s in c.snapshot()["series"])
+    return sum(s["value"] for s in c.snapshot()["series"]
+               if s["labels"].get("method") not in ("heartbeat", "dkv_replica_check"))
 
 
 # ---------------------------------------------------------------------------
@@ -193,6 +197,9 @@ def test_partials_only(homed, monkeypatch):
     lay = fr.chunk_layout
     frame_bytes = 8 * int(lay["espc"][-1]) * len(lay["column_names"])
     monkeypatch.setenv("H2O3_TPU_DIST_HIST", "1")
+    # the first fit on a cloud pays its one-time traffic (1.2-1.4 MB here);
+    # measured is a fit after it, whichever test a worker runs first
+    _fit("gbm", "bin", fr)
     levels0 = _counter("dist_hist_levels_total")
     partial0 = _counter("dist_hist_partial_bytes_total")
     wire0 = _wire_bytes()
@@ -208,8 +215,8 @@ def test_partials_only(homed, monkeypatch):
     n_bins1 = 12 + 1  # interior edges + NA bin
     per_level_cap = 4 * n_feat * n_bins1 * 3 * 8 * n_homes
     assert partial <= levels * per_level_cap
-    # total wire (requests + responses, incl. the one-time y gather and
-    # gossip noise) stays well under shipping the frame to the members
+    # total wire (requests + responses, incl. the y gather and the model's
+    # puts) stays well under shipping the frame to the members
     assert wire < frame_bytes / 2
 
 
