@@ -290,13 +290,18 @@ class Model:
                 if self.params.weights_column
                 else None
             )
-            if not self.is_classifier:
-                return M.regression_metrics(y, raw, weights=w)
-            if self.nclasses == 2:
-                return M.binomial_metrics(y, raw[:, 1], weights=w)
-            return M.multinomial_metrics(
-                y.astype(np.int64), raw, self.data_info.response_domain, weights=w
-            )
+            return self._metrics(y, raw, w)
+
+    def _metrics(self, y: np.ndarray, raw: np.ndarray, w) -> Any:
+        """The ModelMetrics of this model's kind for a response, its raw
+        scores and the rows' weights (or None)."""
+        if not self.is_classifier:
+            return M.regression_metrics(y, raw, weights=w)
+        if self.nclasses == 2:
+            return M.binomial_metrics(y, raw[:, 1], weights=w)
+        return M.multinomial_metrics(
+            y.astype(np.int64), raw, self.data_info.response_domain, weights=w
+        )
 
     def pojo(self, lang: str = "c") -> str:
         """Standalone scoring source (hex/tree/TreeJCodeGen / water/codegen

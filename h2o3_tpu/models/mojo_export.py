@@ -176,8 +176,9 @@ def write_mojo(model: Model, path: str) -> str:
     # Model.predict — an explicit reset_threshold wins over the
     # training max-F1 point
     thr = getattr(model, "_threshold_override", None)
-    if thr is None:
-        thr = getattr(model.training_metrics, "max_f1_threshold", None)
+    if thr is None and getattr(
+            model.training_metrics, "max_f1_threshold", None) is not None:
+        thr = model.default_threshold()
     if thr is not None and np.isfinite(thr):
         meta["default_threshold"] = float(thr)
     buf = io.BytesIO()

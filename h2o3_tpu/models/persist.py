@@ -104,9 +104,10 @@ class _Encoder:
             for k, v in fields.items():
                 if callable(v) and not isinstance(v, type):
                     continue  # drop bound callables (monitors, caches)
-                if k == "dist_eval":
-                    # scoring shim pinned to a live DistFrame/store —
-                    # process-local by construction, never persisted
+                if k == "fit_eval":
+                    # a fit's margins of its training frame, pinned to
+                    # that live frame and dropped by the fit's own metrics
+                    # call — process-local by construction, never persisted
                     continue
                 clean[k] = v
             return {

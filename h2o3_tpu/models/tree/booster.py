@@ -930,6 +930,11 @@ class BoostedTrees:
         self.init_margin = init_margin
         self.params = params
         self.average = average
+        #: what a fit leaves for its own training metrics and the call
+        #: drops: {"frame", "y", "w", "margin"}, the margin
+        #: ``predict_margin`` would return for the rows of ``frame`` the
+        #: fit kept (``common.TreeModelBase.model_performance``)
+        self.fit_eval: Optional[dict] = None
 
     @property
     def nclasses_trees(self) -> int:
@@ -1012,6 +1017,7 @@ def train_boosted(
     monotone: Optional[np.ndarray] = None,
     cache_token=None,
     cache_frame_key: Optional[str] = None,
+    fit_eval: Optional[dict] = None,
 ) -> BoostedTrees:
     """Device-resident booster loop.
 
@@ -1040,6 +1046,10 @@ def train_boosted(
     tree of every fit — reuse the resident bin codes instead of re-binning
     and re-uploading. cache_frame_key links the entry to a DKV frame for
     lifecycle eviction. None bypasses the cache entirely.
+    fit_eval: {"frame", "y", "w"}: the frame X's rows came from and the
+    response and weights its training metrics are over. The ensemble then
+    comes back with them and the fit's final margin of those rows as its
+    ``fit_eval``, wherever that margin is the whole ensemble's.
     """
     if getattr(X, "is_dist_hist", False):
         if params.cat_levels:
@@ -1052,7 +1062,7 @@ def train_boosted(
             X, objective, y, n_class_trees, init_margin, params,
             average=average, monitor=monitor,
             score_interval=score_interval,
-            weights=weights, offset=offset)
+            weights=weights, offset=offset, fit_eval=fit_eval)
 
     if mesh is None:
         mesh = default_mesh()
@@ -1061,13 +1071,13 @@ def train_boosted(
         return _train_boosted(
             X, objective, y, n_class_trees, init_margin, params, average,
             monitor, score_interval, mesh, resume_from, weights, offset,
-            monotone, cache_token, cache_frame_key)
+            monotone, cache_token, cache_frame_key, fit_eval)
 
 
 def _train_boosted(
     X, objective, y, n_class_trees, init_margin, params, average, monitor,
     score_interval, mesh, resume_from, weights, offset, monotone,
-    cache_token, cache_frame_key,
+    cache_token, cache_frame_key, fit_eval,
 ) -> BoostedTrees:
     """The single-host body of :func:`train_boosted`, under its span."""
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1212,6 +1222,7 @@ def _train_boosted(
     p_key = _dc_replace(p, ntrees=0, seed=0)
 
     built = 0
+    final_host = None  # the last budget check's copy of the margin
     default_block = tree_block_size()
     subtract_on = _tree_subtract_enabled()
     while built < p.ntrees:
@@ -1249,10 +1260,23 @@ def _train_boosted(
         built += block
         if monitor is not None:
             with Span("budget_check") as check:
-                margin_host = np.asarray(jax.device_get(margin), np.float64)[:n]
-                stop = bool(monitor(built - 1, margin_host))
+                final_host = np.asarray(jax.device_get(margin), np.float64)[:n]
+                stop = bool(monitor(built - 1, final_host))
                 check.set(stop=stop)
             if stop:
                 break
 
-    return BoostedTrees(trees_per_class, np.asarray(init_margin, np.float64), p, average=average)
+    bt = BoostedTrees(trees_per_class, np.asarray(init_margin, np.float64), p, average=average)
+    # every block added its trees' leaves to the margin of every row, so
+    # the device holds what a walk of the ensemble over X would sum; not
+    # so for an averaged ensemble continued from a checkpoint, whose
+    # margin starts without the earlier trees
+    if fit_eval is not None and not (average and resume_from is not None):
+        if final_host is None:
+            with Span("margin_readback", bytes=margin.nbytes):
+                final_host = np.asarray(jax.device_get(margin))[:n]
+        if average and built:
+            f0 = bt.init_margin[None, :]
+            final_host = f0 + (final_host - f0) / built
+        bt.fit_eval = dict(fit_eval, margin=final_host)
+    return bt

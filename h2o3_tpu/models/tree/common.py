@@ -29,7 +29,16 @@ from h2o3_tpu.frame.frame import ColType, Frame
 from h2o3_tpu.models.data_info import DataInfo, _align_codes, build_data_info
 from h2o3_tpu.models.framework import Model
 from h2o3_tpu.models import metrics as M
+from h2o3_tpu.util import telemetry
 from h2o3_tpu.util.telemetry import Span
+
+TRAIN_METRICS = telemetry.counter(
+    "tree_train_metrics_total",
+    "metrics of a tree model over a frame, by where the margin came from: "
+    "the fit's own final margin of its training frame (fit_margin), or a "
+    "walk of the trees over the frame (walk)",
+    labels=("source",),
+)
 
 
 def tree_data_info(frame: Frame, y: str, ignored=()) -> DataInfo:
@@ -52,8 +61,10 @@ NBINS_CATS = 1024
 #: tree builders' ``profile_counts``): split nodes read back and how many
 #: test a set of levels (``tree_readback``), the categorical features binned
 #: a level a bin (``make_bins``), scoring walks by sets and their chunks
-#: (``score_traverse``)
-SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks")
+#: (``score_traverse``), metrics from the margin a fit held
+#: (``model_performance``)
+SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks",
+               "fit_margin")
 
 
 def resolve_tree_encoding(categorical_encoding: str) -> str:
@@ -624,6 +635,23 @@ class TreeModelBase(Model):
             data_info, self.tree_encoding,
             getattr(params, "nbins_cats", NBINS_CATS))
 
+    def default_threshold(self) -> float:
+        """The binomial label threshold of a tree model: not the training
+        max-F1 score itself but the middle between it and the next lower
+        training score. A training score comes from the margin the fit held
+        and ``predict``'s from a walk of the trees (as a MOJO's from its own
+        scorer): the same leaves summed in another order, equal to float32
+        rounding and not to the bit. So a row that scores what a training
+        row scored is labelled as the training metrics counted it, whichever
+        way its score was summed."""
+        thr = super().default_threshold()
+        ths = getattr(self.training_metrics, "thresholds", None)
+        if (getattr(self, "_threshold_override", None) is not None
+                or ths is None or not len(ths)):
+            return thr
+        below = int(np.searchsorted(-ths, -thr, side="right"))  # descending
+        return 0.5 * (thr + (float(ths[below]) if below < len(ths) else 0.0))
+
     def _predict_raw(self, frame: Frame) -> np.ndarray:
         X = tree_matrix(self.data_info, frame, encoding=self.tree_encoding)
         margin = self.booster.predict_margin(X)
@@ -648,8 +676,8 @@ class TreeModelBase(Model):
 
     def _raw_from_margin(self, margin: np.ndarray) -> np.ndarray:
         """Raw scores (probabilities / inverse-linked response) from the
-        ensemble margin — shared by the materializing predict path and the
-        distributed fit's margin-resident scoring."""
+        ensemble margin — shared by the walk of a frame and the metrics
+        from the margin a fit holds."""
         return (
             margin_to_probs(self.distribution, margin)
             if self.is_classifier
@@ -657,25 +685,25 @@ class TreeModelBase(Model):
         )
 
     def model_performance(self, frame: Frame) -> Any:
-        ev = getattr(self.booster, "dist_eval", None)
-        if ev is not None and frame is ev["frame"]:
-            # the distributed fit already holds this frame's final margins
-            # (over its kept rows) — score them without materializing rows
-            with Span("model_performance", rows=len(ev["y"])):
-                return self._metrics_from_dist(ev)
-        return super().model_performance(frame)
-
-    def _metrics_from_dist(self, ev: dict) -> Any:
-        raw = self._raw_from_margin(np.asarray(ev["margin"], np.float64))
-        y = np.asarray(ev["y"], np.float64)
-        w = ev.get("w")
-        if not self.is_classifier:
-            return M.regression_metrics(y, raw, weights=w)
-        if self.nclasses == 2:
-            return M.binomial_metrics(y, raw[:, 1], weights=w)
-        return M.multinomial_metrics(
-            y.astype(np.int64), raw, self.data_info.response_domain,
-            weights=w)
+        """Metrics over ``frame``. The fit's own call for its training frame
+        finds that frame's final margins with the ensemble (``fit_eval``:
+        over the rows the fit kept, the offset within), scores from them and
+        drops them; any other frame, and the training frame once they are
+        gone, is binned and walked."""
+        ev = getattr(self.booster, "fit_eval", None)
+        if ev is None or frame is not ev["frame"]:
+            TRAIN_METRICS.inc(source="walk")
+            with Span("model_performance", rows=frame.nrows, source="walk"):
+                frame = self._apply_preprocessors(frame)
+                return self._metrics_from_raw(frame, self._predict_raw(frame))
+        self.booster.fit_eval = None
+        TRAIN_METRICS.inc(source="fit_margin")
+        with Span("model_performance", rows=len(ev["y"]), source="fit_margin",
+                  fit_margin=1):
+            with Span("score_link", rows=len(ev["y"])):
+                raw = self._raw_from_margin(np.asarray(ev["margin"], np.float64))
+            with Span("score_metrics", rows=len(ev["y"])):
+                return self._metrics(np.asarray(ev["y"], np.float64), raw, ev["w"])
 
     def predict_contributions(self, frame: Frame, background_frame=None) -> Frame:
         """Exact per-feature SHAP contributions on the margin scale

@@ -997,12 +997,13 @@ def dist_drf_front(frame, p, model_cls):
 def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                        init_margin, params, average: bool = False,
                        monitor=None, score_interval: int = 1,
-                       weights=None, offset=None):
+                       weights=None, offset=None, fit_eval=None):
     """``train_boosted`` over a :class:`DistTreeMatrix`: the level loop
     fans ``hist_level`` ops, merges float64 partials in canonical group
     order, and runs the existing ``_split_search`` caller-side — the
-    result is a plain :class:`BoostedTrees` plus a ``dist_eval`` handle
-    for materialization-free scoring."""
+    result is a plain :class:`BoostedTrees`, with the fit's final margins
+    beside the rows ``fit_eval`` names (as ``train_boosted`` leaves them),
+    so the training metrics materialize no rows."""
     from h2o3_tpu.models.tree import booster as _booster
     p = params
     n_bins1 = p.nbins + 1
@@ -1192,17 +1193,15 @@ def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                 if stop:
                     break
 
-            margin_final = Xd._margins()
+            margin_score = Xd._margins()
             if average and built > 0:
                 margin_score = (f0[None, :]
-                                + (margin_final - f0[None, :]) / built)
-            else:
-                margin_score = margin_final
+                                + (margin_score - f0[None, :]) / built)
         bt = _booster.BoostedTrees(
             trees_per_class, np.asarray(init_margin, np.float64), p,
             average=average)
-        bt.dist_eval = {"frame": Xd.frame, "y": Xd.y_all, "w": Xd.w_all,
-                        "margin": margin_score}
+        if fit_eval is not None:
+            bt.fit_eval = dict(fit_eval, margin=margin_score)
         return bt
     finally:
         Xd._finish()

@@ -130,6 +130,7 @@ class XGBoost(ModelBuilder):
             monotone=mono,
             cache_token=tree_cache_token(frame, p, model.tree_encoding),
             cache_frame_key=getattr(frame, "key", None),
+            fit_eval={"frame": frame, "y": y, "w": weights},
         )
         model.ntrees_built = model.booster.trees_per_class[0].ntrees
         model.training_metrics = model.model_performance(frame)

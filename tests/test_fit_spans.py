@@ -17,8 +17,11 @@ from h2o3_tpu.util import telemetry, timeline
 
 pytestmark = pytest.mark.leaks_keys
 
-#: every kind of ISSUE 28's table B that a single-host GBM fit passes
-#: through, and the two that close its gaps (``tree_rows``, ``score_link``)
+#: every kind of ISSUE 28's table B that a single-host GBM fit with a
+#: validation frame passes through, and the two that close its gaps
+#: (``tree_rows``, ``score_link``); since ISSUE 33 the training frame is
+#: scored from the margin the fit holds, so the walk's kinds are the
+#: validation frame's
 FIT_KINDS = (
     "train", "tree_setup", "tree_matrix", "tree_rows", "train_boosted",
     "make_bins",
@@ -36,12 +39,12 @@ def _frame(rng, n=1500):
     return Frame.from_dict(d)
 
 
-def _fit(frame):
+def _fit(frame, valid=None):
     """One budgeted GBM fit (the budget makes the builder check it after
     every block) and the ring events of its trace."""
     t0 = time_ns()
     model = GBM(response_column="y", ntrees=4, max_depth=3, seed=3,
-                max_runtime_secs=600.0).train(frame)
+                max_runtime_secs=600.0).train(frame, valid)
     events = [e for e in timeline.snapshot(timeline.CAPACITY)
               if e["ns"] >= t0 and "parent_id" in e]
     train = [e for e in events if e["kind"] == "train"][-1]
@@ -57,7 +60,7 @@ def time_ns():
 @pytest.fixture(scope="module")
 def fitted():
     frame = _frame(np.random.default_rng(7))
-    return (frame,) + _fit(frame)
+    return (frame,) + _fit(frame, _frame(np.random.default_rng(8), n=700))
 
 
 @pytest.mark.parametrize("kind", FIT_KINDS)
@@ -114,10 +117,14 @@ def test_fit_profile_rides_the_model_and_the_log(fitted):
     prof = model.fit_profile
     assert prof["tree_block"]["n"] == len(
         [e for e in events if e["kind"] == "tree_block"])
-    for key in ("tree_setup", "tree_readback", "score/tree_matrix",
-                "score/apply_bins", "score/score_traverse", "score/score_link",
-                "score/score_metrics"):
-        assert prof[key]["n"] == 1 and prof[key]["s"] >= 0.0
+    # the walk's kinds once, for the validation frame; link and metrics
+    # for the training frame's margin too
+    for key, n in (("tree_setup", 1), ("tree_readback", 1), ("score/tree_matrix", 1),
+                   ("score/apply_bins", 1), ("score/score_traverse", 1),
+                   ("score/score_link", 2), ("score/score_metrics", 2)):
+        assert prof[key]["n"] == n and prof[key]["s"] >= 0.0
+    assert prof["model_performance"]["n"] == 2
+    assert prof["model_performance"]["fit_margin"] == 1
     assert "score/tree_block" not in prof
     done = [ln for ln in log.recent(500)
             if "gbm train done" in ln and str(model.key) in ln]

@@ -537,8 +537,10 @@ def test_spans_and_counter_name_the_categorical_features(fitted):
 
     before = {k: booster.TREE_SPLITS.value(kind=k) for k in ("set", "threshold")}
     a, b, x, y = table(71, n=1000)
+    # the validation frame is what the fit walks (its own frame it scores
+    # from the margin it holds)
     model = GBM(**dict(PARAMS, ntrees=2, categorical_encoding="enum")).train(
-        frame_of(a, b, x, y))
+        frame_of(a, b, x, y), frame_of(a, b, x, y))
     events = [e for e in timeline.snapshot(4096)]
     assert [e for e in events if e["kind"] == "make_bins"][-1]["cat_features"] == 2
     trees = model.booster.trees_per_class[0]
