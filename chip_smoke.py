@@ -84,10 +84,18 @@ def require_tpu(checks: Checks, platform: str, rehearse: bool) -> None:
 
 
 def training_table(rows: int, seed: int):
-    """bench.py's Higgs-shaped table: float32 [rows, 28] and a 0/1 response."""
-    from bench import synth_higgs
+    """The benchmark's Higgs-shaped table (``benchmark/tables/higgs-synth.py``,
+    loaded by path: its name has a hyphen): float32 [rows, 28] and a 0/1
+    float64 response."""
+    import importlib.util
 
-    return synth_higgs(rows, N_FEAT, seed)
+    spec = importlib.util.spec_from_file_location(
+        "tables_higgs_synth",
+        os.path.join(HERE, "benchmark", "tables", "higgs-synth.py"))
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    X, y = gen.make({"features": N_FEAT, "classes": 2}, rows, seed)
+    return X, y.astype("float64")
 
 
 def reference_auc(X, y, seed: int) -> float:

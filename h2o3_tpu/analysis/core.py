@@ -31,7 +31,7 @@ from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Set
 
 #: directories/files under the repo root the analyzer scans by default
-DEFAULT_ROOTS = ("h2o3_tpu", "scripts", "bench.py")
+DEFAULT_ROOTS = ("h2o3_tpu", "scripts")
 
 #: path fragments never analyzed (generated/vendored/fixture code)
 EXCLUDE_PARTS = ("tests/", "h2o3r/", "deploy/", "/.", "__pycache__")

@@ -12,8 +12,7 @@ predictions collect for a window and execute as one devcache-warm dispatch);
 the cluster control plane is host-side Python, device compute stays in
 jitted programs.  The same versioned route layout (/3/..., /99/Rapids) and
 JSON responses shaped like the reference's schema objects so h2o-py-style
-clients port over.  The earlier thread-per-connection transport survives in
-``server_threaded.py`` as the serving-bench baseline.
+clients port over.
 """
 
 from h2o3_tpu.api.server import H2OServer, start_server

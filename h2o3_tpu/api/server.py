@@ -9,9 +9,8 @@ Design notes (TPU-native): the REST layer is pure control plane — every
 handler manipulates host-side objects (frames, model keys, jobs) and the
 device work happens inside the models' jitted programs.  The front-end is
 an asyncio event loop in one thread (replacing both Jetty and the earlier
-thread-per-connection stand-in, preserved in ``server_threaded.py`` as the
-bench baseline): keep-alive connections, a global connection cap, per-route
-concurrency budgets and a bounded request queue.  Overload sheds with
+thread-per-connection stand-in): keep-alive connections, a global
+connection cap, per-route concurrency budgets and a bounded request queue.  Overload sheds with
 429 + ``Retry-After`` — never a hang, never an unbounded thread pile.
 Handlers stay synchronous: admitted requests run on a bounded worker pool
 off the loop, so all registered routes work unchanged.  Coalescable routes

@@ -863,7 +863,7 @@ def boot_node(
     store=None,
 ) -> Cloud:
     """One-call cluster-node bootstrap shared by the REST launcher
-    (``__main__``), the light ``nodeproc`` harness and ``bench.py``:
+    (``__main__``) and the light ``nodeproc`` harness:
     construct the Cloud, install the DKV router and DTask registry,
     publish it as the process cloud, write the resolved RPC address
     atomically, and run the synchronous join round.  On

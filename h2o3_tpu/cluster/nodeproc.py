@@ -2,15 +2,15 @@
 
 ``python -m h2o3_tpu.cluster.nodeproc --cluster-name c --node-name n1
 --address-file /tmp/n1.addr [--flatfile peers.txt]`` boots the
-application-plane node the multi-process tests and ``bench.py
---cluster-bench`` peer against: it binds port 0, writes the resolved
-``host:port`` to the address file (the rendezvous the harness folds into
-the other nodes' flatfiles), joins the cloud, and serves until its stdin
-closes or it is signalled — the harness owns its lifetime.
+application-plane node the multi-process tests peer against: it binds
+port 0, writes the resolved ``host:port`` to the address file (the
+rendezvous the harness folds into the other nodes' flatfiles), joins the
+cloud, and serves until its stdin closes or it is signalled — the harness
+owns its lifetime.
 
 The full launcher (``python -m h2o3_tpu --flatfile ...``) layers the
 REST server and JAX runtime on the same bootstrap; this entry exists so
-cluster tests and benches pay milliseconds, not a backend init, per node.
+cluster tests pay milliseconds, not a backend init, per node.
 """
 
 from __future__ import annotations

@@ -43,15 +43,17 @@ from h2o3_tpu.util import telemetry
 # ---------------------------------------------------------------------------
 # fixed-shape level plans: the node-bucket ladder
 #
-# ``n_nodes`` is a static jit argname, so every tree level 2^d used to be a
-# fresh plan (~100-250 ms of XLA compile per level, per HIST_BENCH). Padding
-# the node dimension up to a small ladder of power-of-2 buckets makes one
-# traced plan serve every level in the bucket: pad rows are zero-filled (a
-# scatter-add / one-hot contraction never touches a node id beyond the real
-# range) and the real ``n_nodes`` rows are sliced back out, so the result is
-# bit-identical to the unpadded build.
+# ``n_nodes`` is a static jit argname, so every tree level 2^d is a plan of
+# its own. Padding the node dimension up to a small ladder of power-of-2
+# buckets makes one traced plan serve every level in the bucket: pad rows are
+# zero-filled (a scatter-add / one-hot contraction never touches a node id
+# beyond the real range) and the real ``n_nodes`` rows are sliced back out,
+# so the result is bit-identical to the unpadded build. That saved a compile
+# a level while each level was a jit call of its own; today every call sits
+# inside the traced block of trees, where a shared plan saves nothing and a
+# level pays for its slots (ROADMAP S2 holds the consequence).
 
-_DEFAULT_NODE_BUCKETS = (8, 64, 512)
+_NODE_BUCKETS = (8, 64, 512)
 
 PLAN_CACHE = telemetry.counter(
     "hist_plan_cache_total",
@@ -63,23 +65,9 @@ _PLAN_LOCK = threading.Lock()
 _PLAN_KEYS: set = set()
 
 
-def node_buckets() -> Tuple[int, ...]:
-    """The node-capacity ladder from ``H2O3_TPU_HIST_NODE_BUCKETS``
-    (comma-separated, default ``8,64,512``; ``0``/empty disables padding)."""
-    raw = os.environ.get("H2O3_TPU_HIST_NODE_BUCKETS")
-    if raw is None:
-        return _DEFAULT_NODE_BUCKETS
-    try:
-        vals = sorted({int(t) for t in raw.split(",") if t.strip()})
-    except ValueError:
-        return _DEFAULT_NODE_BUCKETS
-    return tuple(v for v in vals if v > 0)
-
-
 def pad_nodes(n_nodes: int) -> int:
-    """Smallest ladder bucket >= ``n_nodes`` (identity above the ladder
-    or with the ladder disabled)."""
-    for b in node_buckets():
+    """Smallest ladder bucket >= ``n_nodes`` (identity above the ladder)."""
+    for b in _NODE_BUCKETS:
         if n_nodes <= b:
             return b
     return n_nodes
@@ -363,8 +351,6 @@ def node_totals_sharded(nodes, g, h, n_nodes: int, mesh=None, rw=None):
 def _hist_impl(impl: Optional[str]) -> str:
     """Resolve histogram implementation: Pallas MXU kernel on TPU, XLA
     scatter elsewhere. Override with H2O3_TPU_HIST_IMPL=scatter|pallas."""
-    import os
-
     impl = impl or os.environ.get("H2O3_TPU_HIST_IMPL") or (
         "pallas" if jax.default_backend() == "tpu" else "scatter"
     )
@@ -377,15 +363,15 @@ def _hist_impl(impl: Optional[str]) -> str:
 
 def _one_shard_histogram(
     bins, nodes, g, h, n_nodes, n_bins1, impl, vma=(), bins_fm=None, rw=None,
-    dtype="auto", kernel="auto",
 ):
     if impl == "pallas":
         from h2o3_tpu.ops.pallas_histogram import build_histogram_pallas
 
+        # kernel and operand precision are the Pallas module's to choose
         return build_histogram_pallas(
             bins, nodes, g, h, n_nodes, n_bins1,
             interpret=jax.default_backend() != "tpu", vma=vma, bins_fm=bins_fm,
-            rw=rw, dtype=dtype, kernel=kernel,
+            rw=rw,
         )
     return _shard_histogram(bins, nodes, g, h, n_nodes, n_bins1, rw=rw)
 
@@ -407,53 +393,25 @@ def build_histogram_sharded(
     before the jit call — one compiled plan per bucket instead of one per
     tree level — and the real ``n_nodes`` rows are sliced back out.
     """
-    # resolve the env overrides OUTSIDE the jit cache so changing them
-    # between calls takes effect (the resolved values are static cache keys);
-    # the scatter impl ignores dtype — pin it so flipping the dtype env var
-    # neither recompiles nor (if invalid) breaks the path that never reads it
     impl = _hist_impl(impl)
     k_pad = pad_nodes(n_nodes)
-    kernel = "auto"
-    if impl == "pallas":
-        from h2o3_tpu.ops.pallas_histogram import (
-            _C,
-            _fact_max_kc,
-            _resolve_hist_dtype,
-        )
-
-        dtype = (
-            "bf16" if _resolve_hist_dtype("auto") == jnp.bfloat16 else "f32"
-        )
-        # kernel choice keys off the PADDED count — that is the shape the
-        # kernel actually compiles for, so every level in a bucket picks
-        # the same kernel and shares the one plan
-        if k_pad * _C <= _fact_max_kc():
-            kernel = "factorized"
-    else:
-        dtype = "f32"
     _note_plan((
         "hist", k_pad, n_bins1, _shape_sig((bins, nodes, g, h, bins_fm, rw)),
-        mesh, impl, dtype, kernel,
+        mesh, impl,
     ), impl)
     out = _build_histogram_jit(
-        bins, nodes, g, h, bins_fm, rw, k_pad, n_bins1, mesh, impl, dtype,
-        kernel,
-    )
+        bins, nodes, g, h, bins_fm, rw, k_pad, n_bins1, mesh, impl)
     return out[:n_nodes] if k_pad != n_nodes else out
 
 
-@partial(
-    jax.jit,
-    static_argnames=("n_nodes", "n_bins1", "mesh", "impl", "dtype", "kernel"),
-)
+@partial(jax.jit, static_argnames=("n_nodes", "n_bins1", "mesh", "impl"))
 def _build_histogram_jit(
     bins, nodes, g, h, bins_fm, rw, n_nodes: int, n_bins1: int, mesh,
-    impl: str, dtype: str = "auto", kernel: str = "auto",
+    impl: str,
 ):
     if mesh is None:
         return _one_shard_histogram(
             bins, nodes, g, h, n_nodes, n_bins1, impl, bins_fm=bins_fm, rw=rw,
-            dtype=dtype, kernel=kernel,
         )
 
     # optional row-sharded / feature-major extras enter the shard_map only
@@ -467,8 +425,7 @@ def _build_histogram_jit(
     def fn(b, nd, gg, hh, *rest):
         kw = dict(zip([name for name, _, _ in extras], rest))
         part = _one_shard_histogram(
-            b, nd, gg, hh, n_nodes, n_bins1, impl, vma=(DATA_AXIS,),
-            dtype=dtype, kernel=kernel, **kw
+            b, nd, gg, hh, n_nodes, n_bins1, impl, vma=(DATA_AXIS,), **kw
         )
         with jax.named_scope("hist_psum"):
             return jax.lax.psum(part, DATA_AXIS)

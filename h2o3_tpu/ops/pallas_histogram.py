@@ -65,18 +65,6 @@ def _out_sds(shape, dtype, vma):
 #: [Fb*B1, K*C] accumulator + operands; ~16 MB/core on v5e)
 _NODE_MATMUL_MAX_KC = 512
 
-#: factorized kernel applies while K*_C <= this (0 disables; override via
-#: H2O3_TPU_HIST_FACT_MAX_KC once measured on hardware — the crossover vs
-#: the node-matmul kernel is where (KC+1)*_FACT_LO ≈ n_bins1)
-_FACT_MAX_KC_DEFAULT = 0
-
-
-def _fact_max_kc() -> int:
-    import os
-
-    v = os.environ.get("H2O3_TPU_HIST_FACT_MAX_KC")
-    return int(v) if v else _FACT_MAX_KC_DEFAULT
-
 #: feature-block width of the node-matmul kernel grid (callers preparing an
 #: aligned feature-major bins copy must pad features to a multiple of this)
 _FEAT_BLOCK = 8
@@ -224,126 +212,6 @@ def _build_histogram_nodematmul(
         n_nodes, n_feat_p, n_bins1, _C
     )
     return out[:, :n_feat, :, :3]
-
-
-# ---------------------------------------------------------------------------
-# factorized hi/lo one-hot kernel (shallow levels)
-#
-# bin = hi*_FACT_LO + lo. Instead of materializing the [B1, R] one-hot (the
-# dominant VPU write volume of the node-matmul kernel), materialize
-# Ihi [HI, R] plus U [(k,c,lo), R] = Ilo[lo,r]*node_masked_vals[(k,c),r];
-# ONE dot_general contracting rows then yields [HI, KC*LO] = the full
-# (bin, node, chan) histogram of the feature. Per-feature VPU write volume
-# drops from B1*R (~257R) to (HI + (KC+1)*LO)*R (~97R at K=1) — a win while
-# KC is small; the node-matmul kernel stays better once KC*LO > B1.
-
-_FACT_LO = 16
-
-
-def _fact_kernel(bins_ref, node_ref, vals_ref, out_ref, *, n_feat_b, n_nodes,
-                 n_hi):
-    rt = pl.program_id(1)
-    r = node_ref.shape[0]
-    dtype = vals_ref.dtype
-    kc = n_nodes * _C
-
-    node = node_ref[...]  # [R, 1]
-    vals = vals_ref[...]  # [R, C]
-    iota_kc = jax.lax.broadcasted_iota(jnp.int32, (r, kc), 1)
-    m_node = (iota_kc // _C) == node  # node<0 never matches
-    tiled = jnp.concatenate([vals] * n_nodes, axis=1)  # [R, KC]
-    vals_k = jnp.where(m_node, tiled, jnp.zeros((), dtype)).T  # [KC, R]
-
-    iota_hi = jax.lax.broadcasted_iota(jnp.int32, (n_hi, r), 0)
-    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (_FACT_LO, r), 0)
-
-    slabs = []
-    for f in range(n_feat_b):
-        b = bins_ref[f][None, :]  # [1, R]
-        ihi = (iota_hi == (b // _FACT_LO)).astype(dtype)  # [HI, R]
-        ilo = (iota_lo == (b % _FACT_LO)).astype(dtype)  # [LO, R]
-        # U [(k,c,lo), R]: per (node, channel) a [LO, R] block ilo*vals_k[j]
-        u = jnp.concatenate(
-            [ilo * vals_k[j][None, :] for j in range(kc)], axis=0
-        )  # [KC*LO, R]
-        slab = jax.lax.dot_general(  # [HI, KC*LO], contraction over rows
-            ihi, u, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        slabs.append(slab)
-    block = jnp.concatenate(slabs, axis=0)[None]  # [1, Fb*HI, KC*LO]
-
-    @pl.when(rt == 0)
-    def _():
-        out_ref[...] = block
-
-    @pl.when(rt != 0)
-    def _():
-        out_ref[...] = out_ref[...] + block
-
-
-def _build_histogram_factorized(
-    bins, nodes, g, h, n_nodes: int, n_bins1: int,
-    row_tile: int, feat_block: int, interpret: bool, vma: tuple,
-    bins_fm=None, rw=None, dtype=jnp.float32,
-):
-    """Factorized-kernel histogram; same contract/layout as the
-    node-matmul builder (returns [n_nodes, F, n_bins1, 3] f32)."""
-    n, n_feat = bins.shape
-    r = row_tile
-    fb = min(feat_block, n_feat)
-    padf = (-n_feat) % fb
-    n_feat_p = n_feat + padf
-    n_hi = (n_bins1 + _FACT_LO - 1) // _FACT_LO
-    if bins_fm is not None and bins_fm.shape == (n_feat_p, n) and n % r == 0:
-        pass  # caller prepared the aligned feature-major copy
-    else:
-        if n % r:
-            pad = (-n) % r
-            bins = jnp.pad(bins, ((0, pad), (0, 0)))
-            nodes = jnp.pad(nodes, (0, pad), constant_values=-1)
-            g = jnp.pad(g, (0, pad))
-            h = jnp.pad(h, (0, pad))
-            if rw is not None:
-                rw = jnp.pad(rw, (0, pad))
-            n = n + pad
-        if padf:
-            bins = jnp.pad(bins, ((0, 0), (0, padf)))
-        bins_fm = bins.T  # [Fp, N]
-
-    w = (nodes >= 0).astype(jnp.float32)
-    cw = w if rw is None else w * rw.astype(jnp.float32)
-    vals = jnp.stack(
-        [g.astype(jnp.float32) * w, h.astype(jnp.float32) * w, cw,
-         jnp.zeros_like(w)], axis=1,
-    ).astype(dtype)  # [N, C]
-
-    n_ftiles = n_feat_p // fb
-    n_rtiles = n // r
-    kc = n_nodes * _C
-
-    out = pl.pallas_call(
-        partial(_fact_kernel, n_feat_b=fb, n_nodes=n_nodes, n_hi=n_hi),
-        grid=(n_ftiles, n_rtiles),
-        in_specs=[
-            pl.BlockSpec((fb, r), lambda f, t: (f, t)),
-            pl.BlockSpec((r, 1), lambda f, t: (t, 0)),
-            pl.BlockSpec((r, _C), lambda f, t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, fb * n_hi, kc * _FACT_LO), lambda f, t: (f, 0, 0)
-        ),
-        out_shape=_out_sds(
-            (n_ftiles, fb * n_hi, kc * _FACT_LO), jnp.float32, vma),
-        interpret=interpret,
-    )(bins_fm, nodes[:, None], vals)
-
-    # [Ft, Fb*HI, KC*LO] with columns laid out (k, c, lo) -> [K, F, B1, 3]
-    out = out.reshape(n_ftiles, fb, n_hi, n_nodes, _C, _FACT_LO)
-    out = jnp.transpose(out, (3, 0, 1, 2, 5, 4)).reshape(
-        n_nodes, n_feat_p, n_hi * _FACT_LO, _C
-    )
-    return out[:, :n_feat, :n_bins1, :3]
 
 
 # ---------------------------------------------------------------------------
@@ -512,20 +380,28 @@ def _prep_gathered(bins, nodes, g, h, n_nodes: int, n_bins1: int,
     return bins_p, vals_p, item_node, item_first
 
 
-def _resolve_hist_dtype(dtype: str):
-    """'auto' -> env H2O3_TPU_HIST_DTYPE, else bf16 on real TPU (2x MXU
-    rate, halved VMEM traffic; accumulation is always f32) and f32
-    elsewhere (the CPU interpreter path doubles as the exact-parity
-    oracle)."""
-    import os
+_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 
+
+def _kernel_choice(kernel: str, dtype: str, n_nodes: int):
+    """The plan of one level, (kernel, operand precision), decided here and
+    nowhere else, from what the code can observe. ``auto`` (what every fit
+    asks for) is the node-matmul kernel while its all-nodes output fits
+    VMEM and the sorted tile-per-node kernel past that; bf16 operands on a
+    real TPU (2x MXU rate, halved VMEM traffic; accumulation is always f32)
+    and f32 where the kernels are interpreted (the CPU interpreter path
+    doubles as the exact-parity oracle). A name asked for is kept."""
+    if kernel == "auto":
+        kernel = (
+            "nodematmul" if n_nodes * _C <= _NODE_MATMUL_MAX_KC else "sorted")
+    if kernel not in ("nodematmul", "sorted"):
+        raise ValueError(
+            f"hist kernel must be 'nodematmul' or 'sorted', got {kernel!r}")
     if dtype == "auto":
-        dtype = os.environ.get("H2O3_TPU_HIST_DTYPE") or (
-            "bf16" if jax.default_backend() == "tpu" else "f32"
-        )
-    if dtype not in ("f32", "bf16"):
+        dtype = "bf16" if jax.default_backend() == "tpu" else "f32"
+    if dtype not in _DTYPES:
         raise ValueError(f"hist dtype must be 'f32' or 'bf16', got {dtype!r}")
-    return jnp.bfloat16 if dtype == "bf16" else jnp.float32
+    return kernel, dtype
 
 
 def build_histogram_pallas(
@@ -538,39 +414,21 @@ def build_histogram_pallas(
     bins: [N, F] int bin codes (NA bucket = n_bins1 - 1 handled upstream);
     nodes: [N] int32 (-1 = inactive row); g, h: [N] float; rw: optional [N]
     per-row count weight (weights_column -> the count channel reports Σw).
-    dtype: 'f32' | 'bf16' | 'auto' — matmul operand precision (the one-hot
-    mask is exact either way; bf16 rounds g/h/w inputs to 8 mantissa bits,
-    accumulation stays f32).
+    kernel: 'nodematmul' | 'sorted' | 'auto'; dtype: 'f32' | 'bf16' |
+    'auto' — matmul operand precision (the one-hot mask is exact either
+    way; bf16 rounds g/h/w inputs to 8 mantissa bits, accumulation stays
+    f32). ``_kernel_choice`` resolves both.
     Returns [n_nodes, F, n_bins1, 3] float32 of (Σg, Σh, Σw).
     """
-    # resolve env-var defaults OUTSIDE the jit boundary: a cached trace
-    # must never pin a stale H2O3_TPU_HIST_DTYPE / _FACT_MAX_KC (when
-    # already inside a trace — called from _build_histogram_jit — dtype
-    # and kernel arrive pre-resolved)
-    if dtype == "auto":
-        dtype = "bf16" if _resolve_hist_dtype("auto") == jnp.bfloat16 else "f32"
-    if kernel == "auto" and n_nodes * _C <= _fact_max_kc():
-        kernel = "factorized"
+    kernel, dtype = _kernel_choice(kernel, dtype, n_nodes)
     # the scope sits OUTSIDE the jit: XLA names the kernel's custom-call
     # after the innermost component of its name stack, and the benchmark's
     # accepted readers find the kernels by ``_build_histogram_pallas_jit``
-    with jax.named_scope("hist_" + _kernel_choice(kernel, n_nodes)):
+    with jax.named_scope("hist_" + kernel):
         return _build_histogram_pallas_jit(
             bins, nodes, g, h, n_nodes, n_bins1, row_tile, interpret,
             vma, kernel, bins_fm, rw, dtype,
         )
-
-
-def _kernel_choice(kernel: str, n_nodes: int) -> str:
-    """Which of the three kernels builds this level: ``factorized`` or
-    ``nodematmul`` as asked, else the node-matmul kernel while its
-    all-nodes output fits VMEM and the sorted tile-per-node kernel past
-    that."""
-    if kernel in ("factorized", "nodematmul"):
-        return kernel
-    if kernel == "auto" and n_nodes * _C <= _NODE_MATMUL_MAX_KC:
-        return "nodematmul"
-    return "sorted"
 
 
 @partial(
@@ -584,20 +442,13 @@ def _build_histogram_pallas_jit(
     row_tile, interpret: bool, vma: tuple,
     kernel: str, bins_fm, rw, dtype: str,
 ):
-    choice = _kernel_choice(kernel, n_nodes)
-    if choice == "factorized":
-        return _build_histogram_factorized(
-            bins, nodes, g, h, n_nodes, n_bins1,
-            row_tile=row_tile or _ROW_TILE, feat_block=_FEAT_BLOCK,
-            interpret=interpret, vma=vma, bins_fm=bins_fm, rw=rw,
-            dtype=_resolve_hist_dtype(dtype),
-        )
-    if choice == "nodematmul":
+    kernel, dtype = _kernel_choice(kernel, dtype, n_nodes)
+    if kernel == "nodematmul":
         return _build_histogram_nodematmul(
             bins, nodes, g, h, n_nodes, n_bins1,
             row_tile=row_tile or _ROW_TILE, feat_block=_FEAT_BLOCK,
             interpret=interpret, vma=vma, bins_fm=bins_fm, rw=rw,
-            dtype=_resolve_hist_dtype(dtype),
+            dtype=_DTYPES[dtype],
         )
     n, n_feat = bins.shape
     r = row_tile or 512  # sorted kernel keeps its original tile height
@@ -606,7 +457,7 @@ def _build_histogram_pallas_jit(
     with jax.named_scope("sorted_prep"):
         bins_p, vals_p, item_node, item_first = _prep_gathered(
             bins, nodes, g, h, n_nodes, n_bins1, r, t_max, rw=rw,
-            dtype=_resolve_hist_dtype(dtype),
+            dtype=_DTYPES[dtype],
         )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

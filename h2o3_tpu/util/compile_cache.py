@@ -2,8 +2,8 @@
 
 The training-block programs take tens of seconds to compile for the chip and
 a node compiles them again on every start unless the cache is on. The
-launcher (``python -m h2o3_tpu``), ``chip_smoke.py``, ``bench.py`` and the
-scripts all call :func:`configure` before their first jit.
+launcher (``python -m h2o3_tpu``), ``chip_smoke.py`` and the benchmark all
+call :func:`configure` before their first jit.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing is set in
   code, so whoever runs the process places the cache.

@@ -305,6 +305,25 @@ class TestKnobRegistry:
         assert fs[0].file == "README.md"
         assert fs[0].symbol == "H2O3_TPU_GHOST_KNOB"
 
+    def test_struck_knobs_stay_struck(self):
+        """The two rules over the tree itself, for the three names that had
+        one value everywhere and went: no file of the package names one,
+        and README.md documents none. The count of names only falls
+        (PERF.md section 3)."""
+        import re
+
+        names = set()
+        for rel in core.iter_source_files(ROOT, roots=("h2o3_tpu",)):
+            with open(os.path.join(ROOT, rel), encoding="utf-8") as f:
+                names.update(re.findall(r"H2O3_TPU_[A-Z0-9_]+", f.read()))
+        with open(os.path.join(ROOT, "README.md"), encoding="utf-8") as f:
+            readme = f.read()
+        for knob in ("H2O3_TPU_HIST_DTYPE", "H2O3_TPU_HIST_FACT_MAX_KC",
+                     "H2O3_TPU_HIST_NODE_BUCKETS"):
+            assert knob not in names, knob
+            assert knob not in readme, knob
+        assert len(names) <= 67, sorted(names)
+
 
 # ---------------------------------------------------------------------------
 # rpc-payload

@@ -1,9 +1,8 @@
 """Fixed-shape level plans: the node-bucket padding ladder.
 
 Contract (h2o3_tpu/ops/histogram.py): every histogram/totals launch pads
-its node dimension up to a bucket ladder (default 8/64/512, override
-``H2O3_TPU_HIST_NODE_BUCKETS``) so ONE traced jit plan serves every tree
-level that lands in the same bucket; the real node rows are sliced back
+its node dimension up to a bucket ladder (8/64/512) so ONE traced jit plan
+serves every tree level that lands in the same bucket; the real node rows are sliced back
 out and the result is BIT-identical to the unpadded build, because the
 scatter-add accumulation order does not depend on the destination
 capacity. ``hist_plan_cache_total{impl,result}`` meters lookups against the
@@ -19,7 +18,7 @@ import jax.numpy as jnp
 
 from h2o3_tpu import Frame
 from h2o3_tpu.models.grid import metric_value
-from h2o3_tpu.models.tree import DRF, GBM, XGBoost
+from h2o3_tpu.models.tree import DRF, GBM, XGBoost, booster
 from h2o3_tpu.ops import histogram as H
 
 pytestmark = pytest.mark.leaks_keys
@@ -29,26 +28,27 @@ pytestmark = pytest.mark.leaks_keys
 # the ladder itself
 
 
+@pytest.fixture
+def no_ladder(monkeypatch):
+    """Call it to take the ladder away for the rest of the test. A fit's
+    block is cached by its parameters (``_make_block_fn``) and its trace
+    holds the padding it was traced with, so the cache is emptied on both
+    sides: the next fit traces unpadded, and no later test inherits that
+    trace."""
+    def off():
+        monkeypatch.setattr(H, "_NODE_BUCKETS", ())
+        booster._make_block_fn.cache_clear()
+    yield off
+    booster._make_block_fn.cache_clear()
+
+
 def test_pad_nodes_default_ladder():
-    assert H.node_buckets() == (8, 64, 512)
+    assert H._NODE_BUCKETS == (8, 64, 512)
     # bucket edges: at the edge stays, one past jumps to the next rung,
     # past the top rung runs unpadded
     for n, want in [(1, 8), (7, 8), (8, 8), (9, 64), (64, 64),
                     (65, 512), (512, 512), (513, 513), (4096, 4096)]:
         assert H.pad_nodes(n) == want, (n, want)
-
-
-def test_pad_nodes_env_ladder(monkeypatch):
-    monkeypatch.setenv("H2O3_TPU_HIST_NODE_BUCKETS", "4,16")
-    assert H.node_buckets() == (4, 16)
-    assert [H.pad_nodes(n) for n in (1, 4, 5, 16, 17)] == [4, 4, 16, 16, 17]
-    # no positive buckets -> padding disabled, every shape runs as-is
-    monkeypatch.setenv("H2O3_TPU_HIST_NODE_BUCKETS", "0")
-    assert H.node_buckets() == ()
-    assert H.pad_nodes(3) == 3
-    # garbage falls back to the default ladder rather than breaking fits
-    monkeypatch.setenv("H2O3_TPU_HIST_NODE_BUCKETS", "eight")
-    assert H.node_buckets() == (8, 64, 512)
 
 
 # ---------------------------------------------------------------------------
@@ -66,13 +66,13 @@ def _level_inputs(rng, n, k, f=3, b=6):
 
 @pytest.mark.parametrize("k", [1, 7, 8, 9, 64, 65])
 @pytest.mark.parametrize("with_rw", [False, True])
-def test_padded_bit_identical(monkeypatch, rng, k, with_rw):
+def test_padded_bit_identical(no_ladder, rng, k, with_rw):
     bins, nodes, g, h, rw, n_bins1 = _level_inputs(rng, 1024, k)
     rw = rw if with_rw else None
     hist = np.asarray(H.build_histogram_sharded(
         bins, nodes, g, h, n_nodes=k, n_bins1=n_bins1, rw=rw))
     tot = np.asarray(H.node_totals_sharded(nodes, g, h, n_nodes=k, rw=rw))
-    monkeypatch.setenv("H2O3_TPU_HIST_NODE_BUCKETS", "0")  # unpadded ref
+    no_ladder()  # unpadded ref
     ref_h = np.asarray(H.build_histogram_sharded(
         bins, nodes, g, h, n_nodes=k, n_bins1=n_bins1, rw=rw))
     ref_t = np.asarray(H.node_totals_sharded(nodes, g, h, n_nodes=k, rw=rw))
@@ -87,8 +87,7 @@ def test_pad_rows_are_exact_zero(rng):
     # build with the ladder forced to a single oversized bucket
     bins, nodes, g, h, _, n_bins1 = _level_inputs(rng, 512, 3)
     full = np.asarray(H._build_histogram_jit(
-        bins, nodes, g, h, None, None, 8, n_bins1, None, "scatter", "f32",
-        "auto"))
+        bins, nodes, g, h, None, None, 8, n_bins1, None, "scatter"))
     assert full.shape[0] == 8
     assert not full[3:].any(), "pad rows picked up mass"
 
@@ -155,12 +154,25 @@ def _model(algo):
 
 @pytest.mark.parametrize("algo", ["gbm", "drf", "xgb"])
 @pytest.mark.parametrize("resp", ["reg", "bin"])
-def test_fit_matrix_padded_vs_unpadded(monkeypatch, algo, resp):
+def test_fit_matrix_padded_vs_unpadded(monkeypatch, no_ladder, algo, resp):
     fr_reg, fr_bin = _frames()
     fr = fr_reg if resp == "reg" else fr_bin
+    slots = []  # the node slots of every level plan a fit asks for
+    note = H._note_plan
+
+    def spy(key, impl):
+        slots.append(key[1])
+        note(key, impl)
+
+    monkeypatch.setattr(H, "_note_plan", spy)
     padded = _model(algo).train(fr)
-    monkeypatch.setenv("H2O3_TPU_HIST_NODE_BUCKETS", "0")
+    assert set(slots) == {8, 64}, slots  # 64: the leaves' totals
+    no_ladder()
+    del slots[:]
     unpadded = _model(algo).train(fr)
+    # traced afresh, every level at its own node count: not the padded
+    # program compared with itself
+    assert set(slots) == {1, 2, 4, 8, 16}, slots
     assert _sig(padded) == _sig(unpadded), f"{algo}/{resp} drifts under padding"
 
 

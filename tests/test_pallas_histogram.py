@@ -23,6 +23,7 @@ from h2o3_tpu.ops.histogram import _shard_histogram
 from h2o3_tpu.ops.pallas_histogram import (
     _build_histogram_pallas_jit,
     _code_words,
+    _kernel_choice,
     _pack_row,
     _unpack_row,
     build_histogram_pallas,
@@ -31,14 +32,42 @@ from h2o3_tpu.ops.pallas_histogram import (
 INTERPRET = jax.default_backend() != "tpu"
 
 # (kernel, rtol, atol): node-matmul carries bf16 operand rounding (~2^-8
-# relative per element); sorted kernel is f32 end-to-end; factorized is the
-# hi/lo-decomposed one-hot variant (same bf16-on-TPU / f32-in-interpret
-# dtype policy as node-matmul)
+# relative per element); sorted kernel is f32 end-to-end; auto is the
+# selection a fit runs (``_kernel_choice``), the node-matmul kernel at every
+# node count of these cases
 KERNELS = [
     ("nodematmul", 2e-2, 5e-2),
     ("sorted", 1e-5, 1e-4),
-    ("factorized", 2e-2, 5e-2),  # bf16 on real TPU, like nodematmul
+    ("auto", 2e-2, 5e-2),
 ]
+
+
+@pytest.mark.parametrize("kernel,dtype,n_nodes,want", [
+    ("auto", "auto", 1, ("nodematmul", "f32")),
+    ("auto", "auto", 128, ("nodematmul", "f32")),  # K*C = 512: the last that fits
+    ("auto", "auto", 129, ("sorted", "f32")),
+    ("auto", "bf16", 512, ("sorted", "bf16")),
+    ("sorted", "auto", 4, ("sorted", "f32")),  # a name asked for is kept
+    ("nodematmul", "f32", 256, ("nodematmul", "f32")),
+])
+def test_kernel_choice_is_the_level_plan(kernel, dtype, n_nodes, want):
+    """The one place that decides kernel and operand precision: by the node
+    slots and the platform (interpreted here, so f32)."""
+    assert INTERPRET
+    assert _kernel_choice(kernel, dtype, n_nodes) == want
+    assert _kernel_choice(*want, n_nodes) == want  # resolving twice is no change
+
+
+def test_kernel_choice_on_a_tpu_is_bf16(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _kernel_choice("auto", "auto", 8) == ("nodematmul", "bf16")
+    assert _kernel_choice("auto", "f32", 8) == ("nodematmul", "f32")
+
+
+@pytest.mark.parametrize("kernel,dtype", [("factorized", "auto"), ("auto", "f16")])
+def test_kernel_choice_refuses_a_name_it_does_not_know(kernel, dtype):
+    with pytest.raises(ValueError):
+        _kernel_choice(kernel, dtype, 8)
 
 
 def _mk(n, f, k, b1, seed, frac_inactive=0.0, empty_node=None):

@@ -316,36 +316,3 @@ class TestBoundedDrain:
         assert not t.is_alive()
         assert out["r"][0] == 200  # flushed and answered before teardown
 
-
-class TestServeBenchSmoke:
-    def test_serve_bench_smoke(self, monkeypatch):
-        import bench
-
-        monkeypatch.setenv("BENCH_SERVE_SMOKE", "1")
-        result = bench._serve_bench()
-        assert result["metric"] == "serve_warm_rps_speedup"
-        assert result["value"] > 0
-        cells = result["detail"]["matrix"]
-        assert cells  # every (server, clients) cell ran
-        for cell in cells:
-            assert cell["rps"] > 0
-            assert cell["p99_ms"] >= cell["p50_ms"] > 0
-            bad = [s for s in cell["statuses"]
-                   if not (200 <= int(s) < 300 or int(s) in (408, 413, 429))]
-            assert not bad, f"unexpected statuses in {cell}"
-        assert result["detail"]["bit_identical"] is True
-        # the multi-node cell: three real node processes, forwarding
-        # through the serving ring (cluster/serving.py).  Its invariants
-        # (overload_clean, bit_identical, forwards coalesce at the home,
-        # spill reaches the replica) are asserted IN-RUN by the bench —
-        # a violation raises — so here we pin the contract shape
-        mn = result["detail"]["multinode"]
-        assert mn["nodes"] == 3
-        assert mn["one_door_rps"] > 0
-        assert mn["three_door_rps"] > 0
-        assert mn["replica_spill_rps"] > 0
-        assert mn["forwarded_requests"] > 0
-        assert mn["replica_spilled"] > 0
-        assert mn["home_dispatches"] < mn["home_coalesced_requests"]
-        assert mn["overload_clean"] is True
-        assert mn["bit_identical"] is True

@@ -98,10 +98,27 @@ def test_sharded_histogram_keeps_its_all_reduce(v5e):
     mesh = _mesh(v5e, 4)
     bins, nodes, g, h, bins_fm = _level_args(mesh, N_KERNEL)
     text = _build_histogram_jit.lower(
-        bins, nodes, g, h, bins_fm, None, 64, B1, mesh, "pallas", "bf16",
-        "auto").compile().as_text()
+        bins, nodes, g, h, bins_fm, None, 64, B1, mesh, "pallas",
+        ).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+def test_chip_smoke_builds_its_table_from_the_benchmarks_generator():
+    """``chip_smoke.training_table`` reads ``benchmark/tables/higgs-synth.py``;
+    the digests are of what ``bench.synth_higgs(1000, 28, 7)`` returned at
+    commit b201c72, so the chip proof still fits the table it always fit."""
+    import hashlib
+
+    import chip_smoke
+
+    X, y = chip_smoke.training_table(1000, 7)
+    assert (X.dtype, X.shape, y.dtype, y.shape) == (
+        np.float32, (1000, 28), np.float64, (1000,))
+    assert hashlib.sha256(X.tobytes()).hexdigest() == (
+        "19eafbb0362d68c6499464bcbfe321b6b296467990a26269114bb8a66d282266")
+    assert hashlib.sha256(y.tobytes()).hexdigest() == (
+        "82f8a351dfa77f5cb7c6cdcad77b72bc5b16c4b6559c2d9132802060a425dd61")
 
 
 def test_training_block_fits_one_chip(v5e, capsys):

@@ -480,6 +480,9 @@ PARENT_PROGRAMS = {
     "block_pallas_subtract": "dde7eaf032dd91a0b2a2101646c6cdac1d5e0f0524496e52a4e7c6434c61072e",
     "block_drf": "a138b78603b6f89b09e1e7f968e96b8df60987af7af81fc1e96c4ab90bfd2942",
     "predict_stacked": "65fd78c589b300f01a4dcaa7e757aab45a4deccda6f745f4d125e892cf5d66db",
+    # a sorted level (256 node slots), which no block above reaches at depth 3:
+    # recorded from commit b201c72, before the level plan moved into one place
+    "hist_sorted_level": "d818051a38ba4a5e256bd6d815b887495653c4f2e07ffa133152f54bef32bc42",
 }
 
 
@@ -488,6 +491,7 @@ def test_the_gate_a_numeric_fit_lowers_to_the_parents_programs(program, monkeypa
     """With no ``enum`` column the set search, the sixth array and the set
     routing are not traced at all: text for text the parent's programs."""
     TP = TreeParams
+    S = jax.ShapeDtypeStruct
     if program == "block_scatter":
         text = numeric_block_text("bernoulli", 1, 2, TP(
             ntrees=0, seed=0, max_depth=3, nbins=16, min_rows=2.0, reg_lambda=0.0),
@@ -500,8 +504,16 @@ def test_the_gate_a_numeric_fit_lowers_to_the_parents_programs(program, monkeypa
         text = numeric_block_text("fixed", 2, 2, TP(
             ntrees=0, seed=0, max_depth=3, nbins=16, learn_rate=1.0, reg_lambda=0.0,
             sample_rate=0.632, mtries=2), "scatter", False, monkeypatch)
+    elif program == "hist_sorted_level":
+        from h2o3_tpu.ops.pallas_histogram import _build_histogram_pallas_jit
+
+        text = _build_histogram_pallas_jit.lower(
+            S((4096, 5), jnp.int32), S((4096,), jnp.int32), S((4096,), jnp.float32),
+            S((4096,), jnp.float32), n_nodes=256, n_bins1=21, row_tile=None,
+            interpret=True, vma=(), kernel="auto", bins_fm=None, rw=None,
+            dtype="f32").as_text()
+        assert "stablehlo.sort" in text
     else:
-        S = jax.ShapeDtypeStruct
         text = booster._predict_stacked.lower(
             S((1000, 5), jnp.int32), S((3, 15), jnp.int32), S((3, 15), jnp.int32),
             S((3, 15), jnp.bool_), S((3, 15), jnp.bool_), S((3, 15), jnp.float32),
