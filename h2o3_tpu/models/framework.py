@@ -329,8 +329,9 @@ def fit_profile(train_span: telemetry.Span,
     ``train_span``; a kind met under ``model_performance`` is keyed
     ``score/<kind>``, so the entry's ``tree_matrix`` and the scoring's stay
     apart; the fields named in ``counts`` (the builder's
-    ``profile_counts``) are summed beside them.  Empty where the ring no
-    longer holds the fit."""
+    ``profile_counts``) are summed beside them where they are numbers and
+    kept as the span states them where they are not (a tree block's
+    ``hist_slots``).  Empty where the ring no longer holds the fit."""
     from h2o3_tpu.util import timeline
 
     spans = {e["span_id"]: e for e in timeline.snapshot(timeline.CAPACITY)
@@ -350,8 +351,12 @@ def fit_profile(train_span: telemetry.Span,
         slot["s"] = round(slot["s"] + e["duration_ms"] / 1e3, 6)
         slot["n"] += 1
         for name in counts:
-            if name in e:
+            if name not in e:
+                continue
+            if isinstance(e[name], (int, float)):
                 slot[name] = slot.get(name, 0) + int(e[name])
+            else:  # a plan, the same in every span of the kind: kept
+                slot[name] = e[name]
     return out
 
 
@@ -359,7 +364,8 @@ def _profile_text(profile: Dict[str, Dict[str, float]]) -> str:
     """``tree_block 35.89s x4, score/apply_bins 9.52s, ...`` longest first."""
     return ", ".join(
         "%s %.2fs%s%s" % (k, v["s"], " x%d" % v["n"] if v["n"] > 1 else "",
-                          "".join(" %s=%d" % (c, x) for c, x in v.items() if c not in ("s", "n")))
+                          "".join(" %s=%d" % (c, x) for c, x in v.items()
+                                  if c not in ("s", "n") and isinstance(x, int)))
         for k, v in sorted(profile.items(), key=lambda kv: -kv[1]["s"]))
 
 

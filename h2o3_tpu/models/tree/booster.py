@@ -43,10 +43,12 @@ import jax
 import jax.numpy as jnp
 
 from h2o3_tpu.ops.histogram import (
+    _hist_impl,
     apply_bins,
     build_histogram_sharded,
     make_bins,
     na_code,
+    pad_nodes,
 )
 from h2o3_tpu.parallel.mesh import default_mesh, row_sharding
 from h2o3_tpu.util import telemetry
@@ -630,12 +632,42 @@ def _tree_subtract_enabled() -> bool:
     """
     import os
 
-    from h2o3_tpu.ops.histogram import _hist_impl
-
     v = os.environ.get("H2O3_TPU_TREE_SUBTRACT", "auto")
     if v in ("0", "1"):
         return v == "1"
     return _hist_impl(None) == "pallas"
+
+
+def _built_nodes(d: int, subtract: bool) -> int:
+    """The nodes whose histogram level ``d`` builds: all 2^d of the level,
+    or with subtraction each parent's smaller child alone."""
+    return 2 ** (d - 1) if subtract and d > 0 else 2**d
+
+
+def level_plan(p: TreeParams, subtract: bool, impl: Optional[str] = None):
+    """What every level of a tree of ``p`` launches, one ``(nodes built,
+    node slots launched, kernel)`` a level in the order the block runs them:
+    the histogram of levels 0 to ``max_depth - 1`` and, without subtraction,
+    the per-node totals of the leaves (``totals``). The padding a level pays
+    is ``slots / built``. Pure Python over the functions the trace itself
+    asks (``pad_nodes``, ``_hist_impl``, ``_kernel_choice``); ``impl`` as
+    ``build_histogram_sharded`` takes it."""
+    impl = _hist_impl(impl)
+    if impl == "pallas":
+        from h2o3_tpu.ops.pallas_histogram import _kernel_choice
+
+        def kernel(slots):
+            return _kernel_choice("auto", "auto", slots)[0]
+    else:
+        def kernel(slots):
+            return impl
+
+    built = [_built_nodes(d, subtract) for d in range(p.max_depth)]
+    plan = [(k, pad_nodes(k), kernel(pad_nodes(k))) for k in built]
+    if not (subtract and p.max_depth > 0):
+        leaves = 2**p.max_depth
+        plan.append((leaves, pad_nodes(leaves), "totals"))
+    return tuple(plan)
 
 
 def _build_one_tree(
@@ -725,7 +757,7 @@ def _build_one_tree(
             # Children of non-split parents hold no rows: their small
             # half is all-zero by the in_lvl mask and their big half is
             # masked to zero by prev_can.
-            Kp = K // 2
+            Kp = _built_nodes(d, subtract)
             with jax.named_scope(f"{lvl}/hist_nodes"):
                 par = jnp.clip(local // 2, 0, Kp - 1)
                 parity = local % 2
@@ -1225,6 +1257,9 @@ def _train_boosted(
     final_host = None  # the last budget check's copy of the margin
     default_block = tree_block_size()
     subtract_on = _tree_subtract_enabled()
+    # what the block's levels launch, (built, slots, kernel) a level: the
+    # span states it, so padding reads as slots / built without a trace
+    hist_slots = level_plan(p_key, subtract_on)
     while built < p.ntrees:
         block = (
             min(score_interval, p.ntrees - built)
@@ -1243,7 +1278,7 @@ def _train_boosted(
         )
         with Span(
             "tree_block", objective=objective, trees=block, rows=n,
-            first_tree=tree_offset + built,
+            first_tree=tree_offset + built, hist_slots=hist_slots,
         ):
             margin, trees_dev = fn(
                 bins_d, y_d, valid_d, margin, keys, bins_fm_d, w_d, mono_d

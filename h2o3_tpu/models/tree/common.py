@@ -62,9 +62,11 @@ NBINS_CATS = 1024
 #: test a set of levels (``tree_readback``), the categorical features binned
 #: a level a bin (``make_bins``), scoring walks by sets and their chunks
 #: (``score_traverse``), metrics from the margin a fit held
-#: (``model_performance``)
+#: (``model_performance``); and, kept as stated and not summed, what every
+#: level of a block launches: ``hist_slots``, one ``[nodes built, node slots
+#: launched, kernel]`` a level (``tree_block``; ``booster.level_plan``)
 SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks",
-               "fit_margin")
+               "fit_margin", "hist_slots")
 
 
 def resolve_tree_encoding(categorical_encoding: str) -> str:

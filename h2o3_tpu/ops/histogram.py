@@ -41,19 +41,27 @@ from h2o3_tpu.util import telemetry
 
 
 # ---------------------------------------------------------------------------
-# fixed-shape level plans: the node-bucket ladder
+# the node ladder: how many node slots a level's call launches
 #
-# ``n_nodes`` is a static jit argname, so every tree level 2^d is a plan of
-# its own. Padding the node dimension up to a small ladder of power-of-2
-# buckets makes one traced plan serve every level in the bucket: pad rows are
-# zero-filled (a scatter-add / one-hot contraction never touches a node id
-# beyond the real range) and the real ``n_nodes`` rows are sliced back out,
-# so the result is bit-identical to the unpadded build. That saved a compile
-# a level while each level was a jit call of its own; today every call sits
-# inside the traced block of trees, where a shared plan saves nothing and a
-# level pays for its slots (ROADMAP S2 holds the consequence).
+# Every call sits inside the traced block of trees, where a level pays for
+# the slots it launches and not for the nodes it builds: on a v5e the
+# node-matmul kernel took 47.6 / 49.2 / 52.5 / 60.0 / 67.9 / 152.3 ms at
+# 1 / 2 / 4 / 8 / 16 / 64 slots of 256 bins (PERF.md section 6, PR 35). So
+# up to 64 nodes the ladder is the node count itself, rounded up to a power
+# of two (a tree's levels build powers of two, so nothing of theirs is
+# padded), and one rung remains above it, 512: levels of 65 to 512 nodes
+# (levels 8 and 9 of a depth-10 tree build 128 and 256) run the sorted
+# kernel, whose cost is its preparation and hardly its slots (un-padding
+# drops at most 3% of the kernel: PR 29). A rung of 128 would move level 8
+# to the node-matmul kernel: which kernel a level gets is
+# ``pallas_histogram._kernel_choice``'s question and waits for the sorted
+# levels' own work (ROADMAP S2, S3).
+# Pad rows are zero-filled (a scatter-add / one-hot contraction never
+# touches a node id beyond the real range) and the real ``n_nodes`` rows are
+# sliced back out, so the result is bit-identical to the unpadded build.
+# Past 512 a call runs unpadded.
 
-_NODE_BUCKETS = (8, 64, 512)
+_NODE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 512)
 
 PLAN_CACHE = telemetry.counter(
     "hist_plan_cache_total",
@@ -320,9 +328,9 @@ def _shard_node_totals(nodes, g, h, n_nodes: int, rw=None):
 def node_totals_sharded(nodes, g, h, n_nodes: int, mesh=None, rw=None):
     """Distributed per-node totals: shard-private contraction + psum.
 
-    The node dimension is padded to the bucket ladder (``pad_nodes``) so one
-    traced shape serves every level in a bucket; node ids never reach the
-    pad columns, so slicing the real rows back out is bit-identical."""
+    The node dimension is padded up the node ladder (``pad_nodes``); node
+    ids never reach the pad columns, so slicing the real rows back out is
+    bit-identical."""
     k_pad = pad_nodes(n_nodes)
     _note_plan(
         ("totals", k_pad, _shape_sig((nodes, g, h, rw)), mesh), "scatter")
@@ -389,9 +397,13 @@ def build_histogram_sharded(
     per-row count weight (weights_column: the count channel reports Σw).
     Returns replicated [n_nodes, F, n_bins1, 3].
 
-    The node dimension is padded up to the bucket ladder (``pad_nodes``)
-    before the jit call — one compiled plan per bucket instead of one per
-    tree level — and the real ``n_nodes`` rows are sliced back out.
+    The node dimension is padded up the node ladder (``pad_nodes``: the
+    powers of two up to 64, then 512) before the jit call and the real
+    ``n_nodes`` rows are sliced back out. A level pays for the slots it
+    launches, so up to 64 the ladder follows the node count (no floor: one
+    slot costs a fifth less than eight); the 512 rung keeps levels of 65 to
+    512 nodes on the sorted kernel, which hardly pays for its slots (the
+    comment at ``_NODE_BUCKETS`` has the readings).
     """
     impl = _hist_impl(impl)
     k_pad = pad_nodes(n_nodes)

@@ -173,6 +173,17 @@ def _build_histogram_nodematmul(
             bins = jnp.pad(bins, ((0, 0), (0, padf)))
         bins_fm = bins.T  # [Fp, N] feature-major: rows land in the lane axis
 
+    # The kernel takes the node ids as [N, 1] and g, h, w as [N, C]: row-major
+    # operands whose rows fill 1 and 4 of 128 lanes. Without the barrier XLA
+    # may hoist those reshapes into the producers of the [N] vectors and
+    # compute them (node ids, the sample's random draws, gradients, routing)
+    # in that 128-lane padded layout, cloned into every consumer. Whether it
+    # does flips with any change to the block: with rungs of 16 and 32 in the
+    # node ladder it took the depth-10 block from 978 to 1,544 ms a tree and
+    # its temporaries from 5.1 to 15.6 GB (PERF.md section 6, PR 35). The
+    # barrier pins the vectors as the lane-dense [N] arrays they are; the
+    # reshape to the kernel's layout is then one small pass of its own.
+    nodes, g, h, rw = jax.lax.optimization_barrier((nodes, g, h, rw))
     w = (nodes >= 0).astype(jnp.float32)
     cw = w if rw is None else w * rw.astype(jnp.float32)
     vals = jnp.stack(

@@ -121,3 +121,29 @@ def _clear_jax_caches_between_modules():
     the explicit release."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture()
+def parents_level_plan(monkeypatch):
+    """Trace a tree level as the commits before ISSUE 35 did: the node
+    ladder 8 / 64 / 512 (``histogram._NODE_BUCKETS``) and no
+    ``optimization_barrier`` on the node-matmul kernel's operands
+    (``pallas_histogram._build_histogram_nodematmul``). A program can then
+    be held to the digest of such a commit: the slots a level launches and
+    that barrier are shown to be all that a level's program gained. The
+    block and the level jits cache their traces by shape, so they are
+    emptied on both sides: no patched trace is inherited, and none is left."""
+    from h2o3_tpu.models.tree import booster
+    from h2o3_tpu.ops import histogram, pallas_histogram
+
+    def clear():
+        booster._make_block_fn.cache_clear()
+        histogram._build_histogram_jit.clear_cache()
+        pallas_histogram._build_histogram_pallas_jit.clear_cache()
+
+    clear()
+    with monkeypatch.context() as patch:
+        patch.setattr(histogram, "_NODE_BUCKETS", (8, 64, 512))
+        patch.setattr(jax.lax, "optimization_barrier", lambda operands: operands)
+        yield
+    clear()

@@ -106,6 +106,58 @@ def test_parity(n, f, k, b1, row_tile, kernel, rtol, atol):
     np.testing.assert_allclose(got, want, rtol=rtol, atol=atol)
 
 
+@pytest.mark.parametrize("dtype,rtol,atol", [("f32", 1e-5, 1e-4),
+                                             ("bf16", 2e-2, 5e-2)])
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("built,slots", [(1, 1), (2, 2), (3, 4), (16, 16),
+                                         (9, 16), (32, 32), (17, 32)])
+def test_nodematmul_at_the_ladders_new_rungs(
+        built, slots, weighted, dtype, rtol, atol):
+    """The launches the node ladder gained at ISSUE 35 (1, 2, 4, 16 and 32
+    slots: K*C = 4 to 128 operand columns): ``built`` nodes in ``slots``
+    slots, as ``pad_nodes`` pads
+    them, against the scatter oracle at the built node count; the empty
+    slots hold exact zeros. f32 as the interpreter runs a fit, bf16 as the
+    chip does."""
+    from h2o3_tpu.ops.histogram import pad_nodes
+
+    assert pad_nodes(built) == slots
+    bins, nodes, g, h = _mk(1500, 5, built, 17, seed=slots + built,
+                            frac_inactive=0.2)
+    rw = None
+    if weighted:
+        rw = (1.0 + np.random.default_rng(built).random(1500)).astype(np.float32)
+    want = np.asarray(_shard_histogram(bins, nodes, g, h, built, 17, rw=rw))
+    got = np.asarray(build_histogram_pallas(
+        bins, nodes, g, h, slots, 17, row_tile=256, interpret=INTERPRET,
+        kernel="nodematmul", dtype=dtype, rw=rw))
+    assert got.shape == (slots, 5, 17, 3)
+    np.testing.assert_allclose(got[:built], want, rtol=rtol, atol=atol)
+    assert not got[built:].any(), "an empty slot picked up mass"
+
+
+def test_nodematmul_operands_sit_behind_a_barrier():
+    """The node ids, g, h (and the row weights) reach the kernel's [N, 1] and
+    [N, C] operands through one ``optimization_barrier``, so XLA cannot hoist
+    those reshapes into the vectors' producers (ISSUE 35); the sorted
+    kernel's operands are gathered rows and have none."""
+    S = jax.ShapeDtypeStruct
+    args = (S((2048, 5), jnp.int32), S((2048,), jnp.int32),
+            S((2048,), jnp.float32), S((2048,), jnp.float32))
+
+    def text(kernel, rw):
+        return _build_histogram_pallas_jit.lower(
+            *args, n_nodes=16, n_bins1=9, row_tile=None, interpret=True, vma=(),
+            kernel=kernel, bins_fm=None, rw=rw, dtype="f32").as_text()
+
+    assert text("nodematmul", None).count("optimization_barrier") == 1
+    weighted = text("nodematmul", S((2048,), jnp.float32))
+    assert weighted.count("optimization_barrier") == 1
+    barrier = next(ln for ln in weighted.splitlines() if "optimization_barrier" in ln)
+    assert barrier.count("tensor<2048x") >= 4  # ids, g, h, w in, and out
+    assert "optimization_barrier" not in text("sorted", None)
+
+
 @pytest.mark.parametrize("kernel,rtol,atol", KERNELS)
 def test_inactive_rows_and_empty_nodes(kernel, rtol, atol):
     bins, nodes, g, h = _mk(

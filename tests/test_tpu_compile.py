@@ -75,10 +75,16 @@ def _level_args(mesh, n):
 
 
 @pytest.mark.parametrize("n_nodes,kernel", [
-    (8, "nodematmul"), (64, "nodematmul"), (512, "sorted")])
+    (1, "nodematmul"), (2, "nodematmul"), (4, "nodematmul"),
+    (8, "nodematmul"), (16, "nodematmul"), (32, "nodematmul"),
+    (64, "nodematmul"), (512, "sorted")])
 def test_histogram_kernel_compiles(v5e, n_nodes, kernel):
-    """The two node-matmul bucket shapes of a depth-6 tree and the sorted
-    kernel of deep levels, bf16, at 1M rows on one chip."""
+    """Every rung of the node ladder (``histogram._NODE_BUCKETS``): the
+    node-matmul shapes of levels 0-7 and the sorted kernel of deep levels,
+    bf16, at 1M rows on one chip."""
+    from h2o3_tpu.ops.histogram import pad_nodes
+
+    assert pad_nodes(n_nodes) == n_nodes  # a rung: what a level launches
     from h2o3_tpu.ops.pallas_histogram import (
         _C, _NODE_MATMUL_MAX_KC, _build_histogram_pallas_jit)
 
@@ -152,3 +158,8 @@ def test_training_block_fits_one_chip(v5e, capsys):
               f"arguments {mem.argument_size_in_bytes:,} = {need:,} bytes "
               f"of {V5E_HBM_BYTES:,}")
     assert need < V5E_HBM_BYTES
+    # the [N] vectors round the kernels stay lane-dense (the barrier on the
+    # node-matmul kernel's operands, ISSUE 35): computed as [N, 1] rows of
+    # 128 lanes they made 14,112,391,680 bytes of temporaries at this very
+    # row count (ROADMAP S4b), 1,580,557,312 since
+    assert mem.temp_size_in_bytes < 2 * 10**9

@@ -487,9 +487,12 @@ PARENT_PROGRAMS = {
 
 
 @pytest.mark.parametrize("program", sorted(PARENT_PROGRAMS))
-def test_the_gate_a_numeric_fit_lowers_to_the_parents_programs(program, monkeypatch):
+def test_the_gate_a_numeric_fit_lowers_to_the_parents_programs(
+        program, monkeypatch, parents_level_plan):
     """With no ``enum`` column the set search, the sixth array and the set
-    routing are not traced at all: text for text the parent's programs."""
+    routing are not traced at all: text for text the parent's programs
+    (traced with the parents' node ladder and without the barrier on the
+    node-matmul kernel's operands, both of ISSUE 35: the fixture)."""
     TP = TreeParams
     S = jax.ShapeDtypeStruct
     if program == "block_scatter":
