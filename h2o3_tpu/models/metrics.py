@@ -187,13 +187,24 @@ def _roc_points(
     p = np.asarray(prob, dtype=np.float64)
     ok = ~(np.isnan(y) | np.isnan(p))
     y, p = y[ok], p[ok]
-    w, _ = _weighted(y, None if weights is None else np.asarray(weights)[ok])
     if nbins and len(np.unique(p)) > nbins:
         # histogram thresholds: uniform quantile-ish bin centers over score range
         edges = np.quantile(p, np.linspace(0, 1, nbins + 1))
         centers = np.unique(edges)
         idx = np.clip(np.searchsorted(centers, p, side="right") - 1, 0, len(centers) - 1)
         p = centers[idx]
+    if weights is None:
+        # rows that all weigh 1 need no order among themselves: count, for
+        # each distinct score, the rows of either class at or above it.  The
+        # same numbers as the sums below, without the argsort and the three
+        # gathers over every row (a third of a 32M-row fit's metrics)
+        pos = y > 0.5
+        ths = np.unique(p)
+        tps, fps = (
+            (len(s) - np.searchsorted(s, ths, side="left")).astype(np.float64)[::-1]
+            for s in (np.sort(p[pos]), np.sort(p[~pos])))
+        return ths[::-1], tps, fps, float(pos.sum()), float((~pos).sum())
+    w, _ = _weighted(y, np.asarray(weights)[ok])
     order = np.argsort(-p, kind="stable")
     ps, ys, ws = p[order], y[order], w[order]
     pos_w = np.where(ys > 0.5, ws, 0.0)
@@ -226,7 +237,7 @@ def binomial_metrics(
     y, p = y[ok], p[ok]
     w, wsum = _weighted(y, None if weights is None else np.asarray(weights)[ok])
 
-    ths, tps, fps, P, N = _roc_points(y, p, w, nbins)
+    ths, tps, fps, P, N = _roc_points(y, p, None if weights is None else w, nbins)
     if P == 0 or N == 0:
         auc = pr = float("nan")
     else:

@@ -61,6 +61,13 @@ TREE_SPLITS = telemetry.counter(
     labels=("kind",),
 )
 
+HIST_PSUM_BYTES = telemetry.counter(
+    "tree_hist_psum_bytes_total",
+    "bytes of level histograms (and leaf totals) a device of a mesh of "
+    "several handed to the per-level psum of the tree blocks it ran; a "
+    "one-device mesh sums nothing and adds nothing",
+)
+
 #: boosting rounds fused into one XLA program when no monitor is active
 #: (overridable via H2O3_TPU_TREE_BLOCK); also the deadline-check cadence
 DEFAULT_TREE_BLOCK = 16
@@ -91,6 +98,13 @@ class TreeParams:
     #: sets of its levels (categorical_encoding="enum") and 0 for a feature
     #: that splits on a threshold; () where no feature is categorical
     cat_levels: Tuple[int, ...] = ()
+    #: xgboost's ``min_child_weight``: a split needs Σh >= it on both
+    #: children, in place of the count test of ``min_rows``; None keeps the
+    #: count test
+    min_child_weight: Optional[float] = None
+    #: xgboost's ``scale_pos_weight``: g and h of a bernoulli fit's positive
+    #: rows times it
+    scale_pos_weight: float = 1.0
 
     @property
     def n_bins1(self) -> int:
@@ -323,7 +337,7 @@ def _winner_set(best_f, best_b, dl, cat_idx, key, key_s, code_s, present):
 def _split_search(
     hist, lam, alpha, gamma, lr, feat_mask, min_rows: float, n_bins1: int,
     constraints=None, node_lo=None, node_hi=None, child_stats: bool = False,
-    cat_levels: Tuple[int, ...] = (),
+    cat_levels: Tuple[int, ...] = (), min_child_weight: Optional[float] = None,
 ):
     """Per-node best split over (feature, bin, NA-direction).
 
@@ -356,6 +370,10 @@ def _split_search(
     last: left [K, B] bool, whether a row with code b of the chosen feature
     goes left — the prefix's levels, ``default_left`` for a level of a
     categorical that no row of the node has, and ``0..bin`` for a threshold.
+
+    A child must hold ``min_rows`` rows (the count channel), or, where
+    ``min_child_weight`` is given, a Σh of at least that much in its place
+    (xgboost's floor on the hessian).
     """
     B = n_bins1 - 1
     total = hist.sum(axis=2)  # [K, F, 3] — identical across F
@@ -394,7 +412,10 @@ def _split_search(
         hr = H[:, None, None] - hl
         cr = CNT[:, None, None] - cl
         gain = 0.5 * (side_score(gl, hl) + side_score(gr, hr) - parent[:, None, None]) - gamma
-        ok = (cl >= min_rows) & (cr >= min_rows)
+        if min_child_weight is None:
+            ok = (cl >= min_rows) & (cr >= min_rows)
+        else:
+            ok = (hl >= min_child_weight) & (hr >= min_child_weight)
         gain = jnp.where(ok, gain, -jnp.inf)
         if constraints is not None:
             wl = opt_w(gl, hl)
@@ -808,6 +829,7 @@ def _build_one_tree(
                 node_hi=b_hi if mono else None,
                 child_stats=subtract,
                 cat_levels=p.cat_levels,
+                min_child_weight=p.min_child_weight,
             )
             if sets:
                 with jax.named_scope("sets"):
@@ -893,6 +915,12 @@ def _make_block_fn(
                     # stats)
                     g_all = g_all * w[:, None]
                     h_all = h_all * w[:, None]
+                if p.scale_pos_weight != 1.0:
+                    # the class weight: a positive row's g and h count so
+                    # many times (the counts stay rows)
+                    cw = jnp.where(y > 0.5, jnp.float32(p.scale_pos_weight), 1.0)
+                    g_all = g_all * cw[:, None]
+                    h_all = h_all * cw[:, None]
             with jax.named_scope("sample"):
                 kr, kc, kt = jax.random.split(key_t, 3)
                 active = valid
@@ -1123,6 +1151,17 @@ def _train_boosted(
 
     n_bins1 = p.n_bins1
     n_cat = sum(1 for v in p.cat_levels if v)
+
+    def sharded(nbytes=None, **more) -> dict:
+        """What a span of something placed on, summed over or fetched from
+        a mesh of several devices says besides: the shards, the bytes a
+        shard (rows are dealt evenly) and ``more``. Nothing on one device."""
+        if nshards == 1:
+            return {}
+        if nbytes is not None:
+            more["bytes_per_shard"] = int(nbytes) // nshards
+        return {"shards": nshards, **more}
+
     if resume_from is not None:
         # continue training: reuse the checkpoint's binning + f0 exactly
         init_margin = resume_from.init_margin
@@ -1155,7 +1194,7 @@ def _train_boosted(
             bfm_host[:F] = bh.T
         nbytes = bh.nbytes + valid_h.nbytes + (
             bfm_host.nbytes if bfm_host is not None else 0)
-        with Span("bins_upload", bytes=nbytes):
+        with Span("bins_upload", bytes=nbytes, **sharded(nbytes)):
             bins_d = jax.device_put(bh, row_sharding(mesh, 2))
             valid_d = jax.device_put(valid_h, row_sharding(mesh, 1))
             bins_fm_d = None
@@ -1232,8 +1271,9 @@ def _train_boosted(
             w_host[:n] = np.asarray(weights, dtype=np.float32)
             w_d = jax.device_put(w_host, row_sharding(mesh, 1))
         jax.block_until_ready((y_d, margin, w_d))
-        upload.set(bytes=y_host.nbytes + margin_host.nbytes
-                   + (w_host.nbytes if w_d is not None else 0))
+        state_bytes = y_host.nbytes + margin_host.nbytes + (
+            w_host.nbytes if w_d is not None else 0)
+        upload.set(bytes=state_bytes, **sharded(state_bytes))
     mono_d = None
     if monotone is not None and np.any(np.asarray(monotone) != 0):
         mono_d = jnp.asarray(np.asarray(monotone, dtype=np.int32))
@@ -1260,6 +1300,11 @@ def _train_boosted(
     # what the block's levels launch, (built, slots, kernel) a level: the
     # span states it, so padding reads as slots / built without a trace
     hist_slots = level_plan(p_key, subtract_on)
+    # what one tree's levels hand to their psums, on each device: float32
+    # [slots, F, B+1, 3] a histogram level, [slots, 3] the leaf totals
+    psum_tree = (nshards > 1) * C * 12 * sum(
+        slots * (1 if kernel == "totals" else F * n_bins1)
+        for _, slots, kernel in hist_slots)
     while built < p.ntrees:
         block = (
             min(score_interval, p.ntrees - built)
@@ -1279,11 +1324,13 @@ def _train_boosted(
         with Span(
             "tree_block", objective=objective, trees=block, rows=n,
             first_tree=tree_offset + built, hist_slots=hist_slots,
+            **sharded(bytes_psummed=block * psum_tree),
         ):
             margin, trees_dev = fn(
                 bins_d, y_d, valid_d, margin, keys, bins_fm_d, w_d, mono_d
             )
             jax.block_until_ready(margin)
+        HIST_PSUM_BYTES.inc(block * psum_tree)
         with Span("tree_readback", trees=block) as readback:
             # [block, C, M] each; with set-valued splits a sixth, [block, C, M, W]
             fields = jax.device_get(trees_dev)
@@ -1294,7 +1341,7 @@ def _train_boosted(
             readback.set(splits=n_split, set_splits=n_set)
         built += block
         if monitor is not None:
-            with Span("budget_check") as check:
+            with Span("budget_check", **sharded()) as check:
                 final_host = np.asarray(jax.device_get(margin), np.float64)[:n]
                 stop = bool(monitor(built - 1, final_host))
                 check.set(stop=stop)
@@ -1308,7 +1355,8 @@ def _train_boosted(
     # margin starts without the earlier trees
     if fit_eval is not None and not (average and resume_from is not None):
         if final_host is None:
-            with Span("margin_readback", bytes=margin.nbytes):
+            with Span("margin_readback", bytes=margin.nbytes,
+                      **sharded(margin.nbytes)):
                 final_host = np.asarray(jax.device_get(margin))[:n]
         if average and built:
             f0 = bt.init_margin[None, :]

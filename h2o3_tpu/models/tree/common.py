@@ -64,9 +64,12 @@ NBINS_CATS = 1024
 #: (``score_traverse``), metrics from the margin a fit held
 #: (``model_performance``); and, kept as stated and not summed, what every
 #: level of a block launches: ``hist_slots``, one ``[nodes built, node slots
-#: launched, kernel]`` a level (``tree_block``; ``booster.level_plan``)
+#: launched, kernel]`` a level (``tree_block``; ``booster.level_plan``); on a
+#: mesh of several devices the bytes a shard of what was uploaded or read
+#: back (``bins_upload``, ``state_upload``, ``margin_readback``) and the bytes
+#: a device handed to the levels' psums (``tree_block``)
 SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks",
-               "fit_margin", "hist_slots")
+               "fit_margin", "hist_slots", "bytes_per_shard", "bytes_psummed")
 
 
 def resolve_tree_encoding(categorical_encoding: str) -> str:

@@ -589,6 +589,9 @@ def use_dist(frame, p, encoding: str) -> bool:
         return False
     if encoding == "one_hot_explicit":
         return False
+    if (getattr(p, "min_child_weight", None) is not None
+            or getattr(p, "scale_pos_weight", 1.0) != 1.0):
+        return False  # xgboost's hessian floor and class weight
     return True
 
 

@@ -38,7 +38,16 @@ class XGBoostParameters(ModelParameters):
     learn_rate: float = 0.3  # eta
     nbins: int = 256  # max_bins (hist/gpu_hist default)
     nbins_cats: int = 1024  # most levels a categorical may have under enum
-    min_rows: float = 1.0  # min_child_weight analogue on row counts
+    #: fewest ROWS a child may hold (H2O's min_rows, a count): the test a
+    #: split passes unless ``min_child_weight`` is given
+    min_rows: float = 1.0
+    #: libxgboost's min_child_weight, a floor on a child's sum of hessians:
+    #: when given, a split needs Σh >= it on both children and ``min_rows``
+    #: is not tested; None (the default) keeps the count test
+    min_child_weight: Optional[float] = None
+    #: libxgboost's scale_pos_weight: the gradient and hessian of a binary
+    #: fit's positive rows times it (training metrics stay unweighted)
+    scale_pos_weight: float = 1.0
     min_split_improvement: float = 0.0
     reg_lambda: float = 1.0
     reg_alpha: float = 0.0
@@ -87,11 +96,22 @@ class XGBoost(ModelBuilder):
                 f"xgboost does not support distribution {p.distribution!r}; "
                 f"choose from {sorted(self.DISTRIBUTIONS)}"
             )
-        # (libxgboost starts from base_score — 0.5 prob -> 0 margin; we use
-        # the data-driven init like the reference's H2O-side initial pred)
+        if p.min_child_weight is not None and p.min_child_weight < 0:
+            raise ValueError("min_child_weight must be non-negative")
+        if p.scale_pos_weight <= 0:
+            raise ValueError("scale_pos_weight must be positive")
+        # the init margin is the builder's data-driven prior, as GBM's: the
+        # link of the response's (weighted) mean, log(p / (1 - p)) for a
+        # binary fit, with ``scale_pos_weight`` left out of it. libxgboost
+        # starts from base_score (0.5 -> margin 0), which this builder has no
+        # parameter for
         model, X, y, weights, _, objective, f0, n_class_trees, mono = (
             tree_fit_setup(frame, p, XGBoostModel, use_offset=False)
         )
+        if p.scale_pos_weight != 1.0 and objective != "bernoulli":
+            raise ValueError(
+                "scale_pos_weight weighs the positive class of a binary "
+                f"response; this fit's objective is {objective!r}")
 
         tp = TreeParams(
             ntrees=_extra_trees(p, n_class_trees),
@@ -107,6 +127,8 @@ class XGBoost(ModelBuilder):
             col_sample_rate_per_tree=p.col_sample_rate_per_tree,
             seed=p.actual_seed(),
             cat_levels=model.cat_levels,
+            min_child_weight=p.min_child_weight,
+            scale_pos_weight=p.scale_pos_weight,
         )
 
         history = []

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import os
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Optional, Tuple  # noqa: F401
 
@@ -187,6 +188,11 @@ def _apply_bins_batched(X: np.ndarray, edges: np.ndarray) -> np.ndarray:
     return out
 
 
+#: ``apply_bins`` bins a feature a thread, up to this many, from this many cells
+_BIN_THREADS = 16
+_BIN_THREADS_MIN_CELLS = 1 << 22
+
+
 def apply_bins(X: np.ndarray, edges: np.ndarray, cat_levels=()) -> np.ndarray:
     """Quantize raw features to bin codes [N, F] int8-range; NA -> the last
     bucket (``na_code``: ``nbins`` where no feature is categorical).
@@ -215,16 +221,30 @@ def apply_bins(X: np.ndarray, edges: np.ndarray, cat_levels=()) -> np.ndarray:
     with telemetry.Span("apply_bins", rows=n, features=F):
         if F > 32 * max(n, 1) and not cats:  # wide-short: loop overhead dominates
             out = _apply_bins_batched(X, edges)
+            out[np.isnan(X)] = na  # NA bucket (DHistogram NA bin at end)
         else:
             out = np.empty((n, F), dtype=np.int32)
-            for f in range(F):
+
+            def one(f):
+                col = X[:, f]
                 if f in cats:
-                    col = X[:, f]
                     known = (col >= 0) & (col < cat_levels[f])  # NaN: False
                     out[:, f] = np.where(known, col, na)
                 else:
-                    out[:, f] = np.searchsorted(edges[f], X[:, f], side="right")
-        out[np.isnan(X)] = na  # NA bucket (DHistogram NA bin at end)
+                    out[:, f] = np.searchsorted(edges[f], col, side="right")
+                out[np.isnan(col), f] = na  # NA bucket (DHistogram NA bin at end)
+
+            # a feature's pass touches no other's cells, and searchsorted and
+            # the copies release the interpreter: a matrix large enough to pay
+            # for the threads takes them (32M x 13 on one: 30 s of a fit's
+            # first touch of a frame)
+            workers = min(F, os.cpu_count() or 1, _BIN_THREADS)
+            if workers < 2 or n * F < _BIN_THREADS_MIN_CELLS:
+                for f in range(F):
+                    one(f)
+            else:
+                with ThreadPoolExecutor(workers) as pool:
+                    list(pool.map(one, range(F)))
     return out
 
 

@@ -282,6 +282,26 @@ class TestApplyBins:
         assert np.array_equal(apply_bins(Xe, edges[:6]),
                               self._reference(Xe, edges[:6]))
 
+    def test_a_feature_a_thread_matches_reference(self, rng, monkeypatch):
+        """Past ``_BIN_THREADS_MIN_CELLS`` a feature is binned on a thread of
+        its own: the same codes, NA and a categorical column among them."""
+        from h2o3_tpu.ops import histogram
+
+        monkeypatch.setattr(histogram, "_BIN_THREADS_MIN_CELLS", 1000)
+        X = rng.normal(size=(5000, 7))
+        X[::7, 3] = np.nan
+        X[:, 6] = rng.integers(0, 5, size=5000)
+        X[::9, 6] = np.nan
+        X[::10, 6] = 7.0  # a level the fit did not know
+        edges = histogram.make_bins(X, nbins=16)
+        assert np.array_equal(histogram.apply_bins(X, edges), self._reference(X, edges))
+        cats = (0, 0, 0, 0, 0, 0, 5)
+        want = self._reference(X, edges)
+        known = (X[:, 6] >= 0) & (X[:, 6] < 5)
+        want[:, 6] = np.where(known, X[:, 6], histogram.na_code(16, cats))
+        want[np.isnan(X[:, 3]), 3] = histogram.na_code(16, cats)
+        assert np.array_equal(histogram.apply_bins(X, edges, cats), want)
+
     def test_batched_wide_path_matches_reference(self, rng):
         from h2o3_tpu.ops.histogram import _apply_bins_batched, apply_bins
 

@@ -33,6 +33,29 @@ def test_auc_400_bins_close_to_exact(binom_data):
     assert approx == pytest.approx(exact, abs=2e-3)
 
 
+@pytest.mark.parametrize("nbins", [0, 400])
+@pytest.mark.parametrize("n", [1, 7, 5000])
+def test_unweighted_roc_points_are_the_weighted_ones_bit_for_bit(n, nbins):
+    """Rows that all weigh 1 take a path with no argsort: thresholds, counts
+    and every metric read from them equal the general path's under weights of
+    one, with tied scores, one class absent (n = 1) and NaN scores."""
+    rng = np.random.default_rng(n)
+    p = (1 / (1 + np.exp(-rng.normal(0, 1.5, n)))).astype(np.float32)
+    p[rng.integers(0, n, n // 5)] = 0.5
+    y = (rng.random(n) < p).astype(np.int32)
+    if n > 1:
+        p[rng.integers(0, n, n // 50)] = np.nan
+    fast = M.binomial_metrics(y, p, nbins=nbins)
+    general = M.binomial_metrics(y, p, weights=np.ones(n), nbins=nbins)
+    for name in ("thresholds", "tps", "fps"):
+        assert np.array_equal(getattr(fast, name), getattr(general, name)), name
+    for name in ("auc", "pr_auc", "logloss", "mse", "max_f1_threshold",
+                 "mean_per_class_error", "nobs"):
+        a, b = getattr(fast, name), getattr(general, name)
+        assert a == b or (a != a and b != b), name
+    assert fast.cm == general.cm
+
+
 def test_max_f1_threshold_and_cm(binom_data):
     y, p = binom_data
     m = M.binomial_metrics(y, p)
