@@ -12,16 +12,27 @@ GainsLift. Metric definitions below match the reference's semantics:
   * Deviances per family follow ``hex/Distribution.java`` definitions.
 
 Inputs are host numpy arrays (predictions already gathered); each metric is a
-cheap O(N) or O(N log N) pass. Device-side streaming computation plugs in at
-the compute layer when metrics are fused into scoring loops.
+cheap O(N) or O(N log N) pass. The one exception is the binomial metrics of a
+margin that is still on the device (a tree fit's own training rows):
+``MarginRoc`` sorts and counts there, as integers, and the host adds what is
+a float64 sum in runs of rows (``binomial_losses``).
 """
 
 from __future__ import annotations
 
+import functools
+import os
+import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 # ---------------------------------------------------------------------------
@@ -285,6 +296,231 @@ def binomial_metrics(
     )
     m._p, m._n = P, N
     return m
+
+
+# ---------------------------------------------------------------------------
+# binomial, from a margin that is on the device
+#
+# One definition of each metric and two ways to count. ``binomial_metrics``
+# orders the scores on the host. A tree fit that ends with its rows' margin
+# on the device (float32), a float64 copy of it on the host and no weights
+# has the device sort it once and count the positives down the order, and
+# the host read every sum from those counts and that copy in runs of rows,
+# float sums in float64 and the ROC's area as the integer it is:
+# ``MarginRoc`` and ``binomial_losses``. ``tests/test_tree_train_metrics.py``
+# holds the two together.
+
+#: rows a pass over every row takes at a time, and the threads it takes them
+#: on (numpy's ufuncs release the interpreter)
+_RUN_ROWS = 1 << 20
+_RUN_THREADS = 16
+
+#: sorted rows a piece of the device's output holds: the host fetches the
+#: pieces that hold a row it counted, not the padding's
+_ROC_PIECE = 1 << 22
+
+_I32_MAX = np.iinfo(np.int32).max
+
+
+def _in_runs(fn: Callable[[int, int], object], n: int, run: int) -> list:
+    """``fn(start, stop)`` over ``[0, n)`` in runs of ``run`` rows, results
+    in the runs' order; on threads where there are several runs."""
+    spans = [(a, min(a + run, n)) for a in range(0, n, run)]
+    workers = min(len(spans), os.cpu_count() or 1, _RUN_THREADS)
+    if workers < 2:
+        return [fn(a, b) for a, b in spans]
+    with ThreadPoolExecutor(workers) as pool:
+        return list(pool.map(lambda ab: fn(*ab), spans))
+
+
+def binomial_losses(actual: np.ndarray, margin: np.ndarray,
+                    link: Callable[[np.ndarray], np.ndarray],
+                    run: int = _RUN_ROWS) -> Tuple[float, float, int]:
+    """(logloss, mse, rows) of the scores ``link(margin)`` against labels
+    {0, 1}, each row weighing 1: ``binomial_metrics``' own expressions, the
+    link within, over runs of ``run`` rows, so nothing the size of the rows
+    is made. A row whose label or margin is NaN is left out."""
+    eps = 1e-15
+
+    def one(a, b):
+        y = np.asarray(actual[a:b], dtype=np.float64)
+        m = np.asarray(margin[a:b], dtype=np.float64)
+        ok = ~(np.isnan(y) | np.isnan(m))
+        if not ok.all():
+            y, m = y[ok], m[ok]
+        p = link(m)
+        pc = np.clip(p, eps, 1 - eps)
+        return (float(np.sum(-(y * np.log(pc) + (1 - y) * np.log(1 - pc)))),
+                float(np.sum((y - p) ** 2)), len(y))
+
+    parts = _in_runs(one, len(actual), run)
+    n = sum(k for _, _, k in parts)
+    if not n:
+        return float("nan"), float("nan"), 0
+    return (float(np.sum([ll for ll, _, _ in parts]) / n),
+            float(np.sum([se for _, se, _ in parts]) / n), n)
+
+
+def roc_area_counts(tps: np.ndarray, fps: np.ndarray, run: int = _RUN_ROWS) -> int:
+    """Twice the area under the ROC's trapezoids times P x N, as the exact
+    integer it is: over the thresholds, (fp - the fp before) x (tp + the tp
+    before), from cumulative counts (under 2^31). A term can pass 2^53, so
+    tp + tp is taken in two 16-bit halves, whose sums int64 holds (the fp
+    differences sum to N)."""
+
+    def one(a, b):
+        tp = np.asarray(tps[max(a - 1, 0):b]).astype(np.int64)
+        fp = np.asarray(fps[max(a - 1, 0):b]).astype(np.int64)
+        if a == 0:
+            tp, fp = np.concatenate([[0], tp]), np.concatenate([[0], fp])
+        d, s = np.diff(fp), tp[1:] + tp[:-1]
+        return int(np.sum(d * (s & 0xFFFF))), int(np.sum(d * (s >> 16)))
+
+    parts = _in_runs(one, len(tps), run)
+    return sum(lo for lo, _ in parts) + (sum(hi for _, hi in parts) << 16)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "piece"))
+def _margin_order(margin, y, valid, *, mesh, piece):
+    """The order of a binomial margin and the positives down it, for
+    ``MarginRoc``: one program a (row count, mesh), every shape static.
+
+    margin [n, 1] float32, y [n] (0 / 1), valid [n] bool, dealt over
+    ``mesh`` by rows or whole on its one device; they are gathered first, so
+    every device sorts the whole (9 bytes a row: 288 MB at 32M rows).
+    Returns ``(counts, pieces)``: counts = (rows counted, positives among
+    them) int32, and per piece of ``piece`` rows (margin float32, positives
+    at or above it int32), descending by margin (-0.0 under 0.0), the rows
+    counted first and what follows them to be ignored."""
+    whole = NamedSharding(mesh, PartitionSpec())
+    m, y, valid = (lax.with_sharding_constraint(a, whole)
+                   for a in (margin[:, 0], y, valid))
+    ok = valid & ~jnp.isnan(m)
+    # an int32 that ascends with the float, turned over: ascending keys are
+    # descending margins, dropped rows and padding last (no float's key is
+    # INT_MAX but a NaN's). Rows of one margin need no order among them
+    bits = lax.bitcast_convert_type(m, jnp.int32)
+    key = jnp.where(ok, ~jnp.where(bits < 0, bits ^ _I32_MAX, bits), _I32_MAX)
+    key, pos = lax.sort((key, ((y > 0.5) & ok).astype(jnp.int32)),
+                        num_keys=1, is_stable=False)
+    tp = jnp.cumsum(pos, dtype=jnp.int32)
+    turned = ~key
+    score = lax.bitcast_convert_type(
+        jnp.where(turned < 0, turned ^ _I32_MAX, turned), jnp.float32)
+    counts = jnp.stack([jnp.sum(ok, dtype=jnp.int32), tp[-1]])
+    pieces = tuple((score[a:a + piece], tp[a:a + piece])
+                   for a in range(0, m.shape[0], piece))
+    return counts, pieces
+
+
+class MarginRoc:
+    """``binomial_metrics`` of a margin that is on the device, for rows that
+    all weigh 1. Creating it starts the device's part and returns (dispatch
+    is asynchronous): one sort by margin and the count of positives down the
+    order. ``metrics`` waits for it, fetches (margin, positives at or above
+    it) a row and reads every number from those counts in runs of rows: a
+    threshold a distinct score (the link in float64), the ROC's area as an
+    integer, the max-F1 scan, PR-AUC and the confusion matrix in float64.
+    Logloss and mse come from the host's copy of the margin
+    (``binomial_losses``), which the host can add meanwhile."""
+
+    def __init__(self, margin, y, valid, mesh, piece: int = _ROC_PIECE):
+        self.t0 = time.perf_counter()
+        self._out = _margin_order(margin, y, valid, mesh=mesh, piece=piece)
+        self._piece = piece
+        #: seconds from the dispatch until the host found the counts ready,
+        #: and the thresholds (distinct scores) read from them
+        self.device_s = self.distinct = None
+
+    def _fetch(self) -> Tuple[np.ndarray, np.ndarray, int]:
+        """(margin, positives at or above it) of the rows counted, as host
+        arrays in the device's order, and the positives in all; drops the
+        device's arrays."""
+        counts, pieces = self._out
+        self._out = None
+        nobs, npos = (int(v) for v in jax.device_get(counts))
+        self.device_s = time.perf_counter() - self.t0
+        pieces = pieces[:-(-nobs // self._piece)]
+        for arrays in pieces:
+            for a in arrays:
+                a.copy_to_host_async()
+        score, tp = np.empty(nobs, np.float32), np.empty(nobs, np.int32)
+        for i, arrays in enumerate(pieces):
+            a = i * self._piece
+            for col, dev in zip((score, tp), arrays):
+                col[a:a + self._piece] = np.asarray(dev)[:nobs - a]
+        return score, tp, npos
+
+    def metrics(self, losses: Tuple[float, float, int],
+                link: Callable[[np.ndarray], np.ndarray],
+                run: int = _RUN_ROWS) -> BinomialMetrics:
+        """The metrics; ``losses`` is ``binomial_losses`` of the same rows,
+        ``link`` what turns a margin into the score of class 1."""
+        score, tp, npos = self._fetch()
+        nobs = len(score)
+        logloss, mse, counted = losses
+        if counted != nobs:
+            raise ValueError(f"the device counted {nobs} rows of the margin, "
+                             f"the host's copy holds {counted}")
+        P, N = float(npos), float(nobs - npos)
+
+        def thresholds(a, b):
+            """The last row of every run of one SCORE in [a, b): the host
+            tells rows apart by their score, so margins that the link gives
+            one score (it saturates; -0.0 and 0.0) are one threshold."""
+            p = link(score[a:min(b + 1, nobs)].astype(np.float64))
+            last = np.ones(b - a, bool)
+            last[:len(p) - 1] = p[:-1] != p[1:]
+            at = np.flatnonzero(last)
+            tps = tp[a:b][at].astype(np.float64)
+            return p[at], tps, (at + (a + 1)) - tps
+
+        parts = _in_runs(thresholds, nobs, run)
+        ths, tps, fps = (np.concatenate([part[i] for part in parts]) if parts
+                         else np.empty(0) for i in range(3))
+        k = self.distinct = len(ths)
+        both = P > 0 and N > 0
+
+        def scan(a, b):
+            """A run's best F1 and where, and its part of PR-AUC's
+            trapezoids: ``binomial_metrics``' expressions, with the
+            threshold before the run beside it."""
+            lo = max(a - 1, 0)
+            t, f = tps[lo:b], fps[lo:b]
+            prec = t / np.maximum(t + f, 1e-300)
+            rec = t / P
+            f1 = np.where(prec + rec > 0,
+                          2 * prec * rec / np.maximum(prec + rec, 1e-300), 0.0)[a - lo:]
+            best = int(np.argmax(f1))
+            if a == 0:
+                prec, rec = np.concatenate([[prec[0]], prec]), np.concatenate([[0.0], rec])
+            return (float(f1[best]), a + best,
+                    float(np.sum(np.diff(rec) * (prec[1:] + prec[:-1]) / 2.0)))
+
+        if both:
+            parts = _in_runs(scan, k, run)
+            auc = roc_area_counts(tps, fps, run) / (2 * npos * (nobs - npos))
+            pr = float(np.sum([part[2] for part in parts]))
+            # the first of the best, as argmax over all of them finds it
+            best = max(parts, key=lambda part: part[0])[1]
+            thr = float(ths[best])
+            cm = ConfusionMatrix(tn=N - fps[best], fp=fps[best], fn=P - tps[best],
+                                 tp=tps[best], threshold=thr)
+        else:
+            auc = pr = float("nan")
+            thr = 0.5
+            cm = _cm_at(ths, tps, fps, P, N, thr) if k else ConfusionMatrix(N, 0, P, 0, thr)
+        tpr_ = cm.tp / P if P else float("nan")
+        tnr_ = cm.tn / N if N else float("nan")
+        m = BinomialMetrics(
+            auc=auc, pr_auc=pr, gini=2 * auc - 1 if auc == auc else float("nan"),
+            logloss=logloss, mse=mse, rmse=float(np.sqrt(mse)),
+            mean_per_class_error=float(1 - (tpr_ + tnr_) / 2),
+            max_f1_threshold=thr, cm=cm, nobs=nobs,
+            thresholds=ths, tps=tps, fps=fps,
+        )
+        m._p, m._n = P, N
+        return m
 
 
 # ---------------------------------------------------------------------------

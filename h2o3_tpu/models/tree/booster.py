@@ -993,7 +993,9 @@ class BoostedTrees:
         #: what a fit leaves for its own training metrics and the call
         #: drops: {"frame", "y", "w", "margin"}, the margin
         #: ``predict_margin`` would return for the rows of ``frame`` the
-        #: fit kept (``common.TreeModelBase.model_performance``)
+        #: fit kept (``common.TreeModelBase.model_performance``); where that
+        #: margin is the one the blocks summed on the device, "device" too:
+        #: {"margin", "y", "valid", "mesh"} as the blocks held them
         self.fit_eval: Optional[dict] = None
 
     @property
@@ -1362,4 +1364,9 @@ def _train_boosted(
             f0 = bt.init_margin[None, :]
             final_host = f0 + (final_host - f0) / built
         bt.fit_eval = dict(fit_eval, margin=final_host)
+        if not average:
+            # the ensemble's own margin where it lives, float32 and padded,
+            # beside the response and the mask of the rows that are real
+            bt.fit_eval["device"] = {"margin": margin, "y": y_d,
+                                     "valid": valid_d, "mesh": mesh}
     return bt

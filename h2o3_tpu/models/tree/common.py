@@ -35,8 +35,9 @@ from h2o3_tpu.util.telemetry import Span
 TRAIN_METRICS = telemetry.counter(
     "tree_train_metrics_total",
     "metrics of a tree model over a frame, by where the margin came from: "
-    "the fit's own final margin of its training frame (fit_margin), or a "
-    "walk of the trees over the frame (walk)",
+    "the fit's own final margin of its training frame, ordered and counted "
+    "on the host (fit_margin) or on the device where it lives "
+    "(fit_margin_device), or a walk of the trees over the frame (walk)",
     labels=("source",),
 )
 
@@ -69,7 +70,8 @@ NBINS_CATS = 1024
 #: back (``bins_upload``, ``state_upload``, ``margin_readback``) and the bytes
 #: a device handed to the levels' psums (``tree_block``)
 SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks",
-               "fit_margin", "hist_slots", "bytes_per_shard", "bytes_psummed")
+               "fit_margin", "fit_margin_device", "hist_slots",
+               "bytes_per_shard", "bytes_psummed")
 
 
 def resolve_tree_encoding(categorical_encoding: str) -> str:
@@ -702,12 +704,32 @@ class TreeModelBase(Model):
                 frame = self._apply_preprocessors(frame)
                 return self._metrics_from_raw(frame, self._predict_raw(frame))
         self.booster.fit_eval = None
-        TRAIN_METRICS.inc(source="fit_margin")
-        with Span("model_performance", rows=len(ev["y"]), source="fit_margin",
-                  fit_margin=1):
-            with Span("score_link", rows=len(ev["y"])):
+        # a binomial ensemble's own margin, still on the device, of rows that
+        # all weigh 1, is ordered and counted there (``metrics.MarginRoc``)
+        dev = ev.get("device")
+        if not (ev["w"] is None and self.nclasses == 2
+                and self.distribution == "bernoulli"):
+            dev = None
+        source = "fit_margin" if dev is None else "fit_margin_device"
+        rows = len(ev["y"])
+        says = {source: 1}
+        if self.nclasses == 2:
+            says["roc"] = "host" if dev is None else "device"
+        TRAIN_METRICS.inc(source=source)
+        with Span("model_performance", rows=rows, source="fit_margin", **says):
+            if dev is not None:
+                roc = M.MarginRoc(**dev)
+                # while the device sorts: the losses in runs of rows, with
+                # what is left of the link inside a run
+                with Span("score_link", rows=rows):
+                    losses = M.binomial_losses(ev["y"], ev["margin"][:, 0], sigmoid)
+                with Span("score_metrics", rows=rows) as span:
+                    metrics = roc.metrics(losses, sigmoid)
+                    span.set(distinct=roc.distinct, device_s=round(roc.device_s, 6))
+                    return metrics
+            with Span("score_link", rows=rows):
                 raw = self._raw_from_margin(np.asarray(ev["margin"], np.float64))
-            with Span("score_metrics", rows=len(ev["y"])):
+            with Span("score_metrics", rows=rows):
                 return self._metrics(np.asarray(ev["y"], np.float64), raw, ev["w"])
 
     def predict_contributions(self, frame: Frame, background_frame=None) -> Frame:

@@ -124,7 +124,7 @@ def test_fit_profile_rides_the_model_and_the_log(fitted):
                    ("score/score_link", 2), ("score/score_metrics", 2)):
         assert prof[key]["n"] == n and prof[key]["s"] >= 0.0
     assert prof["model_performance"]["n"] == 2
-    assert prof["model_performance"]["fit_margin"] == 1
+    assert prof["model_performance"]["fit_margin_device"] == 1
     assert "score/tree_block" not in prof
     done = [ln for ln in log.recent(500)
             if "gbm train done" in ln and str(model.key) in ln]

@@ -56,6 +56,72 @@ def test_unweighted_roc_points_are_the_weighted_ones_bit_for_bit(n, nbins):
     assert fast.cm == general.cm
 
 
+def tied_scores(n, seed=3):
+    rng = np.random.default_rng(seed)
+    p = np.round(1 / (1 + np.exp(-rng.normal(0, 1.5, n))), 3)  # ~1000 distinct
+    return (rng.random(n) < p).astype(np.float64), p
+
+
+def test_the_integer_area_is_the_trapezoids_and_the_pairs():
+    """``roc_area_counts``: twice the ROC's area times P x N is an integer,
+    2 x (pairs a positive outscores a negative) + (pairs that tie)."""
+    y, p = tied_scores(100_000)
+    m = M.binomial_metrics(y, p)
+    P, N = int(m._p), int(m._n)
+    area = M.roc_area_counts(m.tps, m.fps)
+    assert isinstance(area, int)
+    assert area / (2 * P * N) == pytest.approx(m.auc, rel=1e-13)
+    tpr, fpr = np.concatenate([[0.0], m.tps / P]), np.concatenate([[0.0], m.fps / N])
+    assert area / (2 * P * N) == pytest.approx(float(np.trapezoid(tpr, fpr)), rel=1e-13)
+    neg = np.sort(p[y == 0])
+    below = np.searchsorted(neg, p[y == 1], side="left")
+    upto = np.searchsorted(neg, p[y == 1], side="right")
+    assert area == 2 * int(below.sum()) + int((upto - below).sum())
+    # a run's first entry needs the one before the run: any run length
+    for run in (1, 7, 999, 1 << 20):
+        assert M.roc_area_counts(m.tps.astype(np.int32), m.fps.astype(np.int32), run) == area
+
+
+def test_the_integer_area_holds_what_float64_cannot():
+    """Counts near 2^31: a term passes 2^53 and the whole 2^63."""
+    tps = np.array([2**30, 2**31 - 2], np.int64)
+    fps = np.array([2**30, 2**31 - 2], np.int64)
+    want = 2**30 * 2**30 + (2**30 - 2) * (2**31 - 2 + 2**30)
+    assert M.roc_area_counts(tps, fps) == want
+    assert M.roc_area_counts(np.zeros(0), np.zeros(0)) == 0
+
+
+@pytest.mark.parametrize("run", [1, 7, 4999, 5000, 1 << 20])
+def test_losses_in_runs_are_the_one_shot_sums(run):
+    """``binomial_losses``: ``binomial_metrics``' logloss and mse with the
+    link inside a run of rows, whatever the run's length (one row, a length
+    that does not divide the rows, all of them), NaN rows left out."""
+    y, _ = tied_scores(5000, seed=run)
+    rng = np.random.default_rng(run)
+    margin = rng.normal(0, 3, 5000).astype(np.float32)
+    margin[::97] = np.nan
+    margin[5:9] = [60.0, -60.0, 800.0, -800.0]  # the clip at 1e-15, and past exp
+
+    def link(x):
+        with np.errstate(over="ignore"):
+            return 1 / (1 + np.exp(-x))
+
+    whole = M.binomial_metrics(y, link(margin.astype(np.float64)))
+    logloss, mse, rows = M.binomial_losses(y, margin, link, run=run)
+    assert rows == whole.nobs == 5000 - len(margin[::97])
+    assert logloss == pytest.approx(whole.logloss, rel=1e-13)
+    assert mse == pytest.approx(whole.mse, rel=1e-13)
+
+
+def test_losses_of_no_rows():
+    link = lambda x: 1 / (1 + np.exp(-x))  # noqa: E731
+    logloss, mse, rows = M.binomial_losses(np.zeros(0), np.zeros(0), link)
+    assert rows == 0 and logloss != logloss and mse != mse
+    logloss, mse, rows = M.binomial_losses(np.array([np.nan, 1.0]),
+                                           np.array([0.5, np.nan]), link, run=1)
+    assert rows == 0 and logloss != logloss and mse != mse
+
+
 def test_max_f1_threshold_and_cm(binom_data):
     y, p = binom_data
     m = M.binomial_metrics(y, p)

@@ -21,12 +21,13 @@ import jax
 import jax.numpy as jnp
 
 from h2o3_tpu.frame.frame import NA_CAT, ColType, Column, Frame
+from h2o3_tpu.models import metrics as M
 from h2o3_tpu.models.tree import booster
-from h2o3_tpu.models.tree.common import SPAN_COUNTS, TRAIN_METRICS
+from h2o3_tpu.models.tree.common import SPAN_COUNTS, TRAIN_METRICS, sigmoid
 from h2o3_tpu.models.tree.drf import DRF
 from h2o3_tpu.models.tree.gbm import GBM, GBMParameters
 from h2o3_tpu.models.tree.xgboost import XGBoost
-from h2o3_tpu.parallel.mesh import default_mesh
+from h2o3_tpu.parallel.mesh import default_mesh, row_sharding
 from h2o3_tpu.util import timeline
 
 pytestmark = pytest.mark.leaks_keys
@@ -98,6 +99,18 @@ CASES = {
 }
 
 
+#: the cases whose margin is a binomial ensemble's own and whose rows all
+#: weigh 1: ordered and counted on the device (ISSUE 37); every other case's
+#: training metrics are ``metrics.binomial_metrics`` and its kin on the host
+ON_DEVICE = {"bernoulli", "sampled", "offset", "na_response", "budget_monitor",
+             "early_stopping_monitor", "enum_sets", "xgboost"}
+
+
+def margin_sources():
+    return {s: TRAIN_METRICS.value(source=s)
+            for s in ("fit_margin", "fit_margin_device", "walk")}
+
+
 def last_performance_span():
     return [e for e in timeline.snapshot(timeline.CAPACITY)
             if e["kind"] == "model_performance"][-1]
@@ -122,10 +135,14 @@ def assert_same_metrics(ours, walk, zero_weight=0):
 def test_training_metrics_are_the_walks(case):
     builder, extra, shape = CASES[case]
     frame = frame_of(**shape)
-    margin0 = TRAIN_METRICS.value(source="fit_margin")
+    before = margin_sources()
     model = builder(**{**BASE, **extra}).train(frame)
-    assert TRAIN_METRICS.value(source="fit_margin") == margin0 + 1
-    assert last_performance_span()["source"] == "fit_margin"
+    source = "fit_margin_device" if case in ON_DEVICE else "fit_margin"
+    assert margin_sources() == {**before, source: before[source] + 1}
+    perf = last_performance_span()
+    assert perf["source"] == "fit_margin" and perf[source] == 1
+    assert perf.get("roc") == (
+        None if model.nclasses != 2 else "device" if case in ON_DEVICE else "host")
     # consumed by the fit's own call: the same frame is walked now
     assert model.booster.fit_eval is None
     walked = model.model_performance(frame)
@@ -240,15 +257,19 @@ def test_a_saved_model_carries_no_margin(tmp_path):
 
 def test_spans_and_counter_of_a_fit():
     frame = frame_of()
-    before = {s: TRAIN_METRICS.value(source=s) for s in ("fit_margin", "walk")}
+    before = margin_sources()
     model = GBM(**dict(BASE, max_runtime_secs=600.0)).train(frame)
-    assert TRAIN_METRICS.value(source="fit_margin") == before["fit_margin"] + 1
-    assert TRAIN_METRICS.value(source="walk") == before["walk"]
+    assert margin_sources() == {
+        **before, "fit_margin_device": before["fit_margin_device"] + 1}
     events = [e for e in timeline.snapshot(timeline.CAPACITY) if "parent_id" in e]
     perf = [e for e in events if e["kind"] == "model_performance"][-1]
     assert perf["source"] == "fit_margin" and perf["rows"] == N
-    under = [e["kind"] for e in events if e["parent_id"] == perf["span_id"]]
+    assert perf["roc"] == "device"
+    under = {e["kind"]: e for e in events if e["parent_id"] == perf["span_id"]}
     assert sorted(under) == ["score_link", "score_metrics"]
+    # the thresholds counted, and dispatch to ready on the device
+    assert under["score_metrics"]["distinct"] == len(model.training_metrics.thresholds)
+    assert 0 < under["score_metrics"]["device_s"] < 60
     fit = {e["kind"] for e in events if e["trace_id"] == perf["trace_id"]}
     assert not fit & {"score_traverse", "margin_readback"}  # the budget check had it
     by_id = {e["span_id"]: e for e in events}
@@ -257,19 +278,240 @@ def test_spans_and_counter_of_a_fit():
                    if e["kind"] == kind and e["trace_id"] == perf["trace_id"]}
         assert "model_performance" not in parents
     # the fit's profile and its `train done` line say so
-    assert "fit_margin" in SPAN_COUNTS
+    assert {"fit_margin", "fit_margin_device"} <= set(SPAN_COUNTS)
     prof = model.fit_profile
     assert prof["model_performance"] == {"s": prof["model_performance"]["s"], "n": 1,
-                                         "fit_margin": 1}
+                                         "fit_margin_device": 1}
     assert set(k for k in prof if k.startswith("score/")) == {
         "score/score_link", "score/score_metrics"}
     from h2o3_tpu.util import log
 
     done = [ln for ln in log.recent(500)
             if "gbm train done" in ln and str(model.key) in ln]
-    assert done and "fit_margin=1" in done[-1]
+    assert done and "fit_margin_device=1" in done[-1]
     model.model_performance(frame)
     assert TRAIN_METRICS.value(source="walk") == before["walk"] + 1
+
+
+# ---------------------------------------------------------------------------
+# the ROC counted where the margin lives (ISSUE 37)
+
+
+def assert_the_hosts_metrics(ours, y, margin):
+    """``ours`` against ``metrics.binomial_metrics`` of the same rows' scores:
+    what is order and counting bit for bit, what is a float64 sum to the
+    order of its additions."""
+    y, margin = np.asarray(y, np.float64), np.asarray(margin, np.float64)
+    with np.errstate(over="ignore", invalid="ignore", divide="ignore"):
+        host = M.binomial_metrics(y, sigmoid(margin))
+    for name in ("thresholds", "tps", "fps"):
+        assert getattr(ours, name).dtype == np.float64
+        assert np.array_equal(getattr(ours, name), getattr(host, name)), name
+    assert ours.max_f1_threshold == host.max_f1_threshold
+    assert ours.cm == host.cm and ours.nobs == host.nobs
+    assert (ours._p, ours._n) == (host._p, host._n)
+    assert ours.confusion_matrix(0.3) == host.confusion_matrix(0.3)
+    for name in ("auc", "pr_auc", "gini", "logloss", "mse", "rmse",
+                 "mean_per_class_error"):
+        a, b = getattr(ours, name), getattr(host, name)
+        assert (a != a and b != b) or a == pytest.approx(b, rel=1e-12, abs=0), name
+
+
+def on_the_device(y, margin, n_pad=None, mesh=None, piece=M._ROC_PIECE, run=M._RUN_ROWS):
+    """``MarginRoc`` and ``binomial_losses`` of rows padded to ``n_pad`` and
+    dealt over ``mesh``, as a fit leaves them."""
+    mesh = mesh or default_mesh(n_devices=1)
+    n = len(y)
+    n_pad = n_pad or n + (-n) % mesh.devices.size
+    held = np.zeros((n_pad, 1), np.float32)
+    held[:n, 0] = margin
+    held[n:] = 7.0  # padding holds anything
+    y_pad = np.zeros(n_pad, np.float32)
+    y_pad[:n] = y
+    roc = M.MarginRoc(
+        jax.device_put(held, row_sharding(mesh, 2)),
+        jax.device_put(y_pad, row_sharding(mesh, 1)),
+        jax.device_put(np.arange(n_pad) < n, row_sharding(mesh, 1)), mesh, piece)
+    with np.errstate(over="ignore"):
+        losses = M.binomial_losses(y, np.asarray(held[:n, 0], np.float64), sigmoid, run)
+        return roc.metrics(losses, sigmoid, run), roc
+
+
+def margins_of(case, n=3000):
+    rng = np.random.default_rng(len(case))
+    m = rng.normal(0, 2, n).astype(np.float32)
+    y = (rng.random(n) < sigmoid(m)).astype(np.float64)
+    if case == "two_scores":  # a depth-1 model
+        m = np.where(rng.random(n) < 0.4, np.float32(-0.7), np.float32(0.9))
+    elif case == "no_positives":
+        y[:] = 0
+    elif case == "no_negatives":
+        y[:] = 1
+    elif case == "one_score":
+        m[:] = 0.25
+    elif case == "saturating":  # the link gives whole runs of margins one score
+        m[: n // 2] = rng.choice(np.float32([40, 40.5, 100, -100, -800, 36.5, 37, 25,
+                                             25.000002, 3e38, -3e38]), n // 2)
+    elif case == "signed_zeros":
+        m[: n // 2] = rng.choice(np.float32([-0.0, 0.0, 1e-30, -1e-30, 1e-9]), n // 2)
+    elif case == "nan_margin":
+        m[::37] = np.nan
+    elif case == "infinite":
+        m[::41], m[::43] = np.inf, -np.inf
+    elif case == "ties":
+        m = np.round(m, 1)
+    return y, m
+
+
+@pytest.mark.filterwarnings("ignore:overflow encountered in exp")
+@pytest.mark.parametrize("layout", ["whole", "padded", "mesh4", "pieces"])
+@pytest.mark.parametrize("case", [
+    "plain", "ties", "two_scores", "no_positives", "no_negatives", "one_score",
+    "saturating", "signed_zeros", "nan_margin", "infinite"])
+def test_the_devices_counts_are_the_hosts(case, layout):
+    y, m = margins_of(case)
+    how = {"whole": {}, "padded": {"n_pad": 4096},
+           "mesh4": {"mesh": default_mesh(n_devices=4), "n_pad": 3008},
+           "pieces": {"piece": 512, "run": 700}}[layout]
+    ours, roc = on_the_device(y, m, **how)
+    assert_the_hosts_metrics(ours, y, m)
+    assert roc.distinct == len(ours.thresholds) and roc.device_s > 0
+    if case == "saturating":
+        # 40, 40.5, 100 and 3e38 are one score, and so are -800 and -3e38
+        assert len(ours.thresholds) < len(np.unique(m))
+    if case == "signed_zeros":
+        assert np.sum(ours.thresholds == 0.5) == 1
+
+
+def test_no_rows_at_all():
+    ours, roc = on_the_device(np.zeros(0), np.zeros(0, np.float32), n_pad=8)
+    assert ours.nobs == 0 and roc.distinct == 0 and len(ours.thresholds) == 0
+    assert ours.auc != ours.auc and ours.logloss != ours.logloss
+    assert ours.max_f1_threshold == 0.5
+
+
+def test_host_and_device_must_hold_the_same_rows():
+    y, m = margins_of("plain", 64)
+    roc = M.MarginRoc(jnp.asarray(m[:, None]), jnp.asarray(y, jnp.float32),
+                      jnp.ones(64, bool), default_mesh(n_devices=1))
+    with pytest.raises(ValueError, match="counted 64 rows"):
+        roc.metrics(M.binomial_losses(y[:60], m[:60], sigmoid), sigmoid)
+
+
+@pytest.mark.parametrize("case,params,shape", [
+    ("depth_one", {"ntrees": 1, "max_depth": 1}, {}),
+    ("na_response", {}, {"na_response": True}),
+    ("offset", {"offset_column": "off"}, {"offset": True}),
+    ("budget", {"max_runtime_secs": 600.0}, {}),
+    ("odd_rows", {}, {"n": 2401}),
+])
+def test_a_fits_training_metrics_are_the_hosts(case, params, shape, monkeypatch):
+    """Through the whole fit, on the default mesh of eight CPU devices with
+    its padded rows: the metrics the model reports are
+    ``metrics.binomial_metrics`` of the margin the fit held."""
+    from h2o3_tpu.models.tree import gbm as gbm_mod
+
+    held = {}
+
+    def keeping(*a, **kw):
+        bt = booster.train_boosted(*a, **kw)
+        held.update(bt.fit_eval)
+        return bt
+
+    monkeypatch.setattr(gbm_mod, "train_boosted", keeping)
+    frame = frame_of(**shape)
+    before = margin_sources()
+    model = GBM(**{**BASE, **params}).train(frame)
+    assert margin_sources()["fit_margin_device"] == before["fit_margin_device"] + 1
+    assert held["device"]["margin"].shape[0] % 8 == 0
+    assert held["device"]["margin"].shape[0] >= len(held["y"]) == held["margin"].shape[0]
+    assert_the_hosts_metrics(model.training_metrics, held["y"], held["margin"][:, 0])
+    if case == "depth_one":
+        assert len(model.training_metrics.thresholds) == 2
+    if case == "na_response":
+        assert len(held["y"]) < frame.nrows
+
+
+def test_what_the_device_path_asks_of_a_fit():
+    """Only what ``fit_eval`` holds selects it: a device margin that is the
+    ensemble's own (DRF's is averaged: none is left), a binomial model,
+    rows without weights."""
+    X = np.random.default_rng(0).normal(size=(64, 3)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float64)
+    p = booster.TreeParams(ntrees=2, max_depth=2, nbins=8, seed=0)
+    rows = {"frame": object(), "y": y, "w": None}
+    summed = booster.train_boosted(X, "bernoulli", y, 1, np.zeros(1), p, fit_eval=rows)
+    dev = summed.fit_eval["device"]
+    assert sorted(dev) == ["margin", "mesh", "valid", "y"]
+    assert dev["margin"].dtype == jnp.float32 and dev["margin"].shape[1] == 1
+    np.testing.assert_array_equal(
+        np.asarray(dev["margin"])[:64], summed.fit_eval["margin"].astype(np.float32))
+    averaged = booster.train_boosted(X, "fixed", y[:, None], 1, np.zeros(1), p,
+                                     average=True, fit_eval=rows)
+    assert "device" not in averaged.fit_eval
+
+
+def test_a_second_fit_builds_no_program_for_its_metrics():
+    """The window's rule: after a warm-up fit on the same frame nothing of
+    the metrics compiles (one program a padded row count and mesh, every
+    shape static, the pieces fetched by the count)."""
+    frame = frame_of(seed=12, n=2477)
+
+    def perf_spans():
+        events = [e for e in timeline.snapshot(timeline.CAPACITY) if "parent_id" in e]
+        perf = [e for e in events if e["kind"] == "model_performance"][-1]
+        return [perf] + [e for e in events if e["parent_id"] == perf["span_id"]]
+
+    first = GBM(**BASE).train(frame)
+    assert sum(e.get("compiles", 0) + e.get("cache_loads", 0) for e in perf_spans()) >= 1
+    # another count of trees, so another margin and other thresholds
+    second = GBM(**dict(BASE, ntrees=9)).train(frame)
+    spans = perf_spans()
+    assert spans[0]["roc"] == "device" and len(spans) == 3
+    assert all("compiles" not in e and "cache_loads" not in e for e in spans)
+    assert len(second.training_metrics.thresholds) != len(first.training_metrics.thresholds)
+    assert second.fit_profile["model_performance"]["fit_margin_device"] == 1
+
+
+def test_the_benchmarks_check_sees_a_reported_metric_altered(monkeypatch):
+    """``benchmark/tests/test_correct.py::test_reported_metric_altered``
+    plants its fault in ``metrics.binomial_metrics``, which a cell's fit no
+    longer calls for its training rows. The same fault where the numbers
+    are now produced (losses over every other row; counts of a margin that
+    is not the fit's): the harness's run comes out not ``correct``."""
+    import sys
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    from benchmark.lib import harness
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cell = json.load(f)["workloads"][0]
+
+    def load(kind, name):
+        with open(os.path.join(ROOT, "benchmark", kind, name + ".json")) as f:
+            return json.load(f)
+
+    def drive():
+        return harness.run(
+            cell=cell, config=load("configs", cell["config"]),
+            traffic=load("traffic", cell["traffic"]), seed=11, seconds=3.0,
+            trace=False, rehearse=True, t_start=0.0, root=ROOT, metrics=[])
+
+    losses, order = M.binomial_losses, M._margin_order
+    with monkeypatch.context() as planted:
+        planted.setattr(M, "binomial_losses", lambda y, m, link: (
+            *losses(y[::2], m[::2], link)[:2], len(y)))
+        out = drive()
+    assert out["correct"] is False
+    assert out["checks"]["logloss_gap"][0] > out["checks"]["logloss_gap"][1]
+    assert out["checks"]["auc_gap"][0] <= out["checks"]["auc_gap"][1]
+    with monkeypatch.context() as planted:
+        planted.setattr(M, "_margin_order", lambda margin, *a, **kw: order(
+            jnp.round(margin, 1), *a, **kw))
+        out = drive()
+    assert out["correct"] is False
+    assert out["checks"]["auc_gap"][0] > out["checks"]["auc_gap"][1]
+    assert out["checks"]["logloss_gap"][0] <= out["checks"]["logloss_gap"][1]
 
 
 # ---------------------------------------------------------------------------
