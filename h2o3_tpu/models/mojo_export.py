@@ -92,7 +92,10 @@ def _payload(model: Model) -> Payload:
             "edges": np.asarray(t0.edges, dtype=np.float64),
             "init_margin": np.asarray(b.init_margin, dtype=np.float64),
         }
+        from h2o3_tpu.models.tree.booster import refuse_deep
+
         for c, trees in enumerate(b.trees_per_class):
+            refuse_deep(trees, "mojo (MOJO export)")
             arrays[f"feat_{c}"] = np.stack(trees.feat).astype(np.int32)
             arrays[f"split_bin_{c}"] = np.stack(trees.split_bin).astype(np.int32)
             arrays[f"default_left_{c}"] = np.stack(trees.default_left).astype(bool)

@@ -1220,6 +1220,9 @@ def write_mojo(model, path: str) -> str:
             "use the native .mojo (models/mojo_export.py) or POJO "
             f"codegen for {algo}")
     b = model.booster
+    from h2o3_tpu.models.tree.booster import refuse_deep
+
+    refuse_deep(b.trees_per_class[0], "mojo (reference-format MOJO)")
     names = tree_feature_names(model.data_info, model.tree_encoding)
     dom = model.data_info.response_domain
     nclasses = model.nclasses

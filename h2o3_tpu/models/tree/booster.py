@@ -42,9 +42,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from h2o3_tpu.ops import histogram as _histogram
 from h2o3_tpu.ops.histogram import (
     _hist_impl,
     apply_bins,
+    build_frontier_histogram_sharded,
     build_histogram_sharded,
     make_bins,
     na_code,
@@ -53,6 +55,13 @@ from h2o3_tpu.ops.histogram import (
 from h2o3_tpu.parallel.mesh import default_mesh, row_sharding
 from h2o3_tpu.util import telemetry
 from h2o3_tpu.util.telemetry import Span
+
+TREE_FRONTIER_NODES = telemetry.counter(
+    "tree_frontier_nodes_total",
+    "live nodes of the frontier levels (levels past the dense node ladder, "
+    "each node histogrammed over its own mtries features) of the trees read "
+    "back from the device",
+)
 
 TREE_SPLITS = telemetry.counter(
     "tree_splits_total",
@@ -131,14 +140,25 @@ class Trees:
     ``split_bin`` of a set-valued split is the length of the chosen prefix of
     the node's level order, less one, and says nothing without that order:
     read the set. A numeric ensemble holds the five arrays and no sets.
+
+    A tree deep enough to have frontier levels (``frontier_start``) is kept
+    as a list of its nodes instead (``deep``): per tree, the nodes that exist
+    in heap order — a split node's children side by side — with the five
+    fields, ``node`` [L] int32 their heap ids and ``child`` [L] int32 the
+    position of a split node's left child (its right child is the next one),
+    -1 for a leaf. L is the tree's own size, not 2^(max_depth+1)-1, so the
+    trees of an ensemble differ in length and are not stacked.
     """
 
     #: an ensemble saved before there were sets has neither field
     cat_levels: Tuple[int, ...] = ()
     split_set: Optional[List[np.ndarray]] = None
+    #: an ensemble saved before there were frontier levels has none
+    node: Optional[List[np.ndarray]] = None
+    child: Optional[List[np.ndarray]] = None
 
     def __init__(self, max_depth: int, n_bins1: int, edges: np.ndarray,
-                 cat_levels: Tuple[int, ...] = ()):
+                 cat_levels: Tuple[int, ...] = (), deep: bool = False):
         self.max_depth = max_depth
         self.n_bins1 = n_bins1
         self.edges = edges  # [F, B-1] for re-binning at predict time
@@ -150,9 +170,30 @@ class Trees:
         self.leaf: List[np.ndarray] = []
         if self.cat_levels:
             self.split_set: List[np.ndarray] = []
+        if deep:
+            self.node: List[np.ndarray] = []
+            self.child: List[np.ndarray] = []
+
+    @property
+    def deep(self) -> bool:
+        return self.child is not None
+
+    def _fields(self) -> Tuple[str, ...]:
+        return ("feat", "split_bin", "default_left", "is_split", "leaf") + (
+            ("split_set",) if self.cat_levels else ()) + (
+            ("node", "child") if self.deep else ())
 
     def append(self, feat, split_bin, default_left, is_split, leaf,
-               split_set=None) -> None:
+               split_set=None, node=None) -> None:
+        """One tree as the block returned it: heap arrays [M], or for a
+        deep tree the heap arrays of its dense levels followed by the slots
+        of its frontier levels and ``node``, the slots' heap ids."""
+        if self.deep:
+            fields = _node_list(*(np.asarray(a) for a in (
+                feat, split_bin, default_left, is_split, leaf, node)))
+            for name, a in zip(self._fields(), fields):
+                getattr(self, name).append(a)
+            return
         self.feat.append(np.asarray(feat))
         self.split_bin.append(np.asarray(split_bin))
         self.default_left.append(np.asarray(default_left))
@@ -165,8 +206,9 @@ class Trees:
         """Take over another ensemble's trees (checkpoint-continue)."""
         if other.cat_levels != self.cat_levels:
             raise ValueError("checkpoint categorical levels mismatch")
-        for name in ("feat", "split_bin", "default_left", "is_split", "leaf") + (
-                ("split_set",) if self.cat_levels else ()):
+        if other.deep != self.deep:
+            raise ValueError("checkpoint tree layout mismatch (frontier levels)")
+        for name in self._fields():
             getattr(self, name).extend(getattr(other, name))
 
     @property
@@ -196,12 +238,56 @@ def no_sets(what: str) -> NotImplementedError:
 
 
 def refuse_sets(trees: "Trees", what: str) -> None:
-    """``what`` reads ``split_bin`` as a threshold and bins by the edges
-    alone: it can carry neither a split on a set of a categorical's levels
-    nor the codes of a fit that binned a level a bin, and says so instead
-    of scoring it wrong."""
+    """``what`` reads ``split_bin`` as a threshold of a heap of nodes and
+    bins by the edges alone: it can carry neither a split on a set of a
+    categorical's levels, nor the codes of a fit that binned a level a bin,
+    nor a deep tree's list of nodes, and says so instead of scoring it
+    wrong."""
     if getattr(trees, "cat_levels", ()):
         raise no_sets(what)
+    refuse_deep(trees, what)
+
+
+def refuse_deep(trees: "Trees", what: str) -> None:
+    """``what`` reads a tree as a heap of 2^(max_depth+1)-1 nodes: a deep
+    tree (``Trees.deep``) is a list of its nodes, and is refused by name."""
+    if getattr(trees, "child", None) is not None:
+        raise NotImplementedError(
+            f"{what} does not support trees with frontier levels (max_depth "
+            f"{trees.max_depth}: levels past the dense node ladder are kept "
+            "as a list of nodes, not a heap); train with max_depth <= 10")
+
+
+def _node_list(feat, split_bin, default_left, is_split, leaf, node):
+    """A deep tree as the block returned it — heap arrays of its dense
+    levels, then the slots of its frontier levels with their heap ids in
+    ``node`` (-1: an empty slot) — as the nodes that exist, in heap order:
+    the five fields, the heap ids and every split node's left child's
+    position. A node of the dense levels exists when its parent split."""
+    m_dense = len(feat) - len(node)
+    reach = np.zeros(m_dense, bool)
+    reach[0] = True
+    for d in range(1, int(np.log2(m_dense + 1))):
+        idx = np.arange(2**d - 1, 2 ** (d + 1) - 1)
+        up = (idx - 1) // 2
+        reach[idx] = reach[up] & is_split[up]
+    live = node >= 0
+
+    def keep(a):
+        return np.concatenate([a[:m_dense][reach], a[m_dense:][live]])
+
+    # levels follow one another and a level's slots follow their parents'
+    # order, so the ids come in heap order
+    ids = np.concatenate([np.flatnonzero(reach), node[live]]).astype(np.int32)
+    if np.any(np.diff(ids) <= 0):
+        raise RuntimeError("the nodes of a deep tree are not in heap order")
+    fields = [keep(a) for a in (feat, split_bin, default_left, is_split, leaf)]
+    sp = fields[3].astype(bool)
+    child = np.where(sp, np.searchsorted(ids, 2 * ids + 1), -1).astype(np.int32)
+    if sp.any() and not (np.array_equal(ids[child[sp]], 2 * ids[sp] + 1)
+                         and np.array_equal(ids[child[sp] + 1], 2 * ids[sp] + 2)):
+        raise RuntimeError("a split node of a deep tree lacks a child")
+    return (*fields, ids, child)
 
 
 # ---------------------------------------------------------------------------
@@ -338,6 +424,7 @@ def _split_search(
     hist, lam, alpha, gamma, lr, feat_mask, min_rows: float, n_bins1: int,
     constraints=None, node_lo=None, node_hi=None, child_stats: bool = False,
     cat_levels: Tuple[int, ...] = (), min_child_weight: Optional[float] = None,
+    deep: bool = False, stable_gain: bool = False,
 ):
     """Per-node best split over (feature, bin, NA-direction).
 
@@ -374,6 +461,14 @@ def _split_search(
     A child must hold ``min_rows`` rows (the count channel), or, where
     ``min_child_weight`` is given, a Σh of at least that much in its place
     (xgboost's floor on the hessian).
+
+    ``deep`` (the levels of a deep tree, up to 2^19 nodes): the winner's
+    entries are taken by masked sums, not by gathers along the node axis
+    (a TPU gather of [2^19, 1, B, 3] took 348 ms a level). ``stable_gain``
+    (only where lambda = alpha = 0) computes a candidate's gain as
+    ``HL HR (GL/HL - GR/HR)^2 / H``, the three-term form without its
+    cancellation: float32 squares of sums over more than 4,096 rows round,
+    and a pure node's candidates would read a gain of +-1e-4 instead of 0.
     """
     B = n_bins1 - 1
     total = hist.sum(axis=2)  # [K, F, 3] — identical across F
@@ -411,7 +506,11 @@ def _split_search(
         gr = G[:, None, None] - gl
         hr = H[:, None, None] - hl
         cr = CNT[:, None, None] - cl
-        gain = 0.5 * (side_score(gl, hl) + side_score(gr, hr) - parent[:, None, None]) - gamma
+        if stable_gain:
+            diff = gl / jnp.maximum(hl, 1e-12) - gr / jnp.maximum(hr, 1e-12)
+            gain = 0.5 * (hl * hr / jnp.maximum(H[:, None, None], 1e-12)) * diff * diff - gamma
+        else:
+            gain = 0.5 * (side_score(gl, hl) + side_score(gr, hr) - parent[:, None, None]) - gamma
         if min_child_weight is None:
             ok = (cl >= min_rows) & (cr >= min_rows)
         else:
@@ -420,7 +519,9 @@ def _split_search(
         if constraints is not None:
             wl = opt_w(gl, hl)
             wr = opt_w(gr, hr)
-            c = constraints[None, :, None].astype(gl.dtype)
+            # [F] a feature, or [K, F] of a frontier level's own features
+            c = (constraints[None, :, None] if constraints.ndim == 1
+                 else constraints[:, :, None]).astype(gl.dtype)
             gain = jnp.where((c != 0) & (c * (wr - wl) < 0), -jnp.inf, gain)
         return gain
 
@@ -440,12 +541,19 @@ def _split_search(
 
     flat = gain_fb.reshape(gain_fb.shape[0], -1)
     best = jnp.argmax(flat, axis=1)
-    best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
+    if deep:
+        best_gain = jnp.max(flat, axis=1)
+    else:
+        best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
     best_f = (best // B).astype(jnp.int32)
     best_b = (best % B).astype(jnp.int32)
-    dl = jnp.take_along_axis(
-        go_left_better.reshape(go_left_better.shape[0], -1), best[:, None], axis=1
-    )[:, 0]
+    if deep:
+        at_best = jnp.arange(flat.shape[1], dtype=best.dtype)[None, :] == best[:, None]
+        dl = jnp.any(at_best & go_left_better.reshape(at_best.shape), axis=1)
+    else:
+        dl = jnp.take_along_axis(
+            go_left_better.reshape(go_left_better.shape[0], -1), best[:, None], axis=1
+        )[:, 0]
 
     sets = ()
     if cat_idx:
@@ -461,14 +569,22 @@ def _split_search(
         # gather the winning candidate's (Σg, Σh, Σw) left-side stats from
         # cum/na — K-sized gathers, not full [K, F, B] re-materialization
         K = hist.shape[0]
-        idx_f = jnp.broadcast_to(best_f[:, None, None, None], (K, 1, B, 3))
-        cum_f = jnp.take_along_axis(cum, idx_f, axis=1)[:, 0]  # [K, B, 3]
-        stats_l = jnp.take_along_axis(
-            cum_f, jnp.broadcast_to(best_b[:, None, None], (K, 1, 3)), axis=1
-        )[:, 0]  # [K, 3]
-        na_f = jnp.take_along_axis(
-            na, jnp.broadcast_to(best_f[:, None, None], (K, 1, 3)), axis=1
-        )[:, 0]  # [K, 3]
+        if deep:
+            of_f = (jnp.arange(cum.shape[1], dtype=best_f.dtype)[None, :]
+                    == best_f[:, None])[:, :, None]  # [K, F, 1]
+            of_b = (jnp.arange(B, dtype=best_b.dtype)[None, :]
+                    == best_b[:, None])[:, None, :, None]  # [K, 1, B, 1]
+            stats_l = jnp.sum(jnp.where(of_f[..., None] & of_b, cum, 0.0), axis=(1, 2))
+            na_f = jnp.sum(jnp.where(of_f, na, 0.0), axis=1)
+        else:
+            idx_f = jnp.broadcast_to(best_f[:, None, None, None], (K, 1, B, 3))
+            cum_f = jnp.take_along_axis(cum, idx_f, axis=1)[:, 0]  # [K, B, 3]
+            stats_l = jnp.take_along_axis(
+                cum_f, jnp.broadcast_to(best_b[:, None, None], (K, 1, 3)), axis=1
+            )[:, 0]  # [K, 3]
+            na_f = jnp.take_along_axis(
+                na, jnp.broadcast_to(best_f[:, None, None], (K, 1, 3)), axis=1
+            )[:, 0]  # [K, 3]
         stats_l = stats_l + dl[:, None].astype(stats_l.dtype) * na_f
         gl_b, hl_b, cl_b = stats_l[:, 0], stats_l[:, 1], stats_l[:, 2]
         best_wl = opt_w(gl_b, hl_b)
@@ -526,6 +642,12 @@ def _tree_walk(bins, feat, split_bin, default_left, is_split, leaf, max_depth: i
 @partial(jax.jit, static_argnames=("max_depth",))
 def _predict_stacked(bins, feat, split_bin, default_left, is_split, leaf, max_depth: int, n_bins1_arr):
     """Sum of all trees' outputs for each row. Tree arrays: [T, M]."""
+    if frontier_start(max_depth, subtract=True) is not None:
+        # no fit keeps such a tree as a heap (``Trees.deep``), and the walk
+        # would reduce over [N, 2^(max_depth+1)] at every step
+        raise ValueError(
+            f"a tree of depth {max_depth} has frontier levels and is walked "
+            "by its list of nodes (_predict_deep), not as a heap")
 
     def one_tree(carry, tree):
         tf, tb, tdl, tsp, tlf = tree
@@ -636,6 +758,60 @@ def _predict_chunk_sets(acc, bins, feat, default_left, is_split, leaf,
     return out
 
 
+# -- deep trees: a walk over each tree's list of nodes -----------------------
+
+
+@partial(jax.jit, static_argnames=("max_depth", "n_bins1"), donate_argnums=(0,))
+def _predict_chunk_deep(acc, bins, table, leaf, max_depth: int, n_bins1: int):
+    """``acc`` plus the outputs of a chunk of deep trees: ``table`` [T, L, 4]
+    int32 of (feature, split bin, default_left | is_split << 1, left child's
+    position) a node, ``leaf`` [T, L]. A row's node is looked up by a gather
+    a level, so a tree costs rows x depth whatever its size."""
+
+    def one_tree(carry, tree):
+        tab, lf = tree
+
+        def step(_, idx):
+            r = tab[idx]
+            b = _sel_cols(bins, r[:, 0])
+            go_left = jnp.where(b >= n_bins1 - 1, (r[:, 2] & 1) > 0, b <= r[:, 1])
+            return jnp.where((r[:, 2] & 2) > 0, r[:, 3] + jnp.where(go_left, 0, 1), idx)
+
+        idx = jax.lax.fori_loop(
+            0, max_depth, step, jnp.zeros(bins.shape[0], jnp.int32))
+        return carry + lf[idx], None
+
+    with jax.named_scope("score_traverse"):
+        out, _ = jax.lax.scan(one_tree, acc, (table, leaf))
+    return out
+
+
+def _predict_deep(bins, trees: "Trees"):
+    """Sum of the outputs of deep trees, and the number of chunks it took:
+    a tree block's worth of trees a call, each tree's nodes padded to the
+    next power of two of the chunk's largest (the chunk's last trees, where
+    short, are one leaf of 0)."""
+    chunk = tree_block_size()
+    acc = jnp.zeros(bins.shape[0], jnp.float32)
+    starts = range(0, trees.ntrees, chunk)
+    for t in starts:
+        ids = range(t, min(t + chunk, trees.ntrees))
+        size = 1 << max(max(len(trees.feat[i]) for i in ids) - 1, 1).bit_length()
+        table = np.zeros((chunk, size, 4), np.int32)
+        leaf = np.zeros((chunk, size), np.float32)
+        for j, i in enumerate(ids):
+            L = len(trees.feat[i])
+            table[j, :L, 0] = trees.feat[i]
+            table[j, :L, 1] = trees.split_bin[i]
+            table[j, :L, 2] = (trees.default_left[i].astype(np.int32)
+                               | (trees.is_split[i].astype(np.int32) << 1))
+            table[j, :L, 3] = trees.child[i]
+            leaf[j, :L] = trees.leaf[i]
+        acc = _predict_chunk_deep(acc, bins, jnp.asarray(table), jnp.asarray(leaf),
+                                  max_depth=trees.max_depth, n_bins1=trees.n_bins1)
+    return acc, len(starts)
+
+
 # ---------------------------------------------------------------------------
 # the device-resident training block
 
@@ -665,13 +841,72 @@ def _built_nodes(d: int, subtract: bool) -> int:
     return 2 ** (d - 1) if subtract and d > 0 else 2**d
 
 
-def level_plan(p: TreeParams, subtract: bool, impl: Optional[str] = None):
+def frontier_start(max_depth: int, subtract: bool) -> Optional[int]:
+    """The first frontier level of a tree of ``max_depth``: the first level
+    whose histogram would build more nodes than the top of the node ladder
+    (``histogram.MAX_DENSE_NODES``), 10 without subtraction and 11 with it;
+    None for a tree that has none. The tree's shape alone decides it."""
+    for d in range(max_depth):
+        if _built_nodes(d, subtract) > _histogram.MAX_DENSE_NODES:
+            return d
+    return None
+
+
+def frontier_slots(p: TreeParams, rows: Optional[int], weighted: bool = False) -> int:
+    """The slots of every frontier level over ``rows`` (padded) rows: one a
+    node that can exist on the deepest, level ``max_depth - 1``. A node
+    exists where its parent split, and a split leaves each child
+    ``min_rows`` sampled rows: unweighted, at least ceil(min_rows) rows
+    each, so a level holds at most 2 * (rows // (2 * ceil(min_rows)))
+    nodes; under weights or xgboost's hessian floor, one row each. ``rows``
+    None, or no floor to count by, gives 2^(max_depth - 1)."""
+    K = 2 ** (p.max_depth - 1)
+    if rows is None:
+        return K
+    if p.min_child_weight is None and not weighted and p.min_rows >= 1:
+        per = 2 * int(np.ceil(p.min_rows))
+        return max(2, min(K, 2 * (int(rows) // per)))
+    floor = p.min_rows if p.min_child_weight is None else p.min_child_weight
+    return max(2, min(K, int(rows))) if floor > 0 else K
+
+
+def _exact_gain(p: TreeParams) -> bool:
+    """Where a split's gain has the cancellation-free form (no lambda, no
+    alpha: ``_split_search``'s ``stable_gain``)."""
+    return p.reg_lambda == 0 and p.reg_alpha == 0
+
+
+def _mtries(p: TreeParams, F: int) -> int:
+    """Candidate features of a node: ``mtries``, or all F where it is <= 0."""
+    return min(p.mtries, F) if p.mtries > 0 else F
+
+
+def _node_candidates(key, node_ids, F: int, m: int):
+    """[K, m] int32: the mtries features of the nodes of heap ids
+    ``node_ids``, the m of lowest uniform(fold_in(the tree's key, heap id),
+    (F,)), ties to the lower feature, listed in feature order (so a tie of
+    gains goes to the lower feature, as over all F). A node's draw does not
+    depend on how a level numbers its slots, and the order is a two-key
+    sort, the same on every backend (``lax.top_k`` may break a tie either
+    way)."""
+    r = jax.vmap(lambda i: jax.random.uniform(jax.random.fold_in(key, i), (F,)))(
+        node_ids.astype(jnp.uint32))
+    feats = jnp.broadcast_to(jnp.arange(F, dtype=jnp.int32), r.shape)
+    return jnp.sort(jax.lax.sort((r, feats), dimension=1, num_keys=2)[1][:, :m], axis=1)
+
+
+def level_plan(p: TreeParams, subtract: bool, impl: Optional[str] = None,
+               rows: Optional[int] = None, weighted: bool = False):
     """What every level of a tree of ``p`` launches, one ``(nodes built,
     node slots launched, kernel)`` a level in the order the block runs them:
     the histogram of levels 0 to ``max_depth - 1`` and, without subtraction,
     the per-node totals of the leaves (``totals``). The padding a level pays
-    is ``slots / built``. Pure Python over the functions the trace itself
-    asks (``pad_nodes``, ``_hist_impl``, ``_kernel_choice``); ``impl`` as
+    is ``slots / built``. A frontier level (``frontier_start``) says
+    ``(2^d, slots, "frontier")``, every one of them the slots of the deepest
+    (``_frontier_levels``), and a tree with one takes its leaves from the
+    last split (no ``totals``). Pure Python over the
+    functions the trace itself asks (``pad_nodes``, ``_hist_impl``,
+    ``_kernel_choice``, ``frontier_slots``); ``impl`` as
     ``build_histogram_sharded`` takes it."""
     impl = _hist_impl(impl)
     if impl == "pallas":
@@ -683,9 +918,13 @@ def level_plan(p: TreeParams, subtract: bool, impl: Optional[str] = None):
         def kernel(slots):
             return impl
 
-    built = [_built_nodes(d, subtract) for d in range(p.max_depth)]
+    d_f = frontier_start(p.max_depth, subtract)
+    dense = p.max_depth if d_f is None else d_f
+    built = [_built_nodes(d, subtract) for d in range(dense)]
     plan = [(k, pad_nodes(k), kernel(pad_nodes(k))) for k in built]
-    if not (subtract and p.max_depth > 0):
+    plan += [(2**d, frontier_slots(p, rows, weighted), "frontier")
+             for d in range(dense, p.max_depth)]
+    if d_f is None and not (subtract and p.max_depth > 0):
         leaves = 2**p.max_depth
         plan.append((leaves, pad_nodes(leaves), "totals"))
     return tuple(plan)
@@ -714,7 +953,14 @@ def _build_one_tree(
     rows are routed by membership in it; with none the program is the one
     it was.
 
-    Returns (heap arrays [M], per-row leaf value [N]).
+    A tree deep enough to have frontier levels (``frontier_start``) grows
+    its dense levels as above and the rest by ``_frontier_levels``; every
+    row then carries its node's leaf value down the levels, so no lookup
+    over the heap is made at the end.
+
+    Returns (heap arrays [M], per-row leaf value [N]); for a deep tree the
+    arrays are the dense levels' heap followed by the frontier levels'
+    slots, and a sixth array holds the slots' heap ids.
     """
     D = p.max_depth
     n_bins1 = p.n_bins1
@@ -726,12 +972,18 @@ def _build_one_tree(
     if mono:
         b_lo = jnp.full((1,), -jnp.inf, jnp.float32)
         b_hi = jnp.full((1,), jnp.inf, jnp.float32)
+    d_f = frontier_start(D, subtract)
+    deep = d_f is not None
+    if deep:
+        val = jnp.zeros(bins.shape[0], jnp.float32)  # each row's node's leaf
 
     tf_l, tb_l, tdl_l, tsp_l, tlf_l, tset_l = [], [], [], [], [], []
     prev_hist = prev_can = prev_left_small = prev_wl = prev_wr = None
     # every level and phase carries a named scope (metadata only, no
     # instruction): the profiler's device operations are summed by them
-    for d in range(D + 1):
+    for d in range(D if deep else D + 1):
+        if deep and d == d_f:
+            break
         K = 2**d
         lo = K - 1
         lvl = f"L{d:02d}"
@@ -809,10 +1061,13 @@ def _build_one_tree(
                 )
         with jax.named_scope(f"{lvl}/split"):
             if p.mtries > 0:
-                key, sub = jax.random.split(key)
-                r = jax.random.uniform(sub, (K, F))
-                thresh = jnp.sort(r, axis=1)[:, p.mtries - 1][:, None]
-                node_feat_mask = (r <= thresh) & feat_mask[None, :]
+                # the node's mtries features, drawn by its heap id
+                pick = _node_candidates(
+                    key, lo + jnp.arange(K, dtype=jnp.int32), F, _mtries(p, F))
+                chosen = jnp.any(
+                    pick[:, :, None] == jnp.arange(F, dtype=pick.dtype)[None, None, :],
+                    axis=1)
+                node_feat_mask = chosen & feat_mask[None, :]
             else:
                 node_feat_mask = feat_mask
             out = _split_search(
@@ -830,6 +1085,8 @@ def _build_one_tree(
                 child_stats=subtract,
                 cat_levels=p.cat_levels,
                 min_child_weight=p.min_child_weight,
+                deep=deep,
+                stable_gain=deep and _exact_gain(p),
             )
             if sets:
                 with jax.named_scope("sets"):
@@ -855,6 +1112,21 @@ def _build_one_tree(
                 with jax.named_scope("sets"):
                     go_left, cank = _set_route(
                         bins, k, bf, dl, can, node_set, n_bins1)
+            elif deep:
+                # a deep tree's rows carry their node's leaf, and at the
+                # last dense level the rank of their node among those that
+                # split (its children's slots on the first frontier level)
+                last = d == d_f - 1
+                rank = jnp.cumsum(can.astype(jnp.int32)) - 1
+                f, sb, dlk, cank, lfk, *rk = _sel_tables(
+                    (bf, bb, dl, can, leaf) + ((rank,) if last else ()), k)
+                val = jnp.where(in_lvl, lfk, val)
+                b = _sel_cols(bins, f)
+                go_left = jnp.where(b >= n_bins1 - 1, dlk, b <= sb)
+                if last:
+                    slot = jnp.where(
+                        in_lvl & cank, 2 * rk[0] + jnp.where(go_left, 0, 1),
+                        frontier_slots(p, bins.shape[0], rw is not None))
             else:
                 f, sb, dlk, cank = _sel_tables((bf, bb, dl, can), k)
                 b = _sel_cols(bins, f)
@@ -872,6 +1144,14 @@ def _build_one_tree(
                 b_lo = jnp.stack([lo_left, lo_right], axis=1).reshape(2 * K)
                 b_hi = jnp.stack([hi_left, hi_right], axis=1).reshape(2 * K)
 
+    if deep:
+        dense = tuple(jnp.concatenate(a) for a in (tf_l, tb_l, tdl_l, tsp_l, tlf_l))
+        fr, val = _frontier_levels(
+            bins, g, h, sample, feat_mask, key, p, mesh, rw, slot, val, can,
+            (constraints, b_lo, b_hi) if mono else None, d_f)
+        tree = tuple(jnp.concatenate([a, b]) for a, b in zip(dense, fr[:5])) + (fr[5],)
+        return tree, val
+
     with jax.named_scope("leaf"):
         # per-level concatenation IS the heap layout: node (d, i) -> 2^d - 1 + i
         tree = (
@@ -883,6 +1163,155 @@ def _build_one_tree(
         ) + ((jnp.concatenate(tset_l),) if sets else ())
         pred = _sel_table(tree[4], pos)
     return tree, pred
+
+
+def _children(can, parent_node, slots: int, pairs=()):
+    """The next level's ``slots`` slots from the nodes of this one that
+    split (``can``, heap ids ``parent_node``): the parent of rank r among
+    them holds slots 2r (left child) and 2r + 1 (right child). ``pairs``:
+    (left, right) [K] float32 arrays of what each child takes from its
+    parent. Returns the slots' heap ids (-1: empty) and, a pair, the
+    children's values [slots]. One stable sort puts the parents that split
+    first, in order, and one row gather takes their fields: a binary search
+    of the slots in the ranks took 59 ms a level at 2^19 slots on a v5e."""
+    K = can.shape[0]
+    i32 = jnp.int32
+    order = jax.lax.sort(((~can).astype(i32), jnp.arange(K, dtype=i32)),
+                         num_keys=1, is_stable=True)[1]
+    cols = [parent_node] + [jax.lax.bitcast_convert_type(v, i32) for pr in pairs for v in pr]
+    took = jnp.stack(cols, axis=1)[order]  # [K, 1 + 2 pairs]
+
+    def interleave(left, right):
+        v = jnp.stack([left, right], axis=1).reshape(2 * K)
+        if 2 * K >= slots:
+            return v[:slots]
+        return jnp.concatenate([v, jnp.zeros(slots - 2 * K, v.dtype)])
+
+    node = interleave(2 * took[:, 0] + 1, 2 * took[:, 0] + 2)
+    node = jnp.where(jnp.arange(slots, dtype=i32) // 2 < jnp.sum(can.astype(i32)), node, -1)
+    vals = [jax.lax.bitcast_convert_type(
+        interleave(took[:, 1 + 2 * i], took[:, 2 + 2 * i]), jnp.float32)
+        for i in range(len(pairs))]
+    return node, vals
+
+
+def _frontier_levels(bins, g, h, sample, feat_mask, key, p: TreeParams, mesh,
+                     rw, slot, val, can_dense, mono, d_f: int):
+    """Levels ``d_f`` .. ``max_depth`` of a deep tree: the frontier levels
+    and the leaves below them. A level's nodes sit in slots, each carrying
+    its heap id; a row carries the slot of its node (``slot``; the slot
+    count: none) and its node's leaf value (``val``), and finds its node's
+    fields by a gather: what a level costs grows with the rows, not with its
+    nodes. Each node is histogrammed over its own ``mtries`` features alone
+    (all F where mtries <= 0), ``[slots, mtries, B+1, 3]``, with no
+    subtraction (a child's features are not its parent's); the leaves come
+    from the last split's child stats.
+
+    Every frontier level has the slots of the deepest (``frontier_slots``),
+    so the levels are one ``lax.scan`` over one
+    compiled level: unrolled, a depth-20 block took 145 s to compile on a
+    v5e, 9 s a frontier level. Their operations carry the first frontier
+    level's scopes (``L<d_f>/hist_nodes`` ... ``/route``).
+
+    ``slot`` on entry is the first frontier level's (the last dense level's
+    routing made it), ``can_dense`` that level's splits, ``mono`` (monotone
+    directions [F], and the bounds of the dense children by their index) or
+    None. Returns the slots' (feat, split_bin, default_left, is_split, leaf,
+    heap id), each the levels' slots one after the other, and ``val``."""
+    D = p.max_depth
+    n_bins1 = p.n_bins1
+    N, F = bins.shape
+    i32 = jnp.int32
+    m = _mtries(p, F)
+    msi = max(p.min_split_improvement, 0.0)
+    lr = jnp.float32(p.learn_rate)
+    Kp = can_dense.shape[0]
+    S = frontier_slots(p, N, rw is not None)
+    lvl = f"L{d_f:02d}"
+    with jax.named_scope(f"L{d_f - 1:02d}/route"):
+        dense_ids = Kp - 1 + jnp.arange(Kp, dtype=i32)
+        if mono:
+            # the dense children's bounds, by the dense child index 2k + side
+            constraints, b_lo, b_hi = mono
+            node, bounds = _children(can_dense, dense_ids, S, (
+                (b_lo[0::2], b_lo[1::2]), (b_hi[0::2], b_hi[1::2])))
+            bounds = tuple(bounds)
+        else:
+            node, _ = _children(can_dense, dense_ids, S)
+            bounds = (jnp.zeros(S, jnp.float32),) * 2  # unread
+
+    def level(carry, _):
+        slot, val, node, _w, (lo_s, hi_s) = carry
+        at = slot < S
+        with jax.named_scope(f"{lvl}/hist_nodes"):
+            if p.mtries > 0:
+                fidx = _node_candidates(key, node, F, m)
+            else:
+                fidx = jnp.broadcast_to(jnp.arange(F, dtype=i32), (S, F))
+            cand = feat_mask[fidx] & (node >= 0)[:, None]
+            # each row's codes of its node's features, [N, m]
+            row_f = jnp.concatenate([fidx, jnp.zeros((1, m), i32)])[jnp.minimum(slot, S)]
+            codes = jnp.stack([_sel_cols(bins, row_f[:, j]) for j in range(m)], axis=1)
+            hslot = jnp.where(at & sample, slot, S).astype(i32)
+        with jax.named_scope(f"{lvl}/hist"):
+            hist = build_frontier_histogram_sharded(
+                codes, hslot, g, h, n_slots=S, n_bins1=n_bins1, mesh=mesh, rw=rw)
+        with jax.named_scope(f"{lvl}/split"):
+            bj, bb, dl, gain, leaf, bwl, bwr, _ = _split_search(
+                hist,
+                jnp.float32(p.reg_lambda),
+                jnp.float32(p.reg_alpha),
+                jnp.float32(p.gamma),
+                lr,
+                cand,
+                min_rows=float(p.min_rows),
+                n_bins1=n_bins1,
+                constraints=constraints[fidx] if mono else None,
+                node_lo=lo_s if mono else None,
+                node_hi=hi_s if mono else None,
+                child_stats=True,
+                min_child_weight=p.min_child_weight,
+                deep=True,
+                stable_gain=_exact_gain(p),
+            )
+            bf = jnp.sum(jnp.where(jnp.arange(m, dtype=i32)[None, :] == bj[:, None],
+                                   fidx, 0), axis=1)
+            can = (gain > msi) & jnp.isfinite(gain) & (node >= 0)
+        with jax.named_scope(f"{lvl}/route"):
+            rank = jnp.cumsum(can.astype(i32)) - 1
+            tab = jnp.stack([bf, bb, dl.astype(i32), can.astype(i32), rank,
+                             jax.lax.bitcast_convert_type(leaf, i32)], axis=1)
+            r = jnp.concatenate([tab, jnp.zeros((1, 6), i32)])[jnp.minimum(slot, S)]
+            b = _sel_cols(bins, r[:, 0])
+            go_left = jnp.where(b >= n_bins1 - 1, r[:, 2] > 0, b <= r[:, 1])
+            val = jnp.where(at, jax.lax.bitcast_convert_type(r[:, 5], jnp.float32), val)
+            slot = jnp.where(at & (r[:, 3] > 0), 2 * r[:, 4] + jnp.where(go_left, 0, 1),
+                             S).astype(i32)
+            if mono:
+                c_best = constraints[bf].astype(jnp.float32)
+                mid = jnp.clip(0.5 * (bwl + bwr), lo_s, hi_s)
+                lo_l = jnp.where(c_best < 0, jnp.maximum(lo_s, mid), lo_s)
+                hi_l = jnp.where(c_best > 0, jnp.minimum(hi_s, mid), hi_s)
+                lo_r = jnp.where(c_best > 0, jnp.maximum(lo_s, mid), lo_s)
+                hi_r = jnp.where(c_best < 0, jnp.minimum(hi_s, mid), hi_s)
+                node_next, (w_next, lo_s, hi_s) = _children(
+                    can, node, S, ((bwl, bwr), (lo_l, lo_r), (hi_l, hi_r)))
+            else:
+                node_next, (w_next,) = _children(can, node, S, ((bwl, bwr),))
+        return (slot, val, node_next, w_next, (lo_s, hi_s)), (bf, bb, dl, can, leaf, node)
+
+    carry = (slot.astype(i32), val, node, jnp.zeros(S, jnp.float32), bounds)
+    (slot, val, node, w_next, (lo_s, hi_s)), levels = jax.lax.scan(
+        level, carry, None, length=D - d_f)
+    with jax.named_scope("leaf"):
+        raw = jnp.clip(w_next, lo_s, hi_s) if mono else w_next
+        leaf = jnp.where(node >= 0, lr * raw, 0.0)
+        val = jnp.where(slot < S, jnp.concatenate([leaf, jnp.zeros((1,), jnp.float32)])[
+            jnp.minimum(slot, S)], val)
+        zero = jnp.zeros(S, i32)
+        last = (zero, zero, zero.astype(bool), zero.astype(bool), leaf, node)
+        fr = tuple(jnp.concatenate([a.reshape(-1), b]) for a, b in zip(levels, last))
+    return fr, val
 
 
 @lru_cache(maxsize=64)
@@ -1017,6 +1446,9 @@ class BoostedTrees:
                 if trees.cat_levels:
                     s, chunks = _predict_sets(bins, trees)
                     span.set(sets=True, chunks=chunks)
+                elif trees.deep:
+                    s, chunks = _predict_deep(bins, trees)
+                    span.set(deep=True, chunks=chunks)
                 else:
                     s = _predict_stacked(
                         bins, *trees.stacked(), max_depth=trees.max_depth,
@@ -1153,6 +1585,14 @@ def _train_boosted(
 
     n_bins1 = p.n_bins1
     n_cat = sum(1 for v in p.cat_levels if v)
+    subtract_on = _tree_subtract_enabled()
+    deep = frontier_start(p.max_depth, subtract_on) is not None
+    if deep and n_cat:
+        raise NotImplementedError(
+            f"set-valued splits on categorical columns (categorical_encoding="
+            f"'enum') are not carried to the frontier levels of a tree of "
+            f"max_depth {p.max_depth} (levels past the dense node ladder); "
+            "train with max_depth <= 10 or categorical_encoding='label_encoder'")
 
     def sharded(nbytes=None, **more) -> dict:
         """What a span of something placed on, summed over or fetched from
@@ -1280,7 +1720,7 @@ def _train_boosted(
     if monotone is not None and np.any(np.asarray(monotone) != 0):
         mono_d = jnp.asarray(np.asarray(monotone, dtype=np.int32))
 
-    trees_per_class = [Trees(p.max_depth, n_bins1, edges, p.cat_levels)
+    trees_per_class = [Trees(p.max_depth, n_bins1, edges, p.cat_levels, deep=deep)
                        for _ in range(C)]
     tree_offset = 0
     if resume_from is not None:
@@ -1298,15 +1738,16 @@ def _train_boosted(
     built = 0
     final_host = None  # the last budget check's copy of the margin
     default_block = tree_block_size()
-    subtract_on = _tree_subtract_enabled()
     # what the block's levels launch, (built, slots, kernel) a level: the
     # span states it, so padding reads as slots / built without a trace
-    hist_slots = level_plan(p_key, subtract_on)
+    hist_slots = level_plan(p_key, subtract_on, rows=n_pad,
+                            weighted=weights is not None)
     # what one tree's levels hand to their psums, on each device: float32
-    # [slots, F, B+1, 3] a histogram level, [slots, 3] the leaf totals
+    # [slots, F, B+1, 3] a histogram level ([slots, mtries, B+1, 3] a
+    # frontier level), [slots, 3] the leaf totals
+    width = {"totals": 1, "frontier": _mtries(p, F) * n_bins1}
     psum_tree = (nshards > 1) * C * 12 * sum(
-        slots * (1 if kernel == "totals" else F * n_bins1)
-        for _, slots, kernel in hist_slots)
+        slots * width.get(kernel, F * n_bins1) for _, slots, kernel in hist_slots)
     while built < p.ntrees:
         block = (
             min(score_interval, p.ntrees - built)
@@ -1320,9 +1761,13 @@ def _train_boosted(
         )
         # one key per ABSOLUTE tree index: blocking and checkpoints never
         # change the random stream a given tree sees
-        keys = jax.vmap(lambda t: jax.random.fold_in(key, t))(
-            jnp.arange(tree_offset + built, tree_offset + built + block)
-        )
+        # replicated over the mesh, as every other argument is placed on it:
+        # the block is then the program its shapes alone lower to
+        # (``block_fn.lower``), one entry of the persistent compile cache
+        keys = jax.device_put(
+            jax.vmap(lambda t: jax.random.fold_in(key, t))(
+                jnp.arange(tree_offset + built, tree_offset + built + block)),
+            NamedSharding(mesh, P()))
         with Span(
             "tree_block", objective=objective, trees=block, rows=n,
             first_tree=tree_offset + built, hist_slots=hist_slots,
@@ -1334,18 +1779,32 @@ def _train_boosted(
             jax.block_until_ready(margin)
         HIST_PSUM_BYTES.inc(block * psum_tree)
         with Span("tree_readback", trees=block) as readback:
-            # [block, C, M] each; with set-valued splits a sixth, [block, C, M, W]
+            # [block, C, M] each; with set-valued splits a sixth, [block, C,
+            # M, W]; a deep tree's sixth is its frontier slots' heap ids
             fields = jax.device_get(trees_dev)
             for t in range(block):
                 for c in range(C):
-                    trees_per_class[c].append(*(a[t, c] for a in fields))
+                    if deep:
+                        trees_per_class[c].append(*(a[t, c] for a in fields[:5]),
+                                                  node=fields[5][t, c])
+                    else:
+                        trees_per_class[c].append(*(a[t, c] for a in fields))
             n_split, n_set = _count_splits(fields[0], fields[3], p.cat_levels)
             readback.set(splits=n_split, set_splits=n_set)
+            if deep:
+                n_frontier = int((fields[5] >= 0).sum())
+                TREE_FRONTIER_NODES.inc(n_frontier)
+                readback.set(frontier_nodes=n_frontier)
         built += block
         if monitor is not None:
             with Span("budget_check", **sharded()) as check:
                 final_host = np.asarray(jax.device_get(margin), np.float64)[:n]
-                stop = bool(monitor(built - 1, final_host))
+                seen = final_host
+                if average:
+                    # what the forest predicts: the mean of its trees
+                    f0 = np.asarray(init_margin, np.float64)[None, :]
+                    seen = f0 + (final_host - f0) / built
+                stop = bool(monitor(built - 1, seen))
                 check.set(stop=stop)
             if stop:
                 break

@@ -499,25 +499,31 @@ def tree_fit_setup(frame: Frame, p, model_cls, use_offset: bool):
         return model, X, y, weights, offset, objective, f0, n_class_trees, mono
 
 
-def make_tree_monitor(model, p, objective, y, weights, history):
-    """ScoreKeeper monitor closure shared by GBM/XGBoost: wall-clock budget
-    (max_runtime_secs) + stopping_rounds early stopping. Returns
+def make_tree_monitor(model, p, objective, y, weights, history, score=None):
+    """ScoreKeeper monitor closure shared by GBM/XGBoost/DRF: wall-clock
+    budget (max_runtime_secs) + stopping_rounds early stopping. Returns
     (monitor_or_None, score_interval): when only the deadline is active the
     interval stays at the device block size so the budget check does not
-    force a host sync every tree."""
+    force a host sync every tree. ``score(margin)`` is the stopping metric
+    (``training_score`` of ``objective`` where None); a ``score_tree_interval``
+    of 0 (DRF's default) scores every tree block."""
     import time as _time
 
     from h2o3_tpu.models.tree.booster import tree_block_size
 
     deadline = (_time.time() + p.max_runtime_secs) if p.max_runtime_secs > 0 else None
+    interval = p.score_tree_interval or tree_block_size()
+    if score is None:
+        def score(margin):
+            return training_score(objective, y, margin, weights=weights)
 
     def monitor(t: int, margin: np.ndarray) -> bool:
         model.ntrees_built = t + 1
         if deadline is not None and _time.time() >= deadline:
             return True
-        if p.stopping_rounds <= 0 or (t + 1) % p.score_tree_interval:
+        if p.stopping_rounds <= 0 or (t + 1) % interval:
             return False
-        history.append(training_score(objective, y, margin, weights=weights))
+        history.append(score(margin))
         model.scoring_history.append({"tree": t + 1, "score": history[-1]})
         return M.stop_early(
             history, p.stopping_rounds, more_is_better=False,
@@ -525,10 +531,10 @@ def make_tree_monitor(model, p, objective, y, weights, history):
         )
 
     if p.stopping_rounds > 0:
-        return monitor, p.score_tree_interval
+        return monitor, interval
     if deadline is not None:
-        return monitor, max(p.score_tree_interval, tree_block_size())
-    return None, p.score_tree_interval
+        return monitor, max(interval, tree_block_size())
+    return None, interval
 
 
 def checkpoint_booster(

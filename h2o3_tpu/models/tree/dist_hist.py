@@ -592,6 +592,10 @@ def use_dist(frame, p, encoding: str) -> bool:
     if (getattr(p, "min_child_weight", None) is not None
             or getattr(p, "scale_pos_weight", 1.0) != 1.0):
         return False  # xgboost's hessian floor and class weight
+    from h2o3_tpu.models.tree.booster import frontier_start
+
+    if frontier_start(int(getattr(p, "max_depth", 0)), subtract=False) is not None:
+        return False  # every level here is a dense histogram of 2^d nodes
     return True
 
 

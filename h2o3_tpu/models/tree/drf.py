@@ -1,12 +1,22 @@
 """DRF — distributed random forest on the shared tree machinery.
 
 Reference: ``hex/tree/drf/DRF.java`` — same SharedTree driver as GBM, but
-bagged trees fit the raw response (no boosting), per-split feature sampling
-(mtries), sample_rate 0.632 default, and predictions aggregate by averaging.
+bagged trees fit the raw response (no boosting), sample_rate 0.632, and
+predictions aggregate by averaging. H2O's defaults hold: ``max_depth`` 20,
+``min_rows`` 1, ``nbins`` 20, ``mtries`` -1 (floor(sqrt(p)) features a split
+for classification, p/3 for regression). Levels past the dense node ladder
+are frontier levels (``booster._frontier_levels``): a node sits in a slot
+carrying its heap id and is histogrammed over its own mtries features only.
+A node's mtries draw is keyed by (the tree's key, the node's heap id), at
+every level. ``max_runtime_secs`` and ``stopping_rounds`` bound the fit
+through ``common.make_tree_monitor`` on the averaged margin, checked every
+``score_tree_interval`` trees (0: every tree block).
+
 Classification leaves hold class frequencies; this build realizes that as a
 per-class indicator-regression tree (leaf = class fraction in the leaf),
 averaged over trees and normalized — same estimator, SPMD-friendly shapes.
-OOB scoring is a planned refinement (reference scores OOB by default).
+The training metrics are in-bag, over every row of the averaged margin;
+H2O reports a DRF's training metrics out of bag, which this build does not.
 """
 
 from __future__ import annotations
@@ -26,6 +36,7 @@ from h2o3_tpu.models.tree.common import (
     checkpoint_booster as _checkpoint_booster,
     extra_trees as _extra_trees,
     extract_weights,
+    make_tree_monitor,
     tree_cache_token,
     tree_data_info,
     tree_matrix,
@@ -35,13 +46,14 @@ from h2o3_tpu.models.tree.common import (
 @dataclass
 class DRFParameters(ModelParameters):
     ntrees: int = 50
-    max_depth: int = 12  # reference default 20; dense level-wise capacity caps this build
+    max_depth: int = 20  # the reference default (DRFParametersV3)
     nbins: int = 20
     nbins_cats: int = 1024  # most levels a categorical may have under enum
     min_rows: float = 1.0
     min_split_improvement: float = 1e-5
     sample_rate: float = 0.632  # reference DRF default (DRFParametersV3)
     mtries: int = -1  # -1: sqrt(F) classif, F/3 regression (DRF.java)
+    score_tree_interval: int = 0  # 0: the budget is checked every tree block
 
 
 class DRFModel(TreeModelBase):
@@ -58,10 +70,21 @@ class DRFModel(TreeModelBase):
         return p / p.sum(axis=1, keepdims=True)
 
 
+def _forest_score(model: DRFModel, y, margin, weights) -> float:
+    """The stopping metric of a forest's averaged margin: logloss of its
+    class probabilities, mse of a regression."""
+    raw = model._raw_from_margin(margin)
+    if model.is_classifier:
+        p = np.clip(raw[np.arange(len(y)), y.astype(np.int64)], 1e-15, 1.0)
+        return float(np.average(-np.log(p), weights=weights))
+    return float(np.average((raw - y) ** 2, weights=weights))
+
+
 class DRF(ModelBuilder):
 
     SUPPORTED_COMMON = frozenset(
-        {"checkpoint", "weights_column", "categorical_encoding"}
+        {"checkpoint", "weights_column", "categorical_encoding",
+         "stopping_rounds", "max_runtime_secs"}
     )
     algo_name = "drf"
     profile_counts = SPAN_COUNTS
@@ -128,6 +151,10 @@ class DRF(ModelBuilder):
             cat_levels=model.cat_levels,
         )
 
+        history = []
+        monitor, score_interval = make_tree_monitor(
+            model, p, None, y, weights, history,
+            score=lambda margin: _forest_score(model, y, margin, weights))
         # objective='fixed': each tree independently fits the raw targets
         # (g = -target, h = 1 gives Newton leaf = mean(target in leaf);
         # with weights g = -w*t, h = w gives the weighted in-leaf mean)
@@ -139,6 +166,8 @@ class DRF(ModelBuilder):
             init_margin=np.zeros(n_class_trees),
             params=tp,
             average=True,
+            monitor=monitor,
+            score_interval=score_interval,
             resume_from=_checkpoint_booster(
                 p, n_class_trees, self.algo_name,
                 n_features=F, encoding=model.tree_encoding,

@@ -60,9 +60,12 @@ from h2o3_tpu.util import telemetry
 # Pad rows are zero-filled (a scatter-add / one-hot contraction never
 # touches a node id beyond the real range) and the real ``n_nodes`` rows are
 # sliced back out, so the result is bit-identical to the unpadded build.
-# Past 512 a call runs unpadded.
+# Past 512 a call runs unpadded; no tree level builds more nodes than that:
+# a level past it is a frontier level (``booster.frontier_start``).
 
-_NODE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 512)
+#: the top of the node ladder, the most nodes a dense level builds
+MAX_DENSE_NODES = 512
+_NODE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, MAX_DENSE_NODES)
 
 PLAN_CACHE = telemetry.counter(
     "hist_plan_cache_total",
@@ -434,6 +437,62 @@ def build_histogram_sharded(
     out = _build_histogram_jit(
         bins, nodes, g, h, bins_fm, rw, k_pad, n_bins1, mesh, impl)
     return out[:n_nodes] if k_pad != n_nodes else out
+
+
+def build_frontier_histogram_sharded(
+    codes, slots, g, h, n_slots: int, n_bins1: int, mesh=None,
+    impl: Optional[str] = None, rw=None,
+):
+    """The histogram of a frontier level (a tree level past the node
+    ladder): [n_slots, m, n_bins1, 3] of (Σg, Σh, Σw), where each node is
+    histogrammed over its own m features alone. codes: [N, m] int32, a row's
+    codes of its node's features; slots: [N] int32, the row's node's slot
+    (``n_slots``: the row adds nothing). Shard-private, then psum.
+
+    Pallas: ``pallas_histogram.build_frontier_histogram_pallas`` (rows in
+    slot order, tiles over runs of slots, no padding a node); the XLA
+    scatter path is the oracle: the dense scatter with a row's m codes in
+    place of its F."""
+    impl = _hist_impl(impl)
+    _note_plan(("frontier", n_slots, n_bins1,
+                _shape_sig((codes, slots, g, h, rw)), mesh, impl), impl)
+    with jax.named_scope("hist_frontier"):
+        return _build_frontier_jit(codes, slots, g, h, rw, n_slots, n_bins1, mesh, impl)
+
+
+def _one_shard_frontier(codes, slots, g, h, n_slots, n_bins1, impl, vma=(), rw=None):
+    if impl == "pallas":
+        from h2o3_tpu.ops.pallas_histogram import build_frontier_histogram_pallas
+
+        return build_frontier_histogram_pallas(
+            codes, slots, g, h, n_slots, n_bins1,
+            interpret=jax.default_backend() != "tpu", vma=vma, rw=rw)
+    nodes = jnp.where(slots < n_slots, slots, -1)
+    return _shard_histogram(codes, nodes, g, h, n_slots, n_bins1, rw=rw)
+
+
+@partial(jax.jit, static_argnames=("n_slots", "n_bins1", "mesh", "impl"))
+def _build_frontier_jit(codes, slots, g, h, rw, n_slots: int, n_bins1: int,
+                        mesh, impl: str):
+    if mesh is None:
+        return _one_shard_frontier(codes, slots, g, h, n_slots, n_bins1, impl, rw=rw)
+    extras = [] if rw is None else [rw]
+
+    def fn(c, s, gg, hh, *rest):
+        part = _one_shard_frontier(c, s, gg, hh, n_slots, n_bins1, impl,
+                                   vma=(DATA_AXIS,), rw=rest[0] if rest else None)
+        with jax.named_scope("hist_psum"):
+            return jax.lax.psum(part, DATA_AXIS)
+
+    interpreted = impl == "pallas" and jax.default_backend() != "tpu"
+    return _shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS))
+        + tuple(P(DATA_AXIS) for _ in extras),
+        out_specs=P(),
+        check_vma=not interpreted,
+    )(codes, slots, g, h, *extras)
 
 
 @partial(jax.jit, static_argnames=("n_nodes", "n_bins1", "mesh", "impl"))

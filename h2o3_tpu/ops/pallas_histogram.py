@@ -394,6 +394,159 @@ def _prep_gathered(bins, nodes, g, h, n_nodes: int, n_bins1: int,
 _DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
 
 
+# ---------------------------------------------------------------------------
+# frontier kernel (levels past the node ladder: many small nodes)
+#
+# A frontier level has up to hundreds of thousands of nodes of a few rows
+# each, and each node is histogrammed over its own m features. Padding every
+# node to a row tile (the sorted kernel) would move tens of times the rows.
+# Here the rows are sorted by slot and the slots are cut into groups of
+# ``_FRONTIER_SLOTS``; only a group is padded to a whole number of tiles, so
+# the padding is at most a tile a group, whatever the nodes. A tile's rows
+# all belong to one group, and its [group slots, m * B1] partial histogram of
+# each channel is one MXU product of the tile-local one-hot of (slot) and
+# the one-hot of (feature j, bin), accumulated in VMEM across the group's
+# tiles.
+
+#: slots of one group: the sublane extent of a tile's one-hot of slots
+_FRONTIER_SLOTS = 256
+
+
+def _frontier_kernel(blk_ref, first_ref, jmod_ref, lslot_ref, codes_ref, vals_ref,
+                     out_ref, oh_ref, *, m, n_bins1, width):
+    """One grid step = one row tile of one group of slots.
+
+    jmod_ref: [B1, 1] f32 bin iota; lslot_ref: [1, R] int32, the row's slot
+    within the group (-1: a pad row); codes_ref: [m, R] int32, the row's
+    codes of its node's m features; vals_ref: [C, R] (g, h, w, 0);
+    out_ref: [1, 3, W, m*B1] f32, the group's histogram (revisited across
+    the group's tiles)."""
+    t = pl.program_id(0)
+    r = codes_ref.shape[1]
+    dtype = vals_ref.dtype
+    codes = codes_ref[...].astype(jnp.float32)  # codes <= 256 are exact
+    jm = jmod_ref[...]
+    for j in range(m):
+        oh_ref[j * n_bins1:(j + 1) * n_bins1, :] = (
+            jm == codes[j][None, :]).astype(dtype)
+    onehot = oh_ref[...]  # [m*B1, R]
+    sel = jax.lax.broadcasted_iota(jnp.int32, (width, r), 0) == lslot_ref[...]
+    # the select in float32, whose layout is the mask's; the product's
+    # operand is then cast (a bfloat16 select would relayout the mask)
+    vals = vals_ref[...].astype(jnp.float32)
+    slabs = []
+    for c in range(3):
+        a = jnp.where(sel, vals[c][None, :], 0.0).astype(dtype)  # [W, R]
+        slabs.append(jax.lax.dot_general(
+            a, onehot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32))  # [W, m*B1]
+    slab = jnp.stack(slabs)[None]
+
+    @pl.when(first_ref[t] == 1)
+    def _():
+        out_ref[...] = slab
+
+    @pl.when(first_ref[t] != 1)
+    def _():
+        out_ref[...] = out_ref[...] + slab
+
+
+def _prep_frontier(codes, slots, g, h, n_slots: int, n_bins1: int, row_tile: int,
+                   width: int, t_max: int, rw=None, dtype=jnp.float32):
+    """Operands of the frontier kernel: the rows sorted by slot, each group
+    of ``width`` slots padded to whole tiles (at least one: a group's
+    histogram is then always written), moved by one gather of a narrow
+    packed row as ``_prep_gathered`` does. Returns (lslot [1, T*R], codes
+    [m, T*R], vals [C, T*R], item_group [T] — group count for unused
+    tiles —, item_first [T])."""
+    n, m = codes.shape
+    r = row_tile
+    i32 = jnp.int32
+    nb = -(-n_slots // width)
+    past = nb * width  # the key of a row with no slot: past every group
+    key = jnp.where((slots >= 0) & (slots < n_slots), slots, past).astype(i32)
+    key_s, order = jax.lax.sort((key, jnp.arange(n, dtype=i32)), num_keys=1,
+                                is_stable=True)
+    blk_off = jnp.searchsorted(key_s, jnp.arange(nb + 1, dtype=i32) * width,
+                               side="left").astype(i32)
+    tiles = jnp.maximum((blk_off[1:] - blk_off[:-1] + r - 1) // r, 1)
+    tile_off = jnp.concatenate([jnp.zeros((1,), i32), jnp.cumsum(tiles)])
+    t = jnp.arange(t_max, dtype=i32)
+    item = jnp.minimum(jnp.searchsorted(tile_off[1:], t, side="right").astype(i32), nb)
+    item_first = jnp.concatenate([jnp.ones((1,), i32), (item[1:] != item[:-1]).astype(i32)])
+    grp = jnp.minimum(item, nb - 1)
+    start = blk_off[grp] + (t - tile_off[grp]) * r
+    limit = jnp.where(item < nb, blk_off[grp + 1], 0)
+    lane = jnp.arange(r, dtype=i32)[None, :]
+    valid = lane < (limit - start)[:, None]
+    # every tile row's sorted position, and from it the row and its slot by
+    # ONE gather of two columns: windows of ``order`` a tile (lax.gather of
+    # slices) would lower to a loop of a dynamic-slice a tile, ~17,000 a
+    # level at 8M rows, each an event of a profile
+    at = jnp.minimum(start[:, None] + lane, n - 1).reshape(t_max * r)
+    src, ks = jnp.stack([order, key_s], axis=1).at[at].get(
+        mode="promise_in_bounds").T
+    lslot = jnp.where(valid, ks.reshape(t_max, r) - grp[:, None] * width, -1)
+    w = jnp.ones_like(g) if rw is None else rw
+    rows = _pack_row(codes, g, h, w, n_bins1, dtype)
+    codes_p, vals_p = _unpack_row(
+        rows.at[src].get(mode="promise_in_bounds"), m, n_bins1, dtype)
+    vals_p = jnp.where(valid.reshape(t_max * r, 1), vals_p, jnp.zeros((), dtype))
+    return (lslot.reshape(1, t_max * r).astype(i32), codes_p.T, vals_p.T,
+            item, item_first)
+
+
+def build_frontier_histogram_pallas(codes, slots, g, h, n_slots: int, n_bins1: int,
+                                    interpret: bool = False, vma: tuple = (),
+                                    rw=None, dtype: str = "auto"):
+    """A frontier level's histogram: [n_slots, m, n_bins1, 3] float32 of
+    (Σg, Σh, Σw), a row adding to slot ``slots[i]`` (``n_slots``: none) at
+    (j, codes[i, j]). bf16 operands on a TPU, f32 interpreted
+    (``_kernel_choice``)."""
+    dtype = _kernel_choice("sorted", dtype, 0)[1]
+    return _build_frontier_pallas_jit(codes, slots, g, h, rw, n_slots, n_bins1,
+                                      interpret, vma, dtype)
+
+
+@partial(jax.jit, static_argnames=("n_slots", "n_bins1", "interpret", "vma", "dtype"))
+def _build_frontier_pallas_jit(codes, slots, g, h, rw, n_slots: int, n_bins1: int,
+                               interpret: bool, vma: tuple, dtype: str):
+    n, m = codes.shape
+    r, width = _ROW_TILE, _FRONTIER_SLOTS
+    nb = -(-n_slots // width)
+    t_max = -(-n // r) + nb
+    with jax.named_scope("frontier_prep"):
+        lslot, codes_p, vals_p, item, item_first = _prep_frontier(
+            codes, slots, g, h, n_slots, n_bins1, r, width, t_max, rw=rw,
+            dtype=_DTYPES[dtype])
+    jmod = jnp.asarray(np.arange(n_bins1)[:, None], dtype=jnp.float32)
+    if vma:
+        jmod = jax.lax.pcast(jmod, tuple(vma), to="varying")
+    mb = m * n_bins1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(t_max,),
+        in_specs=[
+            pl.BlockSpec((n_bins1, 1), lambda t, b, f: (0, 0)),
+            pl.BlockSpec((1, r), lambda t, b, f: (0, t)),
+            pl.BlockSpec((m, r), lambda t, b, f: (0, t)),
+            pl.BlockSpec((_C, r), lambda t, b, f: (0, t)),
+        ],
+        out_specs=pl.BlockSpec((1, 3, width, mb), lambda t, b, f: (b[t], 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((mb, r), _DTYPES[dtype])],
+    )
+    out = pl.pallas_call(
+        partial(_frontier_kernel, m=m, n_bins1=n_bins1, width=width),
+        grid_spec=grid_spec,
+        out_shape=_out_sds((nb + 1, 3, width, mb), jnp.float32, vma),
+        interpret=interpret,
+    )(item, item_first, jmod, lslot, codes_p, vals_p)
+    # [groups, 3, W, m*B1] -> [slots, m, B1, 3]
+    out = out[:nb].reshape(nb, 3, width, m, n_bins1)
+    out = jnp.transpose(out, (0, 2, 3, 4, 1)).reshape(nb * width, m, n_bins1, 3)
+    return out[:n_slots]
+
+
 def _kernel_choice(kernel: str, dtype: str, n_nodes: int):
     """The plan of one level, (kernel, operand precision), decided here and
     nowhere else, from what the code can observe. ``auto`` (what every fit

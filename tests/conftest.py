@@ -123,6 +123,38 @@ def _clear_jax_caches_between_modules():
     jax.clear_caches()
 
 
+def _max_map_count() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530
+
+
+_MAX_MAPS = _max_map_count()
+
+
+@pytest.fixture(autouse=True)
+def _release_compiled_programs_near_the_map_limit():
+    """Release compiled executables after a test that leaves the process
+    near the kernel's limit of memory mappings (``vm.max_map_count``).
+
+    Each live XLA:CPU executable holds a few mappings of its JIT code; a
+    module that fits many models (``test_automl.py``: hundreds of programs
+    across its AutoML runs) climbs to the limit within the module, and the
+    next compile that cannot map its code dies with SIGSEGV. Half the limit
+    leaves room for the largest single test; below it nothing is cleared,
+    so a module's tests still share their programs."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            mapped = f.read().count("\n")
+    except OSError:
+        return
+    if mapped > _MAX_MAPS // 2:
+        jax.clear_caches()
+
+
 @pytest.fixture()
 def parents_level_plan(monkeypatch):
     """Trace a tree level as the commits before ISSUE 35 did: the node

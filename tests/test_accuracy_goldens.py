@@ -32,7 +32,9 @@ GOLDEN = {
     "glm_binomial_auc": 0.8022620737109191,
     "gbm_binomial_auc": 0.8310825609898799,
     "xgboost_binomial_auc": 0.8873523696367261,
-    "drf_binomial_auc": 0.9957684879870464,
+    # re-recorded when DRF's default max_depth went from 12 to H2O's 20 and
+    # a node's mtries draw came to be keyed by its heap id
+    "drf_binomial_auc": 0.9997969754340276,
     "gbm_regression_rmse": 0.6585004906238698,
     "dl_regression_rmse": 1.0634751969103902,
     "kmeans_tot_withinss": 108.05436325073242,

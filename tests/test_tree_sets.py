@@ -474,11 +474,13 @@ def numeric_block_text(objective, C, block, p, impl, subtract, monkeypatch, n=10
 #: BEFORE the change: the scatter block, the Pallas block with subtraction
 #: and sampling (the chip's flow, interpreted), a DRF block (fixed targets,
 #: mtries), and ``_predict_stacked`` as ``programs.build_scoring_programs``
-#: lowers it
+#: lowers it. The DRF block's was recorded again when a node's mtries draw
+#: came to be keyed by its heap id (``booster._node_candidates``): the one
+#: thing that draw changed in its program
 PARENT_PROGRAMS = {
     "block_scatter": "3a50c57ac37cb62af4195df6a9fb9e1a45e227b50424d43b9a14542bb9c4a396",
     "block_pallas_subtract": "dde7eaf032dd91a0b2a2101646c6cdac1d5e0f0524496e52a4e7c6434c61072e",
-    "block_drf": "a138b78603b6f89b09e1e7f968e96b8df60987af7af81fc1e96c4ab90bfd2942",
+    "block_drf": "4ab0943d87427e2f923a175aa20d9a1f7606a5c19e9d71c4691218c8ecaa2eb2",
     "predict_stacked": "65fd78c589b300f01a4dcaa7e757aab45a4deccda6f745f4d125e892cf5d66db",
     # a sorted level (256 node slots), which no block above reaches at depth 3:
     # recorded from commit b201c72, before the level plan moved into one place
