@@ -1495,7 +1495,7 @@ def _count_splits(feat, is_split, cat_levels) -> Tuple[int, int]:
 
 
 def train_boosted(
-    X: np.ndarray,
+    X,
     objective: str,
     y: np.ndarray,
     n_class_trees: int,
@@ -1515,6 +1515,14 @@ def train_boosted(
 ) -> BoostedTrees:
     """Device-resident booster loop.
 
+    X: the training rows, an [N, F] float array or deferred rows
+    (``common.TreeRows``). Deferred rows are read only by the quantile
+    sketch's sample while the bin codes are resident (``cache_token``'s
+    devcache entry); they are built as a matrix for the codes' placement on
+    a miss, so a first fit under ``max_runtime_secs`` pays that matrix
+    inside its budget, beside ``apply_bins`` and the upload, and for a
+    checkpoint's margin. The ``train_boosted`` span says which
+    (``matrix_built`` / ``matrix_resident``; ``tree_entry_matrix_total``).
     objective: a grad_hess_device family name ('gaussian', 'bernoulli',
     'multinomial', 'poisson', 'gamma', 'laplace', 'tweedie:<p>',
     'huber:<delta>', 'quantile:<alpha>') or 'fixed' with y = targets [N, C]
@@ -1561,11 +1569,19 @@ def train_boosted(
     if mesh is None:
         mesh = default_mesh()
     with Span("train_boosted", objective=objective, rows=X.shape[0],
-              nshards=mesh.devices.size):
-        return _train_boosted(
+              nshards=mesh.devices.size) as span:
+        bt = _train_boosted(
             X, objective, y, n_class_trees, init_margin, params, average,
             monitor, score_interval, mesh, resume_from, weights, offset,
             monotone, cache_token, cache_frame_key, fit_eval)
+        if hasattr(X, "materialize"):  # deferred rows: were they built?
+            span.set(**{"matrix_" + X.count(): 1})
+        return bt
+
+
+def _dense(X) -> np.ndarray:
+    """X, or the whole float matrix of deferred rows (``common.TreeRows``)."""
+    return X.materialize() if hasattr(X, "materialize") else X
 
 
 def _train_boosted(
@@ -1616,7 +1632,7 @@ def _train_boosted(
 
     def _place_bins():
         resident.set(hit=False)
-        bins_host = apply_bins(X, edges, p.cat_levels)
+        bins_host = apply_bins(_dense(X), edges, p.cat_levels)
         padn = (-n) % mult
         if padn:
             bh = np.concatenate(
@@ -1692,7 +1708,7 @@ def _train_boosted(
             y_d = jax.device_put(y_host, row_sharding(mesh, 1))
 
         if resume_from is not None and objective != "fixed":
-            m0 = resume_from.predict_margin(X).astype(np.float32)  # [n, C]
+            m0 = resume_from.predict_margin(_dense(X)).astype(np.float32)  # [n, C]
             margin_host = np.tile(
                 np.asarray(init_margin, dtype=np.float32), (n_pad, 1)
             )

@@ -41,6 +41,14 @@ TRAIN_METRICS = telemetry.counter(
     labels=("source",),
 )
 
+ENTRY_MATRIX = telemetry.counter(
+    "tree_entry_matrix_total",
+    "tree fits by whether their training rows were built as a float matrix: "
+    "never, the bin codes being resident on the device (resident), or for "
+    "the codes' placement or a checkpoint's margin (built)",
+    labels=("path",),
+)
+
 
 def tree_data_info(frame: Frame, y: str, ignored=()) -> DataInfo:
     """Layout for tree models: raw numerics, and one column of level codes
@@ -68,10 +76,13 @@ NBINS_CATS = 1024
 #: launched, kernel]`` a level (``tree_block``; ``booster.level_plan``); on a
 #: mesh of several devices the bytes a shard of what was uploaded or read
 #: back (``bins_upload``, ``state_upload``, ``margin_readback``) and the bytes
-#: a device handed to the levels' psums (``tree_block``)
+#: a device handed to the levels' psums (``tree_block``); whether the fit's
+#: training rows were built as a float matrix (``train_boosted``:
+#: ``matrix_built`` or ``matrix_resident``, ``TreeRows.count``)
 SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks",
                "fit_margin", "fit_margin_device", "hist_slots",
-               "bytes_per_shard", "bytes_psummed")
+               "bytes_per_shard", "bytes_psummed", "matrix_built",
+               "matrix_resident")
 
 
 def resolve_tree_encoding(categorical_encoding: str) -> str:
@@ -132,27 +143,86 @@ def tree_matrix(
     whole block so NA routing still learns a default direction per split.
     """
     with Span("tree_matrix", rows=frame.nrows) as span:
-        cols = []
-        for name in info.predictor_names:
-            col = frame.col(name)
-            if name in info.cat_domains:
-                codes = _align_codes(col, info.cat_domains[name])
-                if encoding == "one_hot_explicit":
-                    dom = info.cat_domains[name]
-                    block = (codes[:, None] == np.arange(len(dom))[None, :]).astype(
-                        np.float32
-                    )
-                    block[codes < 0] = np.nan
-                    cols.append(block)
-                else:
-                    cols.append(
-                        np.where(codes >= 0, codes.astype(np.float32), np.nan)[:, None]
-                    )
-            else:
-                cols.append(col.numeric_view().astype(np.float32)[:, None])
-        X = np.concatenate(cols, axis=1)
+        X = _feature_rows(info, frame, encoding)
         span.set(features=X.shape[1])
     return X
+
+
+def _feature_rows(info: DataInfo, frame: Frame, encoding: str,
+                  rows: Optional[np.ndarray] = None) -> np.ndarray:
+    """``tree_matrix``'s conversion of the frame rows at ``rows`` (every row
+    where None): each column is selected first and converted after, and
+    every conversion is elementwise, so a row reads the same bits either
+    way."""
+    cols = []
+    for name in info.predictor_names:
+        col = frame.col(name)
+        if name in info.cat_domains:
+            codes = _align_codes(col, info.cat_domains[name])
+            if rows is not None:
+                codes = codes[rows]
+            if encoding == "one_hot_explicit":
+                dom = info.cat_domains[name]
+                block = (codes[:, None] == np.arange(len(dom))[None, :]).astype(
+                    np.float32
+                )
+                block[codes < 0] = np.nan
+                cols.append(block)
+            else:
+                cols.append(
+                    np.where(codes >= 0, codes.astype(np.float32), np.nan)[:, None]
+                )
+        else:
+            values = col.numeric_view()
+            if rows is not None:
+                values = values[rows]
+            cols.append(values.astype(np.float32)[:, None])
+    return np.concatenate(cols, axis=1)
+
+
+class TreeRows:
+    """A fit's training rows as a deferred ``[N, F]`` float32 matrix: the
+    rows of ``frame`` that ``keep`` marks, in ``tree_matrix``'s layout.
+
+    A fit whose bin codes are resident on the device (``frame/devcache``)
+    reads only the rows its quantile sketch samples (:meth:`rows`); the
+    whole matrix is built (:meth:`materialize`) only where every row is
+    read: the codes' placement on a cache miss, a checkpoint's margin."""
+
+    def __init__(self, info: DataInfo, frame: Frame, encoding: str,
+                 keep: np.ndarray) -> None:
+        self.info, self.frame, self.encoding, self.keep = info, frame, encoding, keep
+        self.shape = (int(keep.sum()),
+                      len(tree_feature_names(info, encoding)))
+        self._X: Optional[np.ndarray] = None
+
+    def rows(self, idx: Optional[np.ndarray] = None) -> np.ndarray:
+        """float32 ``[len(idx), F]`` of the kept rows at ``idx`` (every
+        kept row where None), equal bit for bit to those rows of
+        :meth:`materialize`."""
+        if self.shape[0] < self.keep.size:
+            where = np.flatnonzero(self.keep)
+            idx = where if idx is None else where[idx]
+        return _feature_rows(self.info, self.frame, self.encoding, idx)
+
+    def materialize(self) -> np.ndarray:
+        """``tree_matrix(...)[keep]``, built once; no copy where ``keep``
+        drops no row."""
+        if self._X is None:
+            X = tree_matrix(self.info, self.frame, encoding=self.encoding)
+            with Span("tree_rows") as span:
+                if self.shape[0] < self.keep.size:
+                    X = X[self.keep]
+                span.set(rows=X.shape[0], dropped=int(self.keep.size - X.shape[0]))
+            self._X = X
+        return self._X
+
+    def count(self) -> str:
+        """Count the fit in ``tree_entry_matrix_total`` and name its path:
+        ``built`` where the matrix was materialized, else ``resident``."""
+        path = "resident" if self._X is None else "built"
+        ENTRY_MATRIX.inc(path=path)
+        return path
 
 
 # -- distributions (hex/Distribution.java gradient/hessian families) ---------
@@ -424,26 +494,41 @@ def tree_cache_token(frame: Frame, p, encoding: str):
     )
 
 
-def extract_weights(frame: Frame, p, keep: np.ndarray):
-    """Load + validate weights_column, folding zero/NA-weight rows into the
-    keep mask (dropping them is equivalent to the reference's zero
-    contribution). Returns the [N] weights or None; index with keep after."""
-    if not p.weights_column:
-        return None
-    weights = frame.col(p.weights_column).numeric_view().astype(np.float64)
-    if np.nanmin(weights) < 0:
-        raise ValueError("weights_column must be non-negative")
-    keep &= ~np.isnan(weights) & (weights > 0)
-    return weights
+def training_rows(frame: Frame, p, info: DataInfo, encoding: str,
+                  y: np.ndarray, use_offset: bool = False):
+    """The rows a tree fit keeps (a row with an NA response, a zero or NA
+    weight or an NA offset is dropped) as deferred :class:`TreeRows`, and
+    the response, weights and offset of those rows: (X, y, weights,
+    offset). Nothing here reads a predictor column."""
+    keep = ~np.isnan(y)
+    weights = None
+    if p.weights_column:
+        weights = frame.col(p.weights_column).numeric_view().astype(np.float64)
+        if np.nanmin(weights) < 0:
+            raise ValueError("weights_column must be non-negative")
+        # dropping a zero-weight row is the reference's zero contribution
+        keep &= ~np.isnan(weights) & (weights > 0)
+    offset = None
+    if use_offset and p.offset_column:
+        offset = frame.col(p.offset_column).numeric_view().astype(np.float64)
+        keep &= ~np.isnan(offset)
+    X = TreeRows(info, frame, encoding, keep)
+    if X.shape[0] < keep.size:
+        y = y[keep]
+        weights = weights[keep] if weights is not None else None
+        offset = offset[keep] if offset is not None else None
+    return X, y, weights, offset
 
 
 def tree_fit_setup(frame: Frame, p, model_cls, use_offset: bool):
-    """Shared GBM/XGBoost front half of _fit: layout, matrices, aux columns,
-    objective resolution, init margin, monotone validation.
+    """Shared GBM/XGBoost front half of _fit: layout, kept rows, aux
+    columns, objective resolution, init margin, monotone validation.
 
     Returns (model, X, y, weights, offset, objective, f0, n_class_trees,
     mono) with the keep mask (NA response / zero-weight / NA-offset rows)
-    already applied to X/y/weights/offset."""
+    already applied to y/weights/offset; X is those rows as deferred
+    :class:`TreeRows`, built as a float matrix only where the booster must
+    read every row (``booster._train_boosted``)."""
     from h2o3_tpu.models.data_info import response_vector
 
     if getattr(frame, "chunk_layout", None) is not None:
@@ -458,7 +543,7 @@ def tree_fit_setup(frame: Frame, p, model_cls, use_offset: bool):
         # ineligible combination (knob off, checkpoint, monotone, custom
         # objective, explicit one-hot): materialize and run the legacy path
 
-    with Span("tree_setup") as span:
+    with Span("tree_setup", matrix="deferred") as span:
         ignored = list(p.ignored_columns)
         aux_cols = [p.weights_column] + ([p.offset_column] if use_offset else [])
         for aux in aux_cols:
@@ -471,21 +556,7 @@ def tree_fit_setup(frame: Frame, p, model_cls, use_offset: bool):
 
         model = model_cls(p, info, dist)
         enc = model.tree_encoding
-        X = tree_matrix(info, frame, encoding=enc)
-        # the rows a fit keeps: a copy of the whole matrix
-        with Span("tree_rows") as rows:
-            keep = ~np.isnan(y)
-            weights = extract_weights(frame, p, keep)
-            offset = None
-            if use_offset and p.offset_column:
-                offset = frame.col(p.offset_column).numeric_view().astype(np.float64)
-                keep &= ~np.isnan(offset)
-            X, y = X[keep], y[keep]
-            if weights is not None:
-                weights = weights[keep]
-            if offset is not None:
-                offset = offset[keep]
-            rows.set(rows=X.shape[0], dropped=int(keep.size - X.shape[0]))
+        X, y, weights, offset = training_rows(frame, p, info, enc, y, use_offset)
         span.set(rows=X.shape[0], features=X.shape[1])
 
         objective = resolve_objective(dist, p, y)

@@ -35,12 +35,12 @@ from h2o3_tpu.models.tree.common import (
     TreeModelBase,
     checkpoint_booster as _checkpoint_booster,
     extra_trees as _extra_trees,
-    extract_weights,
     make_tree_monitor,
+    training_rows,
     tree_cache_token,
     tree_data_info,
-    tree_matrix,
 )
+from h2o3_tpu.util.telemetry import Span
 
 
 @dataclass
@@ -104,20 +104,18 @@ class DRF(ModelBuilder):
             model, X, y, weights, nclasses = dist_hist.dist_drf_front(
                 frame, p, DRFModel)
         else:
-            ignored = list(p.ignored_columns)
-            if p.weights_column and p.weights_column not in ignored:
-                ignored.append(p.weights_column)
-            info = tree_data_info(frame, p.response_column, ignored)
-            y = response_vector(info, frame)
-            nclasses = (len(info.response_domain)
-                        if info.response_domain else 1)
-            model = DRFModel(p, info, "gaussian")
-            X = tree_matrix(info, frame, encoding=model.tree_encoding)
-            keep = ~np.isnan(y)
-            weights = extract_weights(frame, p, keep)
-            X, y = X[keep], y[keep]
-            if weights is not None:
-                weights = weights[keep]
+            with Span("tree_setup", matrix="deferred") as span:
+                ignored = list(p.ignored_columns)
+                if p.weights_column and p.weights_column not in ignored:
+                    ignored.append(p.weights_column)
+                info = tree_data_info(frame, p.response_column, ignored)
+                y = response_vector(info, frame)
+                nclasses = (len(info.response_domain)
+                            if info.response_domain else 1)
+                model = DRFModel(p, info, "gaussian")
+                X, y, weights, _ = training_rows(
+                    frame, p, info, model.tree_encoding, y)
+                span.set(rows=X.shape[0], features=X.shape[1])
         F = X.shape[1]
 
         mtries = p.mtries

@@ -117,23 +117,28 @@ def na_code(nbins: int, cat_levels=()) -> int:
 
 
 def make_bins(
-    X: np.ndarray, nbins: int = 256, sample: int = 200_000, seed: int = 0,
+    X, nbins: int = 256, sample: int = 200_000, seed: int = 0,
     cat_levels=(),
 ) -> np.ndarray:
     """Per-feature bin edges from (sampled) quantiles. Returns [F, nbins-1]
     interior edges; value -> bin = searchsorted(edges, v, 'right').
 
+    X: an ``[N, F]`` array, or deferred rows with ``shape`` and
+    ``rows(idx)`` (``models/tree/common.TreeRows``), of which only the
+    ``sample`` rows drawn are read; the draw and the edges are the same.
     cat_levels: per feature, the number of levels of a categorical column
     that is binned a bin a level (``categorical_encoding="enum"``) and 0 for
     a numeric one; empty for a frame with none.  A categorical feature has
     no sketch: its code is its level, and its row of edges is never read
     (+inf throughout)."""
     n, F = X.shape
+    idx = None  # every row
     if n > sample:
         idx = np.random.default_rng(seed).choice(n, sample, replace=False)
-        Xs = X[idx]
+    if hasattr(X, "rows"):
+        Xs = X.rows(idx)
     else:
-        Xs = X
+        Xs = X if idx is None else X[idx]
     qs = np.linspace(0, 1, nbins + 1)[1:-1]
     edges = np.empty((F, nbins - 1), dtype=np.float64)
     for f in range(F):

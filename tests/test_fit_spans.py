@@ -97,7 +97,9 @@ def test_entry_and_scoring_share_kinds_under_different_parents(fitted):
     by_id = {e["span_id"]: e for e in events}
     parents = {k: {by_id[e["parent_id"]]["kind"] for e in events if e["kind"] == k}
                for k in ("tree_matrix", "apply_bins")}
-    assert parents["tree_matrix"] == {"tree_setup", "model_performance"}
+    # the fixture's fit is a miss: its training rows are built as a matrix
+    # for the codes' placement, and the validation frame's for its walk
+    assert parents["tree_matrix"] == {"bins_resident", "model_performance"}
     assert parents["apply_bins"] == {"bins_resident", "model_performance"}
     resident = [e for e in events if e["kind"] == "bins_resident"]
     assert [e["hit"] for e in resident] == [False]
@@ -108,6 +110,15 @@ def test_second_fit_hits_the_resident_bins(fitted):
     _, _, events = _fit(frame)
     assert [e["hit"] for e in events if e["kind"] == "bins_resident"] == [True]
     assert "bins_upload" not in {e["kind"] for e in events}
+    # and no matrix of the training rows: a tree_matrix is a walk's
+    by_id = {e["span_id"]: e for e in events}
+
+    def scoring(e):
+        while e is not None and e["kind"] != "model_performance":
+            e = by_id.get(e["parent_id"])
+        return e is not None
+
+    assert all(scoring(e) for e in events if e["kind"] in ("tree_matrix", "tree_rows"))
 
 
 def test_fit_profile_rides_the_model_and_the_log(fitted):
