@@ -1633,25 +1633,28 @@ def _train_boosted(
     def _place_bins():
         resident.set(hit=False)
         bins_host = apply_bins(_dense(X), edges, p.cat_levels)
-        padn = (-n) % mult
-        if padn:
-            bh = np.concatenate(
-                [bins_host, np.zeros((padn, F), dtype=np.int32)], axis=0
-            )
-        else:
-            bh = bins_host
-        n_pad = bh.shape[0]
-        valid_h = np.arange(n_pad) < n
-        bfm_host = None
-        if use_pallas:
-            from h2o3_tpu.ops.pallas_histogram import _FEAT_BLOCK
+        # the codes padded to the row tile, and their feature-major copy
+        with Span("bins_layout") as layout:
+            padn = (-n) % mult
+            if padn:
+                bh = np.concatenate(
+                    [bins_host, np.zeros((padn, F), dtype=np.int32)], axis=0
+                )
+            else:
+                bh = bins_host
+            n_pad = bh.shape[0]
+            valid_h = np.arange(n_pad) < n
+            bfm_host = None
+            if use_pallas:
+                from h2o3_tpu.ops.pallas_histogram import _FEAT_BLOCK
 
-            fb = min(_FEAT_BLOCK, F)
-            Fp = F + (-F) % fb
-            bfm_host = np.zeros((Fp, n_pad), dtype=np.int32)
-            bfm_host[:F] = bh.T
-        nbytes = bh.nbytes + valid_h.nbytes + (
-            bfm_host.nbytes if bfm_host is not None else 0)
+                fb = min(_FEAT_BLOCK, F)
+                Fp = F + (-F) % fb
+                bfm_host = np.zeros((Fp, n_pad), dtype=np.int32)
+                bfm_host[:F] = bh.T
+            nbytes = bh.nbytes + valid_h.nbytes + (
+                bfm_host.nbytes if bfm_host is not None else 0)
+            layout.set(bytes=nbytes)
         with Span("bins_upload", bytes=nbytes, **sharded(nbytes)):
             bins_d = jax.device_put(bh, row_sharding(mesh, 2))
             valid_d = jax.device_put(valid_h, row_sharding(mesh, 1))
@@ -1670,19 +1673,20 @@ def _train_boosted(
 
     from h2o3_tpu.frame import devcache as _devcache
 
-    # the span holds the whole lookup: the histogram implementation (a
-    # process's first fit imports the Pallas modules here), the padding
-    # it asks for, the edges' digest and the cache's answer
+    # the span holds the whole lookup: the histogram implementation and the
+    # padding it asks for, the edges' digest and the cache's answer
     with Span("bins_resident", hit=True) as resident:
         # pallas path: pad every shard to the kernel row tile so the
-        # prepared feature-major copy needs no per-level realignment
-        use_pallas = _hist_impl(None) == "pallas"
-        if use_pallas:
-            from h2o3_tpu.ops.pallas_histogram import _ROW_TILE
+        # prepared feature-major copy needs no per-level realignment; a
+        # process's first fit imports the Pallas modules here
+        with Span("hist_impl"):
+            use_pallas = _hist_impl(None) == "pallas"
+            if use_pallas:
+                from h2o3_tpu.ops.pallas_histogram import _ROW_TILE
 
-            mult = nshards * _ROW_TILE
-        else:
-            mult = nshards
+                mult = nshards * _ROW_TILE
+            else:
+                mult = nshards
         edges_digest = hashlib.sha1(
             np.ascontiguousarray(edges).tobytes()
         ).hexdigest()
@@ -1743,7 +1747,7 @@ def _train_boosted(
         tree_offset = resume_from.trees_per_class[0].ntrees
         for c in range(C):
             trees_per_class[c].extend(resume_from.trees_per_class[c])
-    key = jax.random.PRNGKey(p.seed)
+    key = None  # the fit's PRNG key, made under the first block's tree_keys
 
     # the block program depends on neither ntrees nor seed — normalize them
     # out of the compile-cache key
@@ -1780,10 +1784,13 @@ def _train_boosted(
         # replicated over the mesh, as every other argument is placed on it:
         # the block is then the program its shapes alone lower to
         # (``block_fn.lower``), one entry of the persistent compile cache
-        keys = jax.device_put(
-            jax.vmap(lambda t: jax.random.fold_in(key, t))(
-                jnp.arange(tree_offset + built, tree_offset + built + block)),
-            NamedSharding(mesh, P()))
+        with Span("tree_keys", trees=block):
+            if key is None:
+                key = jax.random.PRNGKey(p.seed)
+            keys = jax.device_put(
+                jax.vmap(lambda t: jax.random.fold_in(key, t))(
+                    jnp.arange(tree_offset + built, tree_offset + built + block)),
+                NamedSharding(mesh, P()))
         with Span(
             "tree_block", objective=objective, trees=block, rows=n,
             first_tree=tree_offset + built, hist_slots=hist_slots,
@@ -1814,7 +1821,8 @@ def _train_boosted(
         built += block
         if monitor is not None:
             with Span("budget_check", **sharded()) as check:
-                final_host = np.asarray(jax.device_get(margin), np.float64)[:n]
+                with Span("margin_download", bytes=margin.nbytes, **sharded()):
+                    final_host = np.asarray(jax.device_get(margin), np.float64)[:n]
                 seen = final_host
                 if average:
                     # what the forest predicts: the mean of its trees

@@ -54,10 +54,15 @@ def tree_data_info(frame: Frame, y: str, ignored=()) -> DataInfo:
     """Layout for tree models: raw numerics, and one column of level codes
     a categorical (``tree_matrix`` expands it under ``one_hot_explicit``;
     whether the codes are split as ordinals or as sets is
-    ``resolve_tree_encoding``'s word)."""
-    return build_data_info(
-        frame, y=y, ignored=ignored, standardize=False, use_all_factor_levels=True
-    )
+    ``resolve_tree_encoding``'s word).  Under the span ``data_info``: a
+    frame's first fit pays here for its numeric columns' rollups and each
+    categorical column's most frequent level, a pass over every row."""
+    with Span("data_info", rows=frame.nrows) as span:
+        info = build_data_info(
+            frame, y=y, ignored=ignored, standardize=False, use_all_factor_levels=True
+        )
+        span.set(cat_columns=len(info.cat_domains))
+    return info
 
 
 TREE_ENCODINGS = ("auto", "enum", "label_encoder", "one_hot_explicit")
@@ -543,7 +548,7 @@ def tree_fit_setup(frame: Frame, p, model_cls, use_offset: bool):
         # ineligible combination (knob off, checkpoint, monotone, custom
         # objective, explicit one-hot): materialize and run the legacy path
 
-    with Span("tree_setup", matrix="deferred") as span:
+    with Span("tree_setup") as span:
         ignored = list(p.ignored_columns)
         aux_cols = [p.weights_column] + ([p.offset_column] if use_offset else [])
         for aux in aux_cols:

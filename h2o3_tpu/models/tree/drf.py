@@ -104,7 +104,7 @@ class DRF(ModelBuilder):
             model, X, y, weights, nclasses = dist_hist.dist_drf_front(
                 frame, p, DRFModel)
         else:
-            with Span("tree_setup", matrix="deferred") as span:
+            with Span("tree_setup") as span:
                 ignored = list(p.ignored_columns)
                 if p.weights_column and p.weights_column not in ignored:
                     ignored.append(p.weights_column)

@@ -21,11 +21,20 @@ dispatches, bytes ingested or store churn.  This module is that layer:
   persistent cache served counts under ``jit_cache_loads_total`` instead), the
   substrate for per-dispatch jit cache hit/miss accounting in
   ``compute/mapreduce.py`` and for the ``compiles`` / ``cache_loads`` fields
-  of a span inside which the calling thread built or loaded a program.
+  of a span inside which the calling thread built or loaded a program.  It
+  also hears JAX's trace of a program into a jaxpr and its lowering to
+  StableHLO: a span says ``trace_s`` / ``lower_s``, and under an open span
+  each stage of ``JIT_LEAF_S`` or more is a leaf event of the ring
+  (``jit_trace``, ``jit_lower``, ``jit_build``; shorter steps in a row are
+  merged into one).
 
-Under a ``jax.profiler`` session every :class:`Span` is also a
-``TraceAnnotation`` on its thread's line of the trace, so program spans and
-device operations share the profiler's clock.
+One clock: a ring event's ``start_ns`` and ``ns`` are ``time.time_ns()``
+(the host's wall clock, CLOCK_REALTIME), and so is the profiler's: under a
+``jax.profiler`` session every :class:`Span` is also a ``TraceAnnotation``
+on its thread's line of the trace, whose start, offset by the trace's
+``profile_start_time``, is the span's ``start_ns`` to well under a
+millisecond (``tests/test_fit_spans.py``), so program spans and device
+operations share one clock and a reader needs no conversion.
 
 The TPU-native story (SURVEY.md §5): ``jax.profiler`` owns the device-side
 trace; this registry owns the host-side control-plane numbers that DrJAX-style
@@ -753,7 +762,9 @@ class Span:
     name on its thread's line of the trace, with ``span_id``, ``trace_id``
     and ``parent_id`` as its arguments.  A span inside which the calling
     thread built or loaded XLA programs reports ``compiles``,
-    ``cache_loads`` and ``compile_s`` (never written when zero).
+    ``cache_loads`` and ``compile_s``, and one inside which it traced or
+    lowered them ``trace_s`` and ``lower_s`` (never written when zero; a
+    program traced inside another's trace counts once, in the outer).
 
     ``trace_id``/``parent_id`` may be passed explicitly to continue a trace
     that started somewhere else — another thread (a fan-out worker joining
@@ -795,7 +806,7 @@ class Span:
                 # the span header) — a fresh trace starts at a root
                 self.parent_id = None
         _span_stack().append(self)
-        self._built0 = _thread_builds()
+        self._built0 = _thread_counts()
         self._ann = _trace_annotation(
             self.kind, span_id=self.span_id, trace_id=self.trace_id,
             parent_id=self.parent_id or "")
@@ -808,14 +819,21 @@ class Span:
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
             self._ann = None
+        # both stamps sit beside the annotation's own: one clock, to
+        # microseconds, with the profiler's trace
+        end_ns = time.time_ns()
         stack = _span_stack()
         if stack and stack[-1] is self:
             stack.pop()
         elif self in stack:  # tolerate exotic unwinding, never corrupt peers
             stack.remove(self)
+        run = getattr(_tls_compiles, "run", None)
+        if run is not None and run["parent"] is self:
+            _flush_jit_run(_tls_compiles)
         evt = {
             "kind": self.kind,
             "start_ns": self.start_ns,
+            "ns": end_ns,
             "duration_ms": duration_ms,
             "ok": exc_type is None,
             "trace_id": self.trace_id,
@@ -829,14 +847,18 @@ class Span:
             evt["node"] = node
         if self.fields:
             evt.update(self.fields)
-        builds0, loads0, secs0 = self._built0
-        builds, loads, secs = _thread_builds()
+        builds0, loads0, secs0, trace0, lower0 = self._built0
+        builds, loads, secs, trace, lower = _thread_counts()
         if builds != builds0:
             evt["compiles"] = builds - builds0
         if loads != loads0:
             evt["cache_loads"] = loads - loads0
         if builds != builds0 or loads != loads0:
             evt["compile_s"] = round(secs - secs0, 6)
+        if trace != trace0:
+            evt["trace_s"] = round(trace - trace0, 6)
+        if lower != lower0:
+            evt["lower_s"] = round(lower - lower0, 6)
         timeline.record_event(evt)
 
 
@@ -886,10 +908,24 @@ def install_jax_compile_listener() -> bool:
             if name == "/jax/compilation_cache/cache_hits":
                 _tls_compiles.cache_hit = True
 
-        def _on_duration(name: str, secs: float, **kw: Any) -> None:
-            if name.endswith("backend_compile_duration"):
+        def _on_scalar(name: str, value: Any, **kw: Any) -> None:
+            # a stage opens (its start time is the value): a stage that
+            # closes while another is open on the thread lies inside it
+            if name in _JIT_STAGES:
                 t = _tls_compiles
-                if getattr(t, "cache_hit", False):
+                t.depth = getattr(t, "depth", 0) + 1
+
+        def _on_duration(name: str, secs: float, **kw: Any) -> None:
+            kind = _JIT_STAGES.get(name)
+            if kind is None:
+                return
+            t = _tls_compiles
+            depth = getattr(t, "depth", 0)
+            t.depth = max(depth - 1, 0)
+            load = False
+            if kind == "jit_build":
+                load = getattr(t, "cache_hit", False)
+                if load:
                     t.cache_hit = False
                     _JIT_CACHE_LOADS.inc()
                     t.loads = getattr(t, "loads", 0) + 1
@@ -898,11 +934,88 @@ def install_jax_compile_listener() -> bool:
                     t.builds = getattr(t, "builds", 0) + 1
                 _JIT_COMPILE_SECS.inc(secs)
                 t.seconds = getattr(t, "seconds", 0.0) + secs
+            if depth > 1:
+                return  # inside an outer stage, whose seconds hold it
+            if kind == "jit_trace":
+                t.trace_s = getattr(t, "trace_s", 0.0) + secs
+            elif kind == "jit_lower":
+                t.lower_s = getattr(t, "lower_s", 0.0) + secs
+            sp = current_span()
+            if sp is not None:
+                _jit_step(t, sp, kind, secs, str(kw.get("fun_name", "")), load)
 
         monitoring.register_event_listener(_on_event)
+        monitoring.register_scalar_listener(_on_scalar)
         monitoring.register_event_duration_secs_listener(_on_duration)
         _jit_listener_installed = True
         return True
+
+
+#: jax.monitoring's three stages of making a program (``jax._src.dispatch``)
+#: and the kind of the ring event each becomes
+_JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower",
+    "/jax/core/compile/backend_compile_duration": "jit_build",
+}
+#: a stage this long or longer is a leaf event of its own; shorter ones (the
+#: op-by-op helpers a first fit builds: keys, padding, casts) are merged
+#: with the short steps before them, under the same span and no more than
+#: this far apart, into one event that says how many ``steps`` it holds,
+#: and a merged run shorter than this is left to its span's fields.  So a
+#: process's first fit adds tens of events to the ring, not thousands
+JIT_LEAF_S = 0.010
+_JIT_LEAF_NS = int(JIT_LEAF_S * 1e9)
+
+
+def _jit_step(t: threading.local, sp: "Span", kind: str, secs: float,
+              fun: str, load: bool) -> None:
+    """One outermost stage of making a program under the open span ``sp``:
+    its end is now, its start that less its duration (never before the
+    span's own start)."""
+    end = time.time_ns()
+    start = max(end - int(secs * 1e9), sp.start_ns)
+    run = getattr(t, "run", None)
+    if secs >= JIT_LEAF_S:
+        if run is not None:
+            _flush_jit_run(t)
+        evt = _jit_event(kind, start, end, sp, fun=fun)
+        if load:
+            evt["cache_load"] = True
+        timeline.record_event(evt)
+        return
+    if (run is not None and run["parent"] is sp
+            and start - run["ns"] <= _JIT_LEAF_NS):
+        run["ns"] = end
+        run["steps"] += 1
+        run["s"][kind] = run["s"].get(kind, 0.0) + secs
+        return
+    if run is not None:
+        _flush_jit_run(t)
+    t.run = {"parent": sp, "start_ns": start, "ns": end, "steps": 1,
+             "s": {kind: secs}, "fun": fun}
+
+
+def _flush_jit_run(t: threading.local) -> None:
+    """Record the thread's run of short steps as one leaf, named for the
+    stage that took most of it, where the run lasted ``JIT_LEAF_S``."""
+    run, t.run = t.run, None
+    if run["ns"] - run["start_ns"] < _JIT_LEAF_NS:
+        return
+    kind = max(run["s"], key=run["s"].get)
+    timeline.record_event(_jit_event(kind, run["start_ns"], run["ns"], run["parent"],
+                                     fun=run["fun"], steps=run["steps"]))
+
+
+def _jit_event(kind: str, start: int, end: int, sp: "Span", **fields: Any) -> dict:
+    evt = {"kind": kind, "start_ns": start, "ns": end,
+           "duration_ms": round((end - start) / 1e6, 3), "ok": True,
+           "trace_id": sp.trace_id, "span_id": _new_id(), "parent_id": sp.span_id}
+    node = node_name()
+    if node:
+        evt["node"] = node
+    evt.update(fields)
+    return evt
 
 
 def jit_compile_count() -> float:
@@ -916,6 +1029,13 @@ def _thread_builds() -> Tuple[int, int, float]:
     t = _tls_compiles
     return (getattr(t, "builds", 0), getattr(t, "loads", 0),
             getattr(t, "seconds", 0.0))
+
+
+def _thread_counts() -> Tuple[int, int, float, float, float]:
+    """:func:`_thread_builds` and the seconds of outermost traces and
+    lowerings on the CALLING thread: what a span subtracts at its exit."""
+    t = _tls_compiles
+    return _thread_builds() + (getattr(t, "trace_s", 0.0), getattr(t, "lower_s", 0.0))
 
 
 def thread_compile_count() -> int:

@@ -59,9 +59,11 @@ def record_event(evt: Dict[str, Any]) -> None:
     """Append a pre-built event dict — the hot-path variant of
     :func:`record` for callers that already carry their trace fields
     (telemetry Spans): no kwargs splat, no provider merge, one dict.
-    The caller hands over ownership of ``evt``."""
+    The caller hands over ownership of ``evt``; an event that carries its
+    own end (``ns``, a step heard after it ended) keeps it."""
     global _counter
-    evt["ns"] = time.time_ns()
+    if "ns" not in evt:
+        evt["ns"] = time.time_ns()
     with _lock:
         _counter += 1
         evt["seq"] = _counter

@@ -168,11 +168,14 @@ def test_spans_are_annotations_of_the_profilers_trace(tmp_path):
                     annotated[stats["span_id"]] = (ev.name, ev.start_ns, ev.duration_ns)
     assert start is not None
     for e in events:
+        if e["kind"].startswith("jit_"):
+            continue  # a program's trace, lowering or build: heard after it, no annotation
         name, ev_start, ev_dur = annotated[e["span_id"]]
         assert name == e["kind"]
-        # the ring's wall clock maps onto the trace's by profile_start_time
-        assert abs(start + ev_start - e["start_ns"]) < 5e6
-        assert abs(start + ev_start + ev_dur - e["ns"]) < 5e6
+        # one clock: the ring's stamps are the trace's time plus its
+        # profile_start_time, to well under a millisecond
+        assert abs(start + ev_start - e["start_ns"]) < 1e6
+        assert abs(start + ev_start + ev_dur - e["ns"]) < 1e6
 
 
 SCOPES = ("L00/hist_nodes", "L00/hist", "L00/split", "L00/route", "L01/hist",

@@ -167,15 +167,26 @@ def test_a_second_fit_builds_no_matrix_and_trains_the_same(
 
 
 def test_the_matrix_sits_under_the_codes_placement_on_a_miss():
-    fit("gbm", frame_of(), "enum")
-    by_id = {e["span_id"]: e for e in timeline.snapshot(timeline.CAPACITY)}
+    frame = frame_of()
+    fit("gbm", frame, "enum")
+    by_id = {e["span_id"]: e for e in timeline.snapshot(timeline.CAPACITY)
+             if "span_id" in e}
     train = [e for e in by_id.values() if e["kind"] == "train"][-1]
     spans = [e for e in by_id.values() if e.get("trace_id") == train["trace_id"]]
     parents = {e["kind"]: by_id[e["parent_id"]]["kind"] for e in spans
                if e["kind"] in ("tree_matrix", "tree_rows")}
     assert parents == {"tree_matrix": "bins_resident", "tree_rows": "bins_resident"}
-    setup = [e for e in spans if e["kind"] == "tree_setup"]
-    assert [e["matrix"] for e in setup] == ["deferred"]
+    # a resident fit's tree_setup has no tree_matrix under it
+    _, _, counted = fit("gbm", frame, "enum")
+    assert counted == ["resident"]
+    by_id = {e["span_id"]: e for e in timeline.snapshot(timeline.CAPACITY)
+             if "span_id" in e}
+    train = [e for e in by_id.values() if e["kind"] == "train"][-1]
+    setup = [e for e in by_id.values()
+             if e["kind"] == "tree_setup" and e["parent_id"] == train["span_id"]]
+    assert len(setup) == 1
+    under = [e["kind"] for e in by_id.values() if e.get("parent_id") == setup[0]["span_id"]]
+    assert "data_info" in under and "tree_matrix" not in under
 
 
 @pytest.mark.parametrize("builder", sorted(BUILDERS))
