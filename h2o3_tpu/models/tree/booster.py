@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from h2o3_tpu.ops import histogram as _histogram
+from h2o3_tpu.ops.bitpack import field_bits, pack_words, unpack_words, word_layout
 from h2o3_tpu.ops.histogram import (
     _hist_impl,
     apply_bins,
@@ -61,6 +62,13 @@ TREE_FRONTIER_NODES = telemetry.counter(
     "live nodes of the frontier levels (levels past the dense node ladder, "
     "each node histogrammed over its own mtries features) of the trees read "
     "back from the device",
+)
+
+TREE_FRONTIER_ROW_GATHERS = telemetry.counter(
+    "tree_frontier_row_gathers_total",
+    "row-sized gathers the frontier levels of the tree blocks made: the "
+    "levels of every tree times the gathers a level (the tree_block span's "
+    "frontier_row_gathers)",
 )
 
 TREE_SPLITS = telemetry.counter(
@@ -1165,6 +1173,32 @@ def _build_one_tree(
     return tree, pred
 
 
+def _feature_words(key, node_ids, F: int, m: int):
+    """The features of the nodes of heap ids ``node_ids``
+    (``_node_candidates``) packed at ``ceil(log2 F)`` bits an id: their
+    [K] uint32 words (``bitpack``)."""
+    fidx = _node_candidates(key, node_ids, F, m)
+    return pack_words((fidx[:, j] for j in range(m)), (field_bits(F),) * m)
+
+
+def _kids_words(key, node, F: int, m: int):
+    """The feature words of each child of the nodes of heap ids ``node``,
+    drawn by the child's heap id, in its parent's place: ((left, right)
+    [K]) a word."""
+    return tuple(zip(_feature_words(key, 2 * node + 1, F, m),
+                     _feature_words(key, 2 * node + 2, F, m)))
+
+
+def _gather_rows(cols, idx):
+    """``[c[idx] for c in cols]``, the [K] columns moved by ONE row gather
+    of their [K, len(cols)] stack (a single column by itself: an [N, 1]
+    result may be laid out padded to 128 lanes)."""
+    if len(cols) == 1:
+        return [cols[0][idx]]
+    got = jnp.stack(cols, axis=1)[idx]
+    return [got[:, i] for i in range(len(cols))]
+
+
 def _children(can, parent_node, slots: int, pairs=()):
     """The next level's ``slots`` slots from the nodes of this one that
     split (``can``, heap ids ``parent_node``): the parent of rank r among
@@ -1179,7 +1213,7 @@ def _children(can, parent_node, slots: int, pairs=()):
     order = jax.lax.sort(((~can).astype(i32), jnp.arange(K, dtype=i32)),
                          num_keys=1, is_stable=True)[1]
     cols = [parent_node] + [jax.lax.bitcast_convert_type(v, i32) for pr in pairs for v in pr]
-    took = jnp.stack(cols, axis=1)[order]  # [K, 1 + 2 pairs]
+    took = _gather_rows(cols, order)  # 1 + 2 pairs, [K] each
 
     def interleave(left, right):
         v = jnp.stack([left, right], axis=1).reshape(2 * K)
@@ -1187,12 +1221,21 @@ def _children(can, parent_node, slots: int, pairs=()):
             return v[:slots]
         return jnp.concatenate([v, jnp.zeros(slots - 2 * K, v.dtype)])
 
-    node = interleave(2 * took[:, 0] + 1, 2 * took[:, 0] + 2)
+    node = interleave(2 * took[0] + 1, 2 * took[0] + 2)
     node = jnp.where(jnp.arange(slots, dtype=i32) // 2 < jnp.sum(can.astype(i32)), node, -1)
     vals = [jax.lax.bitcast_convert_type(
-        interleave(took[:, 1 + 2 * i], took[:, 2 + 2 * i]), jnp.float32)
+        interleave(took[1 + 2 * i], took[2 + 2 * i]), jnp.float32)
         for i in range(len(pairs))]
     return node, vals
+
+
+def frontier_row_gathers(impl: str) -> int:
+    """The row-sized gathers one frontier level makes (``_frontier_levels``):
+    the level's slot table by each row's slot and, where the histogram is
+    the Pallas kernel's, the rows sorted by slot into its tile layout
+    (``pallas_histogram._prep_frontier``). ``tests/test_frontier_gathers.py``
+    counts them in the traced level."""
+    return 1 + (impl == "pallas")
 
 
 def _frontier_levels(bins, g, h, sample, feat_mask, key, p: TreeParams, mesh,
@@ -1206,6 +1249,19 @@ def _frontier_levels(bins, g, h, sample, feat_mask, key, p: TreeParams, mesh,
     (all F where mtries <= 0), ``[slots, mtries, B+1, 3]``, with no
     subtraction (a child's features are not its parent's); the leaves come
     from the last split's child stats.
+
+    A row's slot fetches its node's split fields and, where a node has
+    fewer features than F, the feature lists of both of its children, in
+    ONE gather of the level's slot table a level (the chip prices a row
+    gather by the row and by each group of 8 int32 columns, so fields and
+    feature ids are packed into words, ``bitpack.pack_words``): the row
+    carries the words of the node it goes to (``fw``) into the next level,
+    whose codes are then selected with no gather. A level draws its
+    children's features by their heap ids in its own slots for the table,
+    and its own nodes' for the split search: a draw costs ~2.3 ms a level at
+    2^19 slots on a v5e, a gather of the children's words by rank 8.7 ms.
+    The first frontier level's words come by one gather before the levels;
+    with every feature a node's the codes are the row's own.
 
     Every frontier level has the slots of the deepest (``frontier_slots``),
     so the levels are one ``lax.scan`` over one
@@ -1223,12 +1279,29 @@ def _frontier_levels(bins, g, h, sample, feat_mask, key, p: TreeParams, mesh,
     N, F = bins.shape
     i32 = jnp.int32
     m = _mtries(p, F)
+    every = m == F  # every feature a node's: the codes are the row's own
     msi = max(p.min_split_improvement, 0.0)
     lr = jnp.float32(p.learn_rate)
     Kp = can_dense.shape[0]
     S = frontier_slots(p, N, rw is not None)
     lvl = f"L{d_f:02d}"
+    # the slot table's uint32 words: the leaf's bits; the split feature,
+    # bin, NA direction and 1 + the rank among the level's splits (0: no
+    # split); then each child's feature words
+    route_bits = (field_bits(F), field_bits(n_bins1), 1, field_bits(S + 1))
+    n_rw = 1 + word_layout(route_bits)[1]
+    feat_bits = (field_bits(F),) * m
+
+    def padded(v):  # [S] -> [S + 1], the last entry that of no slot
+        return jnp.concatenate([v, jnp.zeros((1,), v.dtype)])
+
     with jax.named_scope(f"L{d_f - 1:02d}/route"):
+        # the dense levels' row state, made whole before the frontier reads
+        # it: with the first level's feature gather reading ``slot``, XLA
+        # fused the chain of every dense level's routing into it, so each
+        # level's [N] routing values stayed live up to the last one (+495 MB
+        # of temporaries at 6M rows, for a described v5e)
+        slot, val = jax.lax.optimization_barrier((slot.astype(i32), val))
         dense_ids = Kp - 1 + jnp.arange(Kp, dtype=i32)
         if mono:
             # the dense children's bounds, by the dense child index 2k + side
@@ -1239,19 +1312,26 @@ def _frontier_levels(bins, g, h, sample, feat_mask, key, p: TreeParams, mesh,
         else:
             node, _ = _children(can_dense, dense_ids, S)
             bounds = (jnp.zeros(S, jnp.float32),) * 2  # unread
+    fw = None
+    if not every:
+        with jax.named_scope(f"{lvl}/hist_nodes"):
+            # the first frontier level's nodes' feature words, each row its own
+            fw = _gather_rows([padded(v) for v in _feature_words(key, node, F, m)],
+                              jnp.minimum(slot, S))
 
     def level(carry, _):
-        slot, val, node, _w, (lo_s, hi_s) = carry
+        slot, val, node, _w, (lo_s, hi_s), fw = carry
         at = slot < S
         with jax.named_scope(f"{lvl}/hist_nodes"):
-            if p.mtries > 0:
-                fidx = _node_candidates(key, node, F, m)
-            else:
+            if every:
                 fidx = jnp.broadcast_to(jnp.arange(F, dtype=i32), (S, F))
+                codes = bins
+            else:
+                fidx = _node_candidates(key, node, F, m)
+                # each row's codes of its node's features, [N, m]
+                row_f = unpack_words(lambda i: fw[i], feat_bits)
+                codes = jnp.stack([_sel_cols(bins, f) for f in row_f], axis=1)
             cand = feat_mask[fidx] & (node >= 0)[:, None]
-            # each row's codes of its node's features, [N, m]
-            row_f = jnp.concatenate([fidx, jnp.zeros((1, m), i32)])[jnp.minimum(slot, S)]
-            codes = jnp.stack([_sel_cols(bins, row_f[:, j]) for j in range(m)], axis=1)
             hslot = jnp.where(at & sample, slot, S).astype(i32)
         with jax.named_scope(f"{lvl}/hist"):
             hist = build_frontier_histogram_sharded(
@@ -1279,14 +1359,7 @@ def _frontier_levels(bins, g, h, sample, feat_mask, key, p: TreeParams, mesh,
             can = (gain > msi) & jnp.isfinite(gain) & (node >= 0)
         with jax.named_scope(f"{lvl}/route"):
             rank = jnp.cumsum(can.astype(i32)) - 1
-            tab = jnp.stack([bf, bb, dl.astype(i32), can.astype(i32), rank,
-                             jax.lax.bitcast_convert_type(leaf, i32)], axis=1)
-            r = jnp.concatenate([tab, jnp.zeros((1, 6), i32)])[jnp.minimum(slot, S)]
-            b = _sel_cols(bins, r[:, 0])
-            go_left = jnp.where(b >= n_bins1 - 1, r[:, 2] > 0, b <= r[:, 1])
-            val = jnp.where(at, jax.lax.bitcast_convert_type(r[:, 5], jnp.float32), val)
-            slot = jnp.where(at & (r[:, 3] > 0), 2 * r[:, 4] + jnp.where(go_left, 0, 1),
-                             S).astype(i32)
+            kids = () if every else _kids_words(key, node, F, m)
             if mono:
                 c_best = constraints[bf].astype(jnp.float32)
                 mid = jnp.clip(0.5 * (bwl + bwr), lo_s, hi_s)
@@ -1298,10 +1371,28 @@ def _frontier_levels(bins, g, h, sample, feat_mask, key, p: TreeParams, mesh,
                     can, node, S, ((bwl, bwr), (lo_l, lo_r), (hi_l, hi_r)))
             else:
                 node_next, (w_next,) = _children(can, node, S, ((bwl, bwr),))
-        return (slot, val, node_next, w_next, (lo_s, hi_s)), (bf, bb, dl, can, leaf, node)
+            # the table a row's slot reads, [S + 1, words]: its node's split
+            # and both children's feature words
+            cols = [jax.lax.bitcast_convert_type(leaf, jnp.uint32)] + pack_words(
+                (bf, bb, dl, jnp.where(can, rank + 1, 0)), route_bits)
+            cols += [lw for lw, _ in kids] + [rw_ for _, rw_ in kids]
+            r = _gather_rows([padded(v) for v in cols], jnp.minimum(slot, S))
+            rf, rb, rdl, rk = (v.astype(i32) for v in
+                               unpack_words(lambda i: r[1 + i], route_bits))
+            b = _sel_cols(bins, rf)
+            go_left = jnp.where(b >= n_bins1 - 1, rdl > 0, b <= rb)
+            val = jnp.where(at, jax.lax.bitcast_convert_type(r[0], jnp.float32), val)
+            slot = jnp.where(at & (rk > 0), 2 * (rk - 1) + jnp.where(go_left, 0, 1),
+                             S).astype(i32)
+            if not every:
+                n_fw = len(kids)
+                fw = [jnp.where(go_left, lw, rw_) for lw, rw_ in
+                      zip(r[n_rw:n_rw + n_fw], r[n_rw + n_fw:])]
+        return ((slot, val, node_next, w_next, (lo_s, hi_s), fw),
+                (bf, bb, dl, can, leaf, node))
 
-    carry = (slot.astype(i32), val, node, jnp.zeros(S, jnp.float32), bounds)
-    (slot, val, node, w_next, (lo_s, hi_s)), levels = jax.lax.scan(
+    carry = (slot, val, node, jnp.zeros(S, jnp.float32), bounds, fw)
+    (slot, val, node, w_next, (lo_s, hi_s), _), levels = jax.lax.scan(
         level, carry, None, length=D - d_f)
     with jax.named_scope("leaf"):
         raw = jnp.clip(w_next, lo_s, hi_s) if mono else w_next
@@ -1768,6 +1859,14 @@ def _train_boosted(
     width = {"totals": 1, "frontier": _mtries(p, F) * n_bins1}
     psum_tree = (nshards > 1) * C * 12 * sum(
         slots * width.get(kernel, F * n_bins1) for _, slots, kernel in hist_slots)
+    # what one frontier level gathers of every row, stated by the span, and
+    # what a tree's frontier levels gather
+    frontier = {}
+    if deep:
+        frontier["frontier_row_gathers"] = frontier_row_gathers(
+            "pallas" if use_pallas else "scatter")
+    gathers_tree = frontier.get("frontier_row_gathers", 0) * sum(
+        kernel == "frontier" for *_, kernel in hist_slots)
     while built < p.ntrees:
         block = (
             min(score_interval, p.ntrees - built)
@@ -1793,7 +1892,7 @@ def _train_boosted(
                 NamedSharding(mesh, P()))
         with Span(
             "tree_block", objective=objective, trees=block, rows=n,
-            first_tree=tree_offset + built, hist_slots=hist_slots,
+            first_tree=tree_offset + built, hist_slots=hist_slots, **frontier,
             **sharded(bytes_psummed=block * psum_tree),
         ):
             margin, trees_dev = fn(
@@ -1801,6 +1900,8 @@ def _train_boosted(
             )
             jax.block_until_ready(margin)
         HIST_PSUM_BYTES.inc(block * psum_tree)
+        if gathers_tree:
+            TREE_FRONTIER_ROW_GATHERS.inc(block * C * gathers_tree)
         with Span("tree_readback", trees=block) as readback:
             # [block, C, M] each; with set-valued splits a sixth, [block, C,
             # M, W]; a deep tree's sixth is its frontier slots' heap ids

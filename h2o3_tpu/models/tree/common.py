@@ -83,11 +83,13 @@ NBINS_CATS = 1024
 #: back (``bins_upload``, ``state_upload``, ``margin_readback``) and the bytes
 #: a device handed to the levels' psums (``tree_block``); whether the fit's
 #: training rows were built as a float matrix (``train_boosted``:
-#: ``matrix_built`` or ``matrix_resident``, ``TreeRows.count``)
+#: ``matrix_built`` or ``matrix_resident``, ``TreeRows.count``); the row
+#: gathers a frontier level makes (``tree_block`` of a deep tree, summed over
+#: the blocks)
 SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks",
                "fit_margin", "fit_margin_device", "hist_slots",
                "bytes_per_shard", "bytes_psummed", "matrix_built",
-               "matrix_resident")
+               "matrix_resident", "frontier_row_gathers")
 
 
 def resolve_tree_encoding(categorical_encoding: str) -> str:

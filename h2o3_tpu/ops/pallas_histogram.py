@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from h2o3_tpu.ops.bitpack import pack_words, unpack_words
+
 # channels: 0=Σg, 1=Σh, 2=Σw(count); a 4th pad channel keeps the matmul
 # operand lane-friendly.
 _C = 4
@@ -280,16 +282,12 @@ def _pack_row(bins, g, h, w, n_bins1: int, dtype):
     width of ``n_bins1`` (whose value itself, which matches no bin, stands
     for any code out of range: such a code still adds nothing); bfloat16 g
     and h share one word and w takes one, float32 operands one each."""
-    bits, per, _ = _code_words(n_bins1, bins.shape[1])
+    F = bins.shape[1]
+    bits = _code_words(n_bins1, F)[0]
     u32 = jnp.uint32
     b = bins.astype(jnp.int32)
     b = jnp.where((b >= 0) & (b < n_bins1), b, n_bins1).astype(u32)
-    words = []
-    for c in range(0, b.shape[1], per):
-        word = b[:, c]
-        for k in range(1, min(per, b.shape[1] - c)):
-            word = word | (b[:, c + k] << (bits * k))
-        words.append(word)
+    words = pack_words((b[:, c] for c in range(F)), (bits,) * F)
     if dtype == jnp.bfloat16:
         def u16(x):
             return jax.lax.bitcast_convert_type(
@@ -305,11 +303,10 @@ def _unpack_row(rows, n_feat: int, n_bins1: int, dtype):
     """Inverse of ``_pack_row`` on gathered rows: [T*R, P] int32 ->
     (codes [T*R, F] int32, vals [T*R, C] ``dtype`` of (g, h, w, 0)), bit
     for bit."""
-    bits, per, n_words = _code_words(n_bins1, n_feat)
+    bits, _, n_words = _code_words(n_bins1, n_feat)
     u = jax.lax.bitcast_convert_type(rows, jnp.uint32)
-    codes = jnp.stack(
-        [(u[:, c // per] >> (bits * (c % per))) & ((1 << bits) - 1)
-         for c in range(n_feat)], axis=1).astype(jnp.int32)
+    codes = jnp.stack(unpack_words(lambda i: u[:, i], (bits,) * n_feat),
+                      axis=1).astype(jnp.int32)
     v = u[:, n_words:]
     if dtype == jnp.bfloat16:
         def bf16(x):
@@ -455,8 +452,10 @@ def _prep_frontier(codes, slots, g, h, n_slots: int, n_bins1: int, row_tile: int
                    width: int, t_max: int, rw=None, dtype=jnp.float32):
     """Operands of the frontier kernel: the rows sorted by slot, each group
     of ``width`` slots padded to whole tiles (at least one: a group's
-    histogram is then always written), moved by one gather of a narrow
-    packed row as ``_prep_gathered`` does. Returns (lslot [1, T*R], codes
+    histogram is then always written). The narrow packed row
+    (``_pack_row``) is the sort's payload, so it reaches its sorted place
+    with its key, and ONE gather of (key, packed row) at every tile row's
+    sorted position lays out the tiles. Returns (lslot [1, T*R], codes
     [m, T*R], vals [C, T*R], item_group [T] — group count for unused
     tiles —, item_first [T])."""
     n, m = codes.shape
@@ -465,8 +464,13 @@ def _prep_frontier(codes, slots, g, h, n_slots: int, n_bins1: int, row_tile: int
     nb = -(-n_slots // width)
     past = nb * width  # the key of a row with no slot: past every group
     key = jnp.where((slots >= 0) & (slots < n_slots), slots, past).astype(i32)
-    key_s, order = jax.lax.sort((key, jnp.arange(n, dtype=i32)), num_keys=1,
-                                is_stable=True)
+    w = jnp.ones_like(g) if rw is None else rw
+    rows = _pack_row(codes, g, h, w, n_bins1, dtype)
+    # stable: a slot's rows keep their order in the frame, so the kernel
+    # sums each slot's rows in one fixed order
+    key_s, *rows_s = jax.lax.sort(
+        (key,) + tuple(rows[:, i] for i in range(rows.shape[1])), num_keys=1,
+        is_stable=True)
     blk_off = jnp.searchsorted(key_s, jnp.arange(nb + 1, dtype=i32) * width,
                                side="left").astype(i32)
     tiles = jnp.maximum((blk_off[1:] - blk_off[:-1] + r - 1) // r, 1)
@@ -480,17 +484,13 @@ def _prep_frontier(codes, slots, g, h, n_slots: int, n_bins1: int, row_tile: int
     lane = jnp.arange(r, dtype=i32)[None, :]
     valid = lane < (limit - start)[:, None]
     # every tile row's sorted position, and from it the row and its slot by
-    # ONE gather of two columns: windows of ``order`` a tile (lax.gather of
-    # slices) would lower to a loop of a dynamic-slice a tile, ~17,000 a
-    # level at 8M rows, each an event of a profile
+    # ONE gather: windows of the sorted rows a tile (lax.gather of slices)
+    # would lower to a loop of a dynamic-slice a tile, ~17,000 a level at 8M
+    # rows, each an event of a profile
     at = jnp.minimum(start[:, None] + lane, n - 1).reshape(t_max * r)
-    src, ks = jnp.stack([order, key_s], axis=1).at[at].get(
-        mode="promise_in_bounds").T
-    lslot = jnp.where(valid, ks.reshape(t_max, r) - grp[:, None] * width, -1)
-    w = jnp.ones_like(g) if rw is None else rw
-    rows = _pack_row(codes, g, h, w, n_bins1, dtype)
-    codes_p, vals_p = _unpack_row(
-        rows.at[src].get(mode="promise_in_bounds"), m, n_bins1, dtype)
+    got = jnp.stack([key_s] + rows_s, axis=1).at[at].get(mode="promise_in_bounds")
+    lslot = jnp.where(valid, got[:, 0].reshape(t_max, r) - grp[:, None] * width, -1)
+    codes_p, vals_p = _unpack_row(got[:, 1:], m, n_bins1, dtype)
     vals_p = jnp.where(valid.reshape(t_max * r, 1), vals_p, jnp.zeros((), dtype))
     return (lslot.reshape(1, t_max * r).astype(i32), codes_p.T, vals_p.T,
             item, item_first)
