@@ -557,27 +557,33 @@ def multinomial_metrics(
     y = np.asarray(actual)
     P = np.asarray(probs, dtype=np.float64)
     ok = y >= 0
-    y, P = y[ok].astype(np.int64), P[ok]
-    w, wsum = _weighted(y.astype(np.float64), None if weights is None else np.asarray(weights)[ok])
+    if not ok.all():  # rows with no response
+        y, P = y[ok], P[ok]
+        weights = None if weights is None else np.asarray(weights)[ok]
+    y = y.astype(np.int64)
+    w, wsum = _weighted(y.astype(np.float64), None if weights is None else np.asarray(weights))
     K = P.shape[1]
     eps = 1e-15
-    py = np.clip(P[np.arange(len(y)), y], eps, 1.0)
-    logloss = float(np.sum(w * -np.log(py)) / wsum)
+    at = (np.arange(len(y)), y)
+    py = P[at]
+    logloss = float(np.sum(w * -np.log(np.clip(py, eps, 1.0))) / wsum)
     # MSE over the 1-of-K residual (reference ModelMetricsMultinomial)
-    onehot = np.zeros_like(P)
-    onehot[np.arange(len(y)), y] = 1.0
-    mse = float(np.sum(w[:, None] * (onehot - P) ** 2) / wsum)
+    sq = P.copy()
+    sq[at] -= 1.0
+    sq *= sq
+    sq *= w[:, None]
+    mse = float(np.sum(sq) / wsum)
     pred = P.argmax(axis=1)
-    cm = np.zeros((K, K), dtype=np.float64)
-    np.add.at(cm, (y, pred), w)
+    cm = np.bincount(y * K + pred, weights=w, minlength=K * K).reshape(K, K)
     row = cm.sum(axis=1)
     per_class_err = np.where(row > 0, 1 - np.diag(cm) / np.maximum(row, 1e-300), np.nan)
     mpce = float(np.nanmean(per_class_err))
-    # top-k hit ratios
+    # top-k hit ratios: the true class's place in the order of -P, ties by
+    # class index (a stable sort's)
     kk = min(max_hit_ratio_k, K)
-    ranks = np.argsort(-P, axis=1)[:, :kk]
-    hits = ranks == y[:, None]
-    hr = (hits.astype(np.float64) * w[:, None]).sum(axis=0) if len(y) else np.zeros(kk)
+    rank = (P > py[:, None]).sum(axis=1) + (
+        (P == py[:, None]) & (np.arange(K) < y[:, None])).sum(axis=1)
+    hr = np.bincount(rank, weights=w, minlength=K)[:kk]
     hit_ratios = np.cumsum(hr) / wsum
     return MultinomialMetrics(
         logloss=logloss,

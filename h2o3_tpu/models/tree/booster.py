@@ -78,6 +78,14 @@ TREE_SPLITS = telemetry.counter(
     labels=("kind",),
 )
 
+HIST_LAUNCHES = telemetry.counter(
+    "tree_hist_launches_total",
+    "histogram kernel launches of the tree blocks run, as their level plans "
+    "state them (a level of K class trees is one launch while one kernel "
+    "holds its class x node slots)",
+    labels=("kernel",),
+)
+
 HIST_PSUM_BYTES = telemetry.counter(
     "tree_hist_psum_bytes_total",
     "bytes of level histograms (and leaf totals) a device of a mesh of "
@@ -122,6 +130,10 @@ class TreeParams:
     #: xgboost's ``scale_pos_weight``: g and h of a bernoulli fit's positive
     #: rows times it
     scale_pos_weight: float = 1.0
+    #: a bound on every leaf's value, None for none: H2O GBM bounds a
+    #: multinomial fit's leaves (``MULTINOMIAL_LEAF_MAX``), whose margins are
+    #: exponentiated
+    max_abs_leaf: Optional[float] = None
 
     @property
     def n_bins1(self) -> int:
@@ -368,23 +380,55 @@ def grad_hess_device(objective: str, y, margin):
     raise ValueError(f"unknown objective {objective!r}")
 
 
+def grad_hess_classes(objective: str, y, margin):
+    """(g, h) [C, N] of a fit of C class trees, from its class-major margin
+    [C, N]: the multinomial's softmax gradient g = p_k - 1[y = k], h =
+    p_k(1 - p_k) (floored at 1e-16), 1 - p_k taken as the other classes'
+    share; or the fixed targets [N, C] of a forest (g = -t, h = 1).
+    Class-major, so every class's [N] row is lane-dense."""
+    if objective == "multinomial":
+        C = margin.shape[0]
+        k = jnp.arange(C)[:, None]
+        e = jnp.exp(margin - margin.max(axis=0, keepdims=True))  # the top class's is 1
+        top = jnp.argmax(margin, axis=0)[None, :]
+        s = e.sum(axis=0, keepdims=True)
+        # 1 - p_k as the other classes' share, summed as such for the top
+        # class: a confident row's p is 1 to float32's rounding (6e-8), and
+        # 1 - p, its hessian and a correct row's gradient would read 0 where
+        # float64 reads 1e-9
+        rest = jnp.where(k == top, 0.0, e).sum(axis=0, keepdims=True)
+        p = e / s
+        q = jnp.where(k == top, rest, s - e) / s
+        g = jnp.where(y.astype(jnp.int32)[None, :] == k, -q, p)
+        return g, jnp.maximum(p * q, 1e-16)
+    if objective == "fixed":
+        t = y.T.astype(jnp.float32)
+        return -t, jnp.ones_like(t)
+    raise ValueError(f"objective {objective!r} has one class tree a round")
+
+
 # ---------------------------------------------------------------------------
 # traced level-step pieces
 
 
-def _order_levels(real, cat_idx: Tuple[int, ...], lam):
+#: float32's least normal number: a floor on a divisor under which no Σh of
+#: a float32 sum falls (a TPU flushes subnormals to zero)
+_F32_TINY = float(np.finfo(np.float32).tiny)
+
+
+def _order_levels(real, cat_idx: Tuple[int, ...], lam, h_floor: float = 1e-12):
     """The categorical features' bins of ``real`` [K, F, B, 3] put in the
     order their prefixes are searched in: levels that hold a row by
-    Σg/(Σh+λ) ascending, ties by level, levels with no row last. One sort
-    carries the sums along (no gather by the order), over the categoricals'
-    slices alone. Returns ``real`` with those slices reordered, and for the
-    categoricals [K, Fc, B]: every level's key, the sorted keys, the sorted
-    levels, and whether a level holds a row."""
+    Σg/max(Σh+λ, h_floor) ascending, ties by level, levels with no row
+    last. One sort carries the sums along (no gather by the order), over the
+    categoricals' slices alone. Returns ``real`` with those slices
+    reordered, and for the categoricals [K, Fc, B]: every level's key, the
+    sorted keys, the sorted levels, and whether a level holds a row."""
     F = real.shape[1]
     rc = jnp.stack([real[:, f] for f in cat_idx], axis=1)  # [K, Fc, B, 3]
     code = jnp.broadcast_to(jnp.arange(rc.shape[2], dtype=jnp.int32), rc.shape[:3])
     present = rc[..., 2] > 0
-    ratio = rc[..., 0] / jnp.maximum(rc[..., 1] + lam, 1e-12)
+    ratio = rc[..., 0] / jnp.maximum(rc[..., 1] + lam, h_floor)
     key = jnp.where(present, jnp.clip(ratio, -3e38, 3e38), jnp.inf)
     key_s, code_s, g_s, h_s, c_s = jax.lax.sort(
         (key, code, rc[..., 0], rc[..., 1], rc[..., 2]), dimension=2, num_keys=2)
@@ -432,7 +476,7 @@ def _split_search(
     hist, lam, alpha, gamma, lr, feat_mask, min_rows: float, n_bins1: int,
     constraints=None, node_lo=None, node_hi=None, child_stats: bool = False,
     cat_levels: Tuple[int, ...] = (), min_child_weight: Optional[float] = None,
-    deep: bool = False, stable_gain: bool = False,
+    deep: bool = False, stable_gain: bool = False, tiny_hessians: bool = False,
 ):
     """Per-node best split over (feature, bin, NA-direction).
 
@@ -477,6 +521,20 @@ def _split_search(
     ``HL HR (GL/HL - GR/HR)^2 / H``, the three-term form without its
     cancellation: float32 squares of sums over more than 4,096 rows round,
     and a pure node's candidates would read a gain of +-1e-4 instead of 0.
+
+    ``tiny_hessians`` (the class trees of a multinomial round, whose rows'
+    hessians go down to the floor of 1e-16): a candidate's right side is
+    summed from the right, over its own bins, as H2O's DTree sums from both
+    ends, not taken as the node's total less the left; a candidate's parent
+    is its own feature's total; and a categorical's levels are ordered by
+    Σg/Σh floored at float32's least normal, not at 1e-12. A rare class
+    (Σh 1e-5 a row) or a saturated one (1e-16) puts a side's Σh under the
+    float32 rounding of the node's sums, and the difference of two such
+    sums, taken over another feature's total, turned a split of no gain into
+    the best (gain 9,067 for 5.5e-5 on a 5-class root of 100,000 rows); and
+    levels whose Σh is under 1e-12 all read G x 1e12, an order of their
+    counts, so a 4.9M-row fit's rare-class tree took prefixes 28% short of
+    the best's gain.
     """
     B = n_bins1 - 1
     total = hist.sum(axis=2)  # [K, F, 3] — identical across F
@@ -489,7 +547,8 @@ def _split_search(
     cat_idx = tuple(f for f, v in enumerate(cat_levels) if v)
     if cat_idx:
         with jax.named_scope("sets"):
-            real, key, key_s, code_s, present = _order_levels(real, cat_idx, lam)
+            real, key, key_s, code_s, present = _order_levels(
+                real, cat_idx, lam, _F32_TINY if tiny_hessians else 1e-12)
     cum = jnp.cumsum(real, axis=2)  # bins <= b (the order's first b+1) on the left
 
     def thresh(g):
@@ -505,20 +564,32 @@ def _split_search(
         return -thresh(g) / jnp.maximum(h + lam, 1e-12)
 
     parent = side_score(G, H)  # [K]
+    if tiny_hessians:
+        # Σ of the bins past b, a candidate's right side less the NA bucket
+        after = jnp.flip(jnp.cumsum(jnp.flip(real, axis=2), axis=2), axis=2)
+        after = jnp.concatenate([after[:, :, 1:], jnp.zeros_like(after[:, :, :1])], axis=2)
+        parent_f = side_score(total[:, :, 0], total[:, :, 1])[:, :, None]  # [K, F, 1]
+        H_f = total[:, :, 1][:, :, None]
 
-    def dir_gain(gl, hl, cl):
+    def dir_gain(gl, hl, cl, right=None):
         # constraints mode materializes per-candidate child values (the
         # directional mask needs them); otherwise child stats for the ONE
         # winning candidate are gathered later — full [K, F, B] wl/wr
         # arrays would be pure waste on the default subtract path
-        gr = G[:, None, None] - gl
-        hr = H[:, None, None] - hl
-        cr = CNT[:, None, None] - cl
+        if right is None:
+            gr = G[:, None, None] - gl
+            hr = H[:, None, None] - hl
+            cr = CNT[:, None, None] - cl
+        else:
+            gr, hr, cr = right
+        # (the node's total and score sliced where the one-tree program did)
         if stable_gain:
             diff = gl / jnp.maximum(hl, 1e-12) - gr / jnp.maximum(hr, 1e-12)
-            gain = 0.5 * (hl * hr / jnp.maximum(H[:, None, None], 1e-12)) * diff * diff - gamma
+            gain = 0.5 * (hl * hr / jnp.maximum(
+                H[:, None, None] if right is None else H_f, 1e-12)) * diff * diff - gamma
         else:
-            gain = 0.5 * (side_score(gl, hl) + side_score(gr, hr) - parent[:, None, None]) - gamma
+            gain = 0.5 * (side_score(gl, hl) + side_score(gr, hr) - (
+                parent[:, None, None] if right is None else parent_f)) - gamma
         if min_child_weight is None:
             ok = (cl >= min_rows) & (cr >= min_rows)
         else:
@@ -534,12 +605,19 @@ def _split_search(
         return gain
 
     # NA right (default_left=False): left stats = cum; NA left: left += NA bucket
-    gain_r = dir_gain(cum[..., 0], cum[..., 1], cum[..., 2])
-    gain_l = dir_gain(
-        cum[..., 0] + na[..., 0][:, :, None],
-        cum[..., 1] + na[..., 1][:, :, None],
-        cum[..., 2] + na[..., 2][:, :, None],
-    )
+    if tiny_hessians:
+        gain_r = dir_gain(cum[..., 0], cum[..., 1], cum[..., 2],
+                          tuple(after[..., i] + na[..., i][:, :, None] for i in range(3)))
+        gain_l = dir_gain(
+            *(cum[..., i] + na[..., i][:, :, None] for i in range(3)),
+            tuple(after[..., i] for i in range(3)))
+    else:
+        gain_r = dir_gain(cum[..., 0], cum[..., 1], cum[..., 2])
+        gain_l = dir_gain(
+            cum[..., 0] + na[..., 0][:, :, None],
+            cum[..., 1] + na[..., 1][:, :, None],
+            cum[..., 2] + na[..., 2][:, :, None],
+        )
 
     go_left_better = gain_l > gain_r
     gain_fb = jnp.where(go_left_better, gain_l, gain_r)  # [K, F, B]
@@ -596,11 +674,33 @@ def _split_search(
         stats_l = stats_l + dl[:, None].astype(stats_l.dtype) * na_f
         gl_b, hl_b, cl_b = stats_l[:, 0], stats_l[:, 1], stats_l[:, 2]
         best_wl = opt_w(gl_b, hl_b)
-        best_wr = opt_w(G - gl_b, H - hl_b)
+        if tiny_hessians:
+            # the chosen candidate's right side as its gain saw it
+            if deep:
+                stats_r = jnp.sum(jnp.where(of_f[..., None] & of_b, after, 0.0), axis=(1, 2))
+            else:
+                after_f = jnp.take_along_axis(after, idx_f, axis=1)[:, 0]
+                stats_r = jnp.take_along_axis(
+                    after_f, jnp.broadcast_to(best_b[:, None, None], (K, 1, 3)), axis=1
+                )[:, 0]
+            stats_r = stats_r + (~dl)[:, None].astype(stats_r.dtype) * na_f
+            best_wr = opt_w(stats_r[:, 0], stats_r[:, 1])
+        else:
+            best_wr = opt_w(G - gl_b, H - hl_b)
         left_small = 2.0 * cl_b <= CNT
         return (best_f, best_b, dl, best_gain, lr * raw_leaf,
                 best_wl, best_wr, left_small) + sets
     return (best_f, best_b, dl, best_gain, lr * raw_leaf) + sets
+
+
+#: H2O GBM's bound on a multinomial leaf, whose margins are exponentiated
+#: (hex/tree/gbm/GBM.java: "check for very large values (which will get
+#: exponentiated later)"). A class whose prior is tiny (a few rows in
+#: millions) takes Newton steps of lr/p on its first round, and a row whose
+#: class probability has underflowed sums its gradient over a hessian at its
+#: 1e-16 floor: unbounded, such leaves reach 1e12 within a few rounds and the
+#: margins lose every smaller term
+MULTINOMIAL_LEAF_MAX = 1e4
 
 
 def _sel_table(table, idx):
@@ -904,47 +1004,85 @@ def _node_candidates(key, node_ids, F: int, m: int):
 
 
 def level_plan(p: TreeParams, subtract: bool, impl: Optional[str] = None,
-               rows: Optional[int] = None, weighted: bool = False):
-    """What every level of a tree of ``p`` launches, one ``(nodes built,
-    node slots launched, kernel)`` a level in the order the block runs them:
-    the histogram of levels 0 to ``max_depth - 1`` and, without subtraction,
-    the per-node totals of the leaves (``totals``). The padding a level pays
-    is ``slots / built``. A frontier level (``frontier_start``) says
+               rows: Optional[int] = None, weighted: bool = False,
+               classes: int = 1):
+    """What every level of a round of ``classes`` trees of ``p`` launches,
+    one ``(nodes built, node slots launched, kernel)`` a level in the order
+    the block runs them: the histogram of levels 0 to ``max_depth - 1`` and,
+    without subtraction, the per-node totals of the leaves (``totals``). The
+    padding a level pays is ``slots / built``. A level of K class trees
+    counts its (class, node) slots; it is one launch of the kernel named
+    while that kernel holds them all and one a class past it
+    (``plan_launches``); K class trees build every node of a level and take
+    their leaves from the last split. A frontier level (``frontier_start``) says
     ``(2^d, slots, "frontier")``, every one of them the slots of the deepest
     (``_frontier_levels``), and a tree with one takes its leaves from the
     last split (no ``totals``). Pure Python over the
     functions the trace itself asks (``pad_nodes``, ``_hist_impl``,
-    ``_kernel_choice``, ``frontier_slots``); ``impl`` as
+    ``_kernel_choice``, ``frontier_slots``, ``class_launches``); ``impl`` as
     ``build_histogram_sharded`` takes it."""
     impl = _hist_impl(impl)
+    # K class trees build every node and take the leaves from the last split
+    # (``_grow_trees``)
+    stats = subtract or classes > 1
+    subtract = subtract and classes == 1
     if impl == "pallas":
         from h2o3_tpu.ops.pallas_histogram import _kernel_choice
 
-        def kernel(slots):
+        def kernel(built, slots):
+            if _histogram.class_launches(built, classes, impl) == 1:
+                slots = classes * slots
             return _kernel_choice("auto", "auto", slots)[0]
     else:
-        def kernel(slots):
+        def kernel(built, slots):
             return impl
 
     d_f = frontier_start(p.max_depth, subtract)
     dense = p.max_depth if d_f is None else d_f
     built = [_built_nodes(d, subtract) for d in range(dense)]
-    plan = [(k, pad_nodes(k), kernel(pad_nodes(k))) for k in built]
-    plan += [(2**d, frontier_slots(p, rows, weighted), "frontier")
+    plan = [(classes * k, classes * pad_nodes(k), kernel(k, pad_nodes(k))) for k in built]
+    plan += [(classes * 2**d, classes * frontier_slots(p, rows, weighted), "frontier")
              for d in range(dense, p.max_depth)]
-    if d_f is None and not (subtract and p.max_depth > 0):
+    if d_f is None and not (stats and p.max_depth > 0):
         leaves = 2**p.max_depth
-        plan.append((leaves, pad_nodes(leaves), "totals"))
+        plan.append((classes * leaves, classes * pad_nodes(leaves), "totals"))
     return tuple(plan)
 
 
-def _build_one_tree(
-    bins, g, h, sample, feat_mask, key, p: TreeParams, mesh, bins_fm=None,
+def plan_launches(plan, classes: int = 1, impl: Optional[str] = None) -> dict:
+    """Histogram kernel launches of one round of a ``level_plan``, by
+    kernel: a level of K class trees is one launch while one kernel holds
+    its (class, node) slots (``histogram.class_launches``), K past it; a
+    frontier level is a launch a class tree, the leaves' totals none."""
+    out: dict = {}
+    for built, _, kernel in plan:
+        if kernel == "totals":
+            continue
+        n = classes if kernel == "frontier" else _histogram.class_launches(
+            built // classes, classes, impl)
+        out[kernel] = out.get(kernel, 0) + n
+    return out
+
+
+def _grow_trees(
+    bins, g, h, sample, feat_mask, keys, p: TreeParams, mesh, bins_fm=None,
     constraints=None, rw=None, subtract: bool = False,
 ):
-    """Grow one tree to max_depth, fully traced. Levels are unrolled with
-    per-level static node capacity 2^d (the fixed-capacity redesign of the
-    reference's dynamic DTree node growth).
+    """Grow the class trees of one boosting round to max_depth together,
+    fully traced. Levels are unrolled with per-level static node capacity
+    2^d (the fixed-capacity redesign of the reference's dynamic DTree node
+    growth).
+
+    ``keys`` holds one PRNG key a class tree; ``g``, ``h`` are a class's
+    [N] gradient and hessian where there is one class tree and the [K, N]
+    class-major stack where there are K (never [N, K], whose minor axis XLA
+    pads to 128 lanes). Every row sits in one node of each class tree: a
+    level's slots are its (class, node) pairs, one histogram launch builds
+    all of them (``build_histogram_sharded`` takes the K rows of node ids),
+    and split search, subtraction and the leaves run on that flattened
+    class-major axis, each node on its own. A row's node in each class tree
+    is a lane-dense [N] vector of its own, routed by that tree's splits. One
+    class tree traces the very program a single tree always traced.
 
     Every row (sampled or not) is routed so its leaf is known at the end —
     the margin update is then a single small-table select, with no separate
@@ -962,28 +1100,58 @@ def _build_one_tree(
     it was.
 
     A tree deep enough to have frontier levels (``frontier_start``) grows
-    its dense levels as above and the rest by ``_frontier_levels``; every
-    row then carries its node's leaf value down the levels, so no lookup
-    over the heap is made at the end.
+    its dense levels as above and the rest by ``_frontier_levels``, a class
+    tree at a time; every row then carries its node's leaf value down the
+    levels, so no lookup over the heap is made at the end.
 
-    Returns (heap arrays [M], per-row leaf value [N]); for a deep tree the
-    arrays are the dense levels' heap followed by the frontier levels'
-    slots, and a sixth array holds the slots' heap ids.
+    Where ``p.max_abs_leaf`` is set (H2O GBM's multinomial fit) every leaf,
+    and so each row's value, is clamped to it.
+
+    Returns (heap arrays, per-row leaf value of each class tree): with one
+    class tree the arrays are [M] and the values one [N] in a list, with K
+    they are [K, M] and K of [N]. For a deep tree the arrays are the dense
+    levels' heap followed by the frontier levels' slots, and a sixth array
+    holds the slots' heap ids.
     """
+    nc = len(keys)  # class trees of the round
+    # K class trees build every node of a level: subtraction's larger child
+    # (the parent less the smaller) loses a side whose Σh is a saturated or
+    # rare class's (1e-16 or 1e-5 a row) to the float32 rounding of the
+    # parent's sums; the leaves below the last split still come from its
+    # child stats, not from a totals pass
+    stats = subtract or nc > 1
+    subtract = subtract and nc == 1
     D = p.max_depth
     n_bins1 = p.n_bins1
     sets = any(p.cat_levels)
     n_words = -(-(n_bins1 - 1) // 32)
     F = bins.shape[1]
-    pos = jnp.zeros(bins.shape[0], dtype=jnp.int32)  # absolute heap position
+
+    def of(a, c):
+        """Class c's nodes of a flattened (class, node) axis; the value
+        itself where there is one class tree."""
+        return a if nc == 1 else a.reshape(nc, -1, *a.shape[1:])[c]
+
+    def rows_of(a, c):  # class c's [N] row of a [K, N] stack
+        return a if nc == 1 else a[c]
+
+    def stacked(vs):  # K per-class [N] vectors as one [K, N] operand
+        return vs[0] if nc == 1 else jnp.stack(vs)
+
+    def joined(vs):  # K per-class node tables as one flattened axis
+        return vs[0] if nc == 1 else jnp.concatenate(vs)
+
+    # absolute heap position of each row in each class tree
+    pos = [jnp.zeros(bins.shape[0], dtype=jnp.int32) for _ in range(nc)]
     mono = constraints is not None
     if mono:
-        b_lo = jnp.full((1,), -jnp.inf, jnp.float32)
-        b_hi = jnp.full((1,), jnp.inf, jnp.float32)
+        b_lo = jnp.full((nc,), -jnp.inf, jnp.float32)
+        b_hi = jnp.full((nc,), jnp.inf, jnp.float32)
     d_f = frontier_start(D, subtract)
     deep = d_f is not None
     if deep:
-        val = jnp.zeros(bins.shape[0], jnp.float32)  # each row's node's leaf
+        # each row's node's leaf
+        val = [jnp.zeros(bins.shape[0], jnp.float32) for _ in range(nc)]
 
     tf_l, tb_l, tdl_l, tsp_l, tlf_l, tset_l = [], [], [], [], [], []
     prev_hist = prev_can = prev_left_small = prev_wl = prev_wr = None
@@ -996,17 +1164,20 @@ def _build_one_tree(
         lo = K - 1
         lvl = f"L{d:02d}"
         with jax.named_scope(f"{lvl}/hist_nodes"):
-            local = pos - lo
-            in_lvl = (local >= 0) & (local < K)
-            hist_nodes = jnp.where(in_lvl & sample, local, -1).astype(jnp.int32)
+            local, in_lvl, hist_nodes = [], [], []
+            for c in range(nc):
+                local.append(pos[c] - lo)
+                in_lvl.append((local[c] >= 0) & (local[c] < K))
+                hist_nodes.append(
+                    jnp.where(in_lvl[c] & sample, local[c], -1).astype(jnp.int32))
         if d == D:
             with jax.named_scope("leaf"):
-                if subtract and prev_wl is not None:  # D=0 has no parent split
+                if stats and prev_wl is not None:  # D=0 has no parent split
                     # terminal leaves straight from the parent split's child
                     # stats: child(2k+0) = wl[k], child(2k+1) = wr[k] — the
                     # level-(D-1) cumsum stats cover exactly the rows each
                     # child receives, so no totals pass is needed at all
-                    raw_leaf = jnp.stack([prev_wl, prev_wr], axis=1).reshape(K)
+                    raw_leaf = jnp.stack([prev_wl, prev_wr], axis=1).reshape(nc * K)
                 else:
                     # terminal level: no split is possible, so the full
                     # [K, F, B+1, 3] histogram (the widest of the tree) is
@@ -1014,8 +1185,9 @@ def _build_one_tree(
                     # values
                     from h2o3_tpu.ops.histogram import node_totals_sharded
 
-                    tot = node_totals_sharded(
-                        hist_nodes, g, h, K, mesh=mesh, rw=rw)
+                    tot = joined([node_totals_sharded(
+                        hist_nodes[c], rows_of(g, c), rows_of(h, c), K, mesh=mesh,
+                        rw=rw) for c in range(nc)])
                     G, H = tot[:, 0], tot[:, 1]
                     t = jnp.sign(G) * jnp.maximum(
                         jnp.abs(G) - jnp.float32(p.reg_alpha), 0.0
@@ -1024,13 +1196,13 @@ def _build_one_tree(
                         H + jnp.float32(p.reg_lambda), 1e-12)
                 if mono:
                     raw_leaf = jnp.clip(raw_leaf, b_lo, b_hi)
-                tf_l.append(jnp.zeros(K, jnp.int32))
-                tb_l.append(jnp.zeros(K, jnp.int32))
-                tdl_l.append(jnp.zeros(K, bool))
-                tsp_l.append(jnp.zeros(K, bool))
+                tf_l.append(jnp.zeros(nc * K, jnp.int32))
+                tb_l.append(jnp.zeros(nc * K, jnp.int32))
+                tdl_l.append(jnp.zeros(nc * K, bool))
+                tsp_l.append(jnp.zeros(nc * K, bool))
                 tlf_l.append(jnp.float32(p.learn_rate) * raw_leaf)
                 if sets:
-                    tset_l.append(jnp.zeros((K, n_words), jnp.uint32))
+                    tset_l.append(jnp.zeros((nc * K, n_words), jnp.uint32))
             break
         if subtract and d > 0:
             # build ONLY each parent's smaller child (one kernel slot per
@@ -1040,16 +1212,18 @@ def _build_one_tree(
             # masked to zero by prev_can.
             Kp = _built_nodes(d, subtract)
             with jax.named_scope(f"{lvl}/hist_nodes"):
-                par = jnp.clip(local // 2, 0, Kp - 1)
-                parity = local % 2
-                small_parity = jnp.where(prev_left_small, 0, 1)  # [Kp]
-                sp_row = _sel_table(small_parity.astype(jnp.int32), par)
-                half_nodes = jnp.where(
-                    in_lvl & sample & (parity == sp_row), par, -1
-                ).astype(jnp.int32)
+                half_nodes = []
+                for c in range(nc):
+                    par = jnp.clip(local[c] // 2, 0, Kp - 1)
+                    parity = local[c] % 2
+                    small_parity = jnp.where(of(prev_left_small, c), 0, 1)  # [Kp]
+                    sp_row = _sel_table(small_parity.astype(jnp.int32), par)
+                    half_nodes.append(jnp.where(
+                        in_lvl[c] & sample & (parity == sp_row), par, -1
+                    ).astype(jnp.int32))
             with jax.named_scope(f"{lvl}/hist"):
                 hist_small = build_histogram_sharded(
-                    bins, half_nodes, g, h, n_nodes=Kp, n_bins1=n_bins1,
+                    bins, stacked(half_nodes), g, h, n_nodes=Kp, n_bins1=n_bins1,
                     mesh=mesh, bins_fm=bins_fm, rw=rw,
                 )
             with jax.named_scope(f"{lvl}/subtract"):
@@ -1059,23 +1233,26 @@ def _build_one_tree(
                 left = jnp.where(ls_m, hist_small, hist_big)
                 right = jnp.where(ls_m, hist_big, hist_small)
                 hist = jnp.stack([left, right], axis=1).reshape(
-                    K, *hist_small.shape[1:]
+                    nc * K, *hist_small.shape[1:]
                 )
         else:
             with jax.named_scope(f"{lvl}/hist"):
                 hist = build_histogram_sharded(
-                    bins, hist_nodes, g, h, n_nodes=K, n_bins1=n_bins1,
+                    bins, stacked(hist_nodes), g, h, n_nodes=K, n_bins1=n_bins1,
                     mesh=mesh, bins_fm=bins_fm, rw=rw,
                 )
         with jax.named_scope(f"{lvl}/split"):
             if p.mtries > 0:
                 # the node's mtries features, drawn by its heap id
-                pick = _node_candidates(
-                    key, lo + jnp.arange(K, dtype=jnp.int32), F, _mtries(p, F))
-                chosen = jnp.any(
-                    pick[:, :, None] == jnp.arange(F, dtype=pick.dtype)[None, None, :],
-                    axis=1)
-                node_feat_mask = chosen & feat_mask[None, :]
+                node_feat_mask = []
+                for c in range(nc):
+                    pick = _node_candidates(
+                        keys[c], lo + jnp.arange(K, dtype=jnp.int32), F, _mtries(p, F))
+                    chosen = jnp.any(
+                        pick[:, :, None] == jnp.arange(F, dtype=pick.dtype)[None, None, :],
+                        axis=1)
+                    node_feat_mask.append(chosen & feat_mask[None, :])
+                node_feat_mask = joined(node_feat_mask)
             else:
                 node_feat_mask = feat_mask
             out = _split_search(
@@ -1090,18 +1267,19 @@ def _build_one_tree(
                 constraints=constraints if mono else None,
                 node_lo=b_lo if mono else None,
                 node_hi=b_hi if mono else None,
-                child_stats=subtract,
+                child_stats=stats,
                 cat_levels=p.cat_levels,
                 min_child_weight=p.min_child_weight,
                 deep=deep,
                 stable_gain=deep and _exact_gain(p),
+                tiny_hessians=nc > 1,
             )
             if sets:
                 with jax.named_scope("sets"):
                     node_set = _pack_words(out[-1])
                     tset_l.append(node_set)
                 out = out[:-1]
-            if mono or subtract:
+            if mono or stats:
                 bf, bb, dl, gain, leaf, bwl, bwr, left_small = out
             else:
                 bf, bb, dl, gain, leaf = out
@@ -1113,34 +1291,42 @@ def _build_one_tree(
         tlf_l.append(leaf)
         if subtract:
             prev_hist, prev_can, prev_left_small = hist, can, left_small
+        if stats:
             prev_wl, prev_wr = bwl, bwr
         with jax.named_scope(f"{lvl}/route"):
-            k = jnp.clip(local, 0, K - 1)
-            if sets:
-                with jax.named_scope("sets"):
-                    go_left, cank = _set_route(
-                        bins, k, bf, dl, can, node_set, n_bins1)
-            elif deep:
-                # a deep tree's rows carry their node's leaf, and at the
-                # last dense level the rank of their node among those that
-                # split (its children's slots on the first frontier level)
-                last = d == d_f - 1
-                rank = jnp.cumsum(can.astype(jnp.int32)) - 1
-                f, sb, dlk, cank, lfk, *rk = _sel_tables(
-                    (bf, bb, dl, can, leaf) + ((rank,) if last else ()), k)
-                val = jnp.where(in_lvl, lfk, val)
-                b = _sel_cols(bins, f)
-                go_left = jnp.where(b >= n_bins1 - 1, dlk, b <= sb)
-                if last:
-                    slot = jnp.where(
-                        in_lvl & cank, 2 * rk[0] + jnp.where(go_left, 0, 1),
-                        frontier_slots(p, bins.shape[0], rw is not None))
-            else:
-                f, sb, dlk, cank = _sel_tables((bf, bb, dl, can), k)
-                b = _sel_cols(bins, f)
-                go_left = jnp.where(b >= n_bins1 - 1, dlk, b <= sb)
-            child = 2 * (lo + k) + jnp.where(go_left, 1, 2)
-            pos = jnp.where(in_lvl & cank, child, pos).astype(jnp.int32)
+            if deep and d == d_f - 1:
+                slot = [None] * nc
+            for c in range(nc):
+                k = jnp.clip(local[c], 0, K - 1)
+                if sets:
+                    with jax.named_scope("sets"):
+                        go_left, cank = _set_route(
+                            bins, k, of(bf, c), of(dl, c), of(can, c), of(node_set, c),
+                            n_bins1)
+                elif deep:
+                    # a deep tree's rows carry their node's leaf, and at the
+                    # last dense level the rank of their node among those
+                    # that split (its children's slots on the first frontier
+                    # level)
+                    last = d == d_f - 1
+                    rank = jnp.cumsum(of(can, c).astype(jnp.int32)) - 1
+                    f, sb, dlk, cank, lfk, *rk = _sel_tables(
+                        (of(bf, c), of(bb, c), of(dl, c), of(can, c), of(leaf, c))
+                        + ((rank,) if last else ()), k)
+                    val[c] = jnp.where(in_lvl[c], lfk, val[c])
+                    b = _sel_cols(bins, f)
+                    go_left = jnp.where(b >= n_bins1 - 1, dlk, b <= sb)
+                    if last:
+                        slot[c] = jnp.where(
+                            in_lvl[c] & cank, 2 * rk[0] + jnp.where(go_left, 0, 1),
+                            frontier_slots(p, bins.shape[0], rw is not None))
+                else:
+                    f, sb, dlk, cank = _sel_tables(
+                        (of(bf, c), of(bb, c), of(dl, c), of(can, c)), k)
+                    b = _sel_cols(bins, f)
+                    go_left = jnp.where(b >= n_bins1 - 1, dlk, b <= sb)
+                child = 2 * (lo + k) + jnp.where(go_left, 1, 2)
+                pos[c] = jnp.where(in_lvl[c] & cank, child, pos[c]).astype(jnp.int32)
             if mono:
                 # propagate bounds: split midpoint caps the monotone side
                 c_best = jnp.take(constraints, bf).astype(jnp.float32)  # [K]
@@ -1149,28 +1335,49 @@ def _build_one_tree(
                 hi_left = jnp.where(c_best > 0, jnp.minimum(b_hi, mid), b_hi)
                 lo_right = jnp.where(c_best > 0, jnp.maximum(b_lo, mid), b_lo)
                 hi_right = jnp.where(c_best < 0, jnp.minimum(b_hi, mid), b_hi)
-                b_lo = jnp.stack([lo_left, lo_right], axis=1).reshape(2 * K)
-                b_hi = jnp.stack([hi_left, hi_right], axis=1).reshape(2 * K)
+                b_lo = jnp.stack([lo_left, lo_right], axis=1).reshape(nc * 2 * K)
+                b_hi = jnp.stack([hi_left, hi_right], axis=1).reshape(nc * 2 * K)
+
+    def heap(levels):
+        """A field's levels as each class tree's heap: per-level
+        concatenation IS the heap layout, node (d, i) -> 2^d - 1 + i."""
+        if nc == 1:
+            return jnp.concatenate(levels)
+        return jnp.concatenate([a.reshape(nc, -1, *a.shape[1:]) for a in levels], axis=1)
 
     if deep:
-        dense = tuple(jnp.concatenate(a) for a in (tf_l, tb_l, tdl_l, tsp_l, tlf_l))
-        fr, val = _frontier_levels(
-            bins, g, h, sample, feat_mask, key, p, mesh, rw, slot, val, can,
-            (constraints, b_lo, b_hi) if mono else None, d_f)
-        tree = tuple(jnp.concatenate([a, b]) for a, b in zip(dense, fr[:5])) + (fr[5],)
-        return tree, val
+        dense = tuple(heap(a) for a in (tf_l, tb_l, tdl_l, tsp_l, tlf_l))
+        trees, preds = [], []
+        for c in range(nc):
+            fr, v = _frontier_levels(
+                bins, rows_of(g, c), rows_of(h, c), sample, feat_mask, keys[c], p, mesh,
+                rw, slot[c], val[c], of(can, c),
+                (constraints, of(b_lo, c), of(b_hi, c)) if mono else None, d_f)
+            tree = tuple(jnp.concatenate([rows_of(a, c), b])
+                         for a, b in zip(dense, fr[:5])) + (fr[5],)
+            if p.max_abs_leaf is not None:
+                bound = jnp.float32(p.max_abs_leaf)
+                tree = tree[:4] + (jnp.clip(tree[4], -bound, bound),) + tree[5:]
+                v = jnp.clip(v, -bound, bound)
+            trees.append(tree)
+            preds.append(v)
+        if nc == 1:
+            return trees[0], preds
+        return tuple(jnp.stack(a) for a in zip(*trees)), preds
 
     with jax.named_scope("leaf"):
-        # per-level concatenation IS the heap layout: node (d, i) -> 2^d - 1 + i
         tree = (
-            jnp.concatenate(tf_l),
-            jnp.concatenate(tb_l),
-            jnp.concatenate(tdl_l),
-            jnp.concatenate(tsp_l),
-            jnp.concatenate(tlf_l),
-        ) + ((jnp.concatenate(tset_l),) if sets else ())
-        pred = _sel_table(tree[4], pos)
-    return tree, pred
+            heap(tf_l),
+            heap(tb_l),
+            heap(tdl_l),
+            heap(tsp_l),
+            heap(tlf_l),
+        ) + ((heap(tset_l),) if sets else ())
+        if p.max_abs_leaf is not None:
+            bound = jnp.float32(p.max_abs_leaf)
+            tree = tree[:4] + (jnp.clip(tree[4], -bound, bound),) + tree[5:]
+        preds = [_sel_table(rows_of(tree[4], c), pos[c]) for c in range(nc)]
+    return tree, preds
 
 
 def _feature_words(key, node_ids, F: int, m: int):
@@ -1421,26 +1628,36 @@ def _make_block_fn(
     bins_fm, w, mono) -> (margin', tree arrays [block, C, M]).
     `weighted`/`monotone` are compile-time flags so the unweighted /
     unconstrained program is byte-identical to before (w/mono are passed as
-    None and never touched)."""
+    None and never touched).
+
+    A round grows its C class trees together (``_grow_trees``). With C > 1
+    the rounds carry the margin class-major, [C, N], and the gradients are
+    computed so (``grad_hess_classes``): the [N, C] margin handed in and out
+    is transposed once a block."""
     C = n_class_trees
 
     @partial(jax.jit, donate_argnums=(3,))
     def block_fn(bins, y, valid, margin, keys, bins_fm, w, mono):
         def one_round(margin, key_t):
             with jax.named_scope("grad"):
-                g_all, h_all = grad_hess_device(objective, y, margin)
+                if C == 1:
+                    g_all, h_all = grad_hess_device(objective, y, margin)
+                else:
+                    g_all, h_all = grad_hess_classes(objective, y, margin)
+                # a row's weight along its axis: [N, 1] rows, or [C, N] classes
+                row = (slice(None), None) if C == 1 else (None, slice(None))
                 if weighted:
                     # fold row weights into (g, h): every Σg/Σh a histogram
                     # sees becomes the weighted sum (DHistogram's Σw-scaled
                     # stats)
-                    g_all = g_all * w[:, None]
-                    h_all = h_all * w[:, None]
+                    g_all = g_all * w[row]
+                    h_all = h_all * w[row]
                 if p.scale_pos_weight != 1.0:
                     # the class weight: a positive row's g and h count so
                     # many times (the counts stay rows)
                     cw = jnp.where(y > 0.5, jnp.float32(p.scale_pos_weight), 1.0)
-                    g_all = g_all * cw[:, None]
-                    h_all = h_all * cw[:, None]
+                    g_all = g_all * cw[row]
+                    h_all = h_all * cw[row]
             with jax.named_scope("sample"):
                 kr, kc, kt = jax.random.split(key_t, 3)
                 active = valid
@@ -1457,37 +1674,40 @@ def _make_block_fn(
                 else:
                     feat_mask = jnp.ones((F,), bool)
 
-            outs = []
-            for c in range(C):
+            if C == 1:
                 with jax.named_scope("grad"):
-                    g_c = g_all[:, c].astype(jnp.float32)
-                    h_c = h_all[:, c].astype(jnp.float32)
-                tree, pred = _build_one_tree(
-                    bins,
-                    g_c,
-                    h_c,
-                    active,
-                    feat_mask,
-                    jax.random.fold_in(kt, c),
-                    p,
-                    mesh,
-                    bins_fm=bins_fm,
-                    constraints=mono if monotone else None,
-                    rw=w if weighted else None,
-                    subtract=subtract,
-                )
-                # margin update from this tree (full data, not just the sample)
-                with jax.named_scope("margin"):
-                    margin = margin.at[:, c].add(pred)
-                outs.append(tree)
-            stacked = tuple(
-                jnp.stack([outs[c][i] for c in range(C)])
-                for i in range(len(outs[0]))
-            )  # each [C, M]; with set-valued splits a sixth, [C, M, W]
-            return margin, stacked
+                    g_all = g_all[:, 0].astype(jnp.float32)
+                    h_all = h_all[:, 0].astype(jnp.float32)
+            tree, preds = _grow_trees(
+                bins,
+                g_all,
+                h_all,
+                active,
+                feat_mask,
+                [jax.random.fold_in(kt, c) for c in range(C)],
+                p,
+                mesh,
+                bins_fm=bins_fm,
+                constraints=mono if monotone else None,
+                rw=w if weighted else None,
+                subtract=subtract,
+            )
+            # margin update from the round's trees (full data, not just the
+            # sample); each field [C, M], with set-valued splits a sixth,
+            # [C, M, W]
+            with jax.named_scope("margin"):
+                if C == 1:
+                    margin = margin.at[:, 0].add(preds[0])
+                else:
+                    margin = margin + jnp.stack(preds)
+            if C == 1:
+                tree = tuple(jnp.stack([a]) for a in tree)
+            return margin, tree
 
+        if C > 1:
+            margin = margin.T
         margin, trees = jax.lax.scan(one_round, margin, keys)
-        return margin, trees
+        return (margin if C == 1 else margin.T), trees
 
     return block_fn
 
@@ -1693,7 +1913,8 @@ def _train_boosted(
     n_bins1 = p.n_bins1
     n_cat = sum(1 for v in p.cat_levels if v)
     subtract_on = _tree_subtract_enabled()
-    deep = frontier_start(p.max_depth, subtract_on) is not None
+    # K class trees build every node (``_grow_trees``)
+    deep = frontier_start(p.max_depth, subtract_on and n_class_trees == 1) is not None
     if deep and n_cat:
         raise NotImplementedError(
             f"set-valued splits on categorical columns (categorical_encoding="
@@ -1852,12 +2073,14 @@ def _train_boosted(
     # what the block's levels launch, (built, slots, kernel) a level: the
     # span states it, so padding reads as slots / built without a trace
     hist_slots = level_plan(p_key, subtract_on, rows=n_pad,
-                            weighted=weights is not None)
+                            weighted=weights is not None, classes=C)
+    # the histogram launches of a round, by kernel: the span states their sum
+    launches = plan_launches(hist_slots, C)
     # what one tree's levels hand to their psums, on each device: float32
     # [slots, F, B+1, 3] a histogram level ([slots, mtries, B+1, 3] a
     # frontier level), [slots, 3] the leaf totals
     width = {"totals": 1, "frontier": _mtries(p, F) * n_bins1}
-    psum_tree = (nshards > 1) * C * 12 * sum(
+    psum_tree = (nshards > 1) * 12 * sum(
         slots * width.get(kernel, F * n_bins1) for _, slots, kernel in hist_slots)
     # what one frontier level gathers of every row, stated by the span, and
     # what a tree's frontier levels gather
@@ -1892,7 +2115,8 @@ def _train_boosted(
                 NamedSharding(mesh, P()))
         with Span(
             "tree_block", objective=objective, trees=block, rows=n,
-            first_tree=tree_offset + built, hist_slots=hist_slots, **frontier,
+            first_tree=tree_offset + built, hist_slots=hist_slots,
+            class_trees=C, hist_launches=block * sum(launches.values()), **frontier,
             **sharded(bytes_psummed=block * psum_tree),
         ):
             margin, trees_dev = fn(
@@ -1900,6 +2124,8 @@ def _train_boosted(
             )
             jax.block_until_ready(margin)
         HIST_PSUM_BYTES.inc(block * psum_tree)
+        for kernel, k in launches.items():
+            HIST_LAUNCHES.inc(block * k, kernel=kernel)
         if gathers_tree:
             TREE_FRONTIER_ROW_GATHERS.inc(block * C * gathers_tree)
         with Span("tree_readback", trees=block) as readback:

@@ -85,11 +85,13 @@ NBINS_CATS = 1024
 #: training rows were built as a float matrix (``train_boosted``:
 #: ``matrix_built`` or ``matrix_resident``, ``TreeRows.count``); the row
 #: gathers a frontier level makes (``tree_block`` of a deep tree, summed over
-#: the blocks)
+#: the blocks); the histogram kernel launches of a block (``tree_block``'s
+#: ``hist_launches``: a level of K class trees is one while one kernel holds
+#: its class x node slots)
 SPAN_COUNTS = ("splits", "set_splits", "cat_features", "sets", "chunks",
                "fit_margin", "fit_margin_device", "hist_slots",
                "bytes_per_shard", "bytes_psummed", "matrix_built",
-               "matrix_resident", "frontier_row_gathers")
+               "matrix_resident", "frontier_row_gathers", "hist_launches")
 
 
 def resolve_tree_encoding(categorical_encoding: str) -> str:
@@ -813,7 +815,9 @@ class TreeModelBase(Model):
                     return metrics
             with Span("score_link", rows=rows):
                 raw = self._raw_from_margin(np.asarray(ev["margin"], np.float64))
-            with Span("score_metrics", rows=rows):
+            # a K-class model's metrics say its classes (one host thread)
+            k = {"classes": self.nclasses} if self.nclasses > 2 else {}
+            with Span("score_metrics", rows=rows, **k):
                 return self._metrics(np.asarray(ev["y"], np.float64), raw, ev["w"])
 
     def predict_contributions(self, frame: Frame, background_frame=None) -> Frame:

@@ -1154,7 +1154,11 @@ def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                     hdl.append(np.zeros(k_term, bool))
                     hsp.append(np.zeros(k_term, bool))
                     hlf.append(leaves[ci])
-                    leaf_heap.append(np.concatenate(hlf))
+                    heap = np.concatenate(hlf)
+                    if p.max_abs_leaf is not None:
+                        heap = np.clip(heap, -np.float32(p.max_abs_leaf),
+                                       np.float32(p.max_abs_leaf))
+                    leaf_heap.append(heap)
                 fin = {"kind": "fin", "r": r, "c0": c0, "c1": c1,
                        "routes": term_routes,
                        "leaf": np.stack(leaf_heap).astype(np.float64),
@@ -1165,7 +1169,7 @@ def train_boosted_dist(Xd: DistTreeMatrix, objective: str, y, n_class_trees,
                     trees_per_class[c0 + ci].append(
                         np.concatenate(hf), np.concatenate(hb),
                         np.concatenate(hdl), np.concatenate(hsp),
-                        np.concatenate(hlf))
+                        leaf_heap[ci])
                 if want_margin:
                     return np.concatenate(
                         [np.asarray(o, np.float64) for o in outs], axis=0)

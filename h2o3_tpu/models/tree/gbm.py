@@ -14,7 +14,7 @@ from typing import Optional
 
 from h2o3_tpu.frame.frame import Frame
 from h2o3_tpu.models.framework import ModelBuilder, ModelParameters
-from h2o3_tpu.models.tree.booster import TreeParams, train_boosted
+from h2o3_tpu.models.tree.booster import MULTINOMIAL_LEAF_MAX, TreeParams, train_boosted
 from h2o3_tpu.models.tree.common import (
     SPAN_COUNTS,
     TreeModelBase,
@@ -86,6 +86,7 @@ class GBM(ModelBuilder):
             col_sample_rate_per_tree=p.col_sample_rate_per_tree,
             seed=p.actual_seed(),
             cat_levels=model.cat_levels,
+            max_abs_leaf=MULTINOMIAL_LEAF_MAX if objective == "multinomial" else None,
         )
 
         history = []

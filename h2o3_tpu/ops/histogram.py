@@ -304,11 +304,22 @@ def guard_hist_payload(what: str, n_classes: int, n_nodes: int,
 
 
 def _shard_histogram(bins, nodes, g, h, n_nodes: int, n_bins1: int, rw=None):
-    """Shard-private histogram: [K, F, B+1, 3] of (Σg, Σh, Σw).
+    """Shard-private histogram: [K, F, B+1, 3] of (Σg, Σh, Σw); of the class
+    trees of a round ([C, N] nodes, g, h), [C * K, F, B+1, 3], class-major.
 
     rw: optional [N] per-row count weight — the third channel becomes the
     weighted observation count (DHistogram Σw), so min_rows sees weighted
     counts under a weights_column. None keeps raw row counts."""
+    if nodes.ndim == 2:
+        # K class trees ([K, N] nodes, g, h): class k's node j is slot
+        # k * n_nodes + j of one scatter over the rows of every class
+        k = nodes.shape[0]
+        offset = n_nodes * jnp.arange(k, dtype=nodes.dtype)[:, None]
+        nodes = jnp.where(nodes >= 0, nodes + offset, -1).reshape(-1)
+        bins = jnp.tile(bins, (k, 1))
+        g, h = g.reshape(-1), h.reshape(-1)
+        rw = None if rw is None else jnp.tile(rw, k)
+        n_nodes = k * n_nodes
     n, F = bins.shape
     valid = nodes >= 0
     node = jnp.where(valid, nodes, 0)
@@ -419,7 +430,10 @@ def build_histogram_sharded(
     """Full distributed histogram: private scatter-add per shard, psum merge.
 
     bins:[N,F] int32 row-sharded; nodes:[N] int32 (-1 = inactive row);
-    g,h:[N] float32. bins_fm: optional feature-major [F, N] copy of bins
+    g,h:[N] float32; or nodes, g, h [K, N] of the K class trees of a round,
+    one histogram of their (class, node) slots, [K * n_nodes, ...]
+    class-major (``class_launches`` says how many launches it takes).
+    bins_fm: optional feature-major [F, N] copy of bins
     (already padded to the kernel row tile) — callers in a training loop pass
     it so the pallas path skips a per-call transpose. rw: optional [N]
     per-row count weight (weights_column: the count channel reports Σw).
@@ -441,7 +455,26 @@ def build_histogram_sharded(
     ), impl)
     out = _build_histogram_jit(
         bins, nodes, g, h, bins_fm, rw, k_pad, n_bins1, mesh, impl)
-    return out[:n_nodes] if k_pad != n_nodes else out
+    if k_pad == n_nodes:
+        return out
+    if nodes.ndim == 2:  # each class tree's real nodes
+        return out.reshape(nodes.shape[0], k_pad, *out.shape[1:])[:, :n_nodes].reshape(
+            -1, *out.shape[1:])
+    return out[:n_nodes]
+
+
+def class_launches(n_nodes: int, n_classes: int, impl: Optional[str] = None) -> int:
+    """Histogram launches of a level that builds ``n_nodes`` nodes in each of
+    ``n_classes`` class trees (``build_histogram_sharded`` with [K, N] node
+    ids): one, the scatter over every class's rows or the node-matmul kernel
+    over the (class, node) slots of all, while that kernel holds them
+    (``pallas_histogram._kernel_choice``); past it, one a class."""
+    if n_classes == 1 or _hist_impl(impl) != "pallas":
+        return 1
+    from h2o3_tpu.ops.pallas_histogram import _kernel_choice
+
+    one = _kernel_choice("auto", "auto", n_classes * pad_nodes(n_nodes))[0] == "nodematmul"
+    return 1 if one else n_classes
 
 
 def build_frontier_histogram_sharded(
@@ -531,10 +564,12 @@ def _build_histogram_jit(
     # into them; the check only exists to validate collective placement,
     # which the real-TPU path still enforces
     interpreted = impl == "pallas" and jax.default_backend() != "tpu"
+    # a row's node ids and channels: [N], or [K, N] of K class trees
+    row = P(DATA_AXIS) if nodes.ndim == 1 else P(None, DATA_AXIS)
     return _shard_map(
         fn,
         mesh=mesh,
-        in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS))
+        in_specs=(P(DATA_AXIS, None), row, row, row)
         + tuple(spec for _, _, spec in extras),
         out_specs=P(),
         check_vma=not interpreted,

@@ -148,6 +148,125 @@ def _nm_kernel(
         out_ref[...] = out_ref[...] + slab
 
 
+def _nm_classes_kernel(
+    jmod_ref, bins_ref, node_ref, vals_ref, out_ref, oh_ref, vk_ref, *,
+    n_feat_b, n_bins1, n_nodes, n_classes
+):
+    """``_nm_kernel`` for the K class trees of a boosting round: one grid
+    step = one (feature-block, row-tile), the tile's one-hot of codes built
+    ONCE and contracted against the node-masked values of every class tree.
+
+    node_ref: [K, R] int32, a row's node in each class tree (-1 inactive);
+    vals_ref: [3K, R], row ch * K + k the row's channel ch (Σg, Σh, Σw) of
+    class k; both lane-dense (rows in lanes). vk_ref: [3K*n, R] scratch,
+    row (3k + ch) * n + j = channel ch of class k where the row's node in
+    tree k is j, else 0; out_ref: [1, 3K*n, Fb*B1] f32 (revisited across
+    the row-tile grid dimension — accumulates in VMEM). The MXU's M
+    dimension is the K*n*3 (class, node, channel) slots: K times a tree's
+    at every level, in one launch."""
+    rt = pl.program_id(1)
+    dtype = oh_ref.dtype
+    binsb = bins_ref[...].astype(jnp.float32)  # [Fb, R]
+    jm = jmod_ref[...]  # [B1, 1] f32 iota constant
+    for f in range(n_feat_b):
+        oh_ref[f * n_bins1 : (f + 1) * n_bins1, :] = (
+            jm == binsb[f][None, :]
+        ).astype(dtype)
+
+    node = node_ref[...]  # [K, R]
+    # the select in float32, whose layout is the mask's; the stored operand
+    # is then cast (a bfloat16 select would relayout the mask)
+    vals = vals_ref[...].astype(jnp.float32)  # [3K, R]
+    at = jax.lax.broadcasted_iota(jnp.int32, (n_nodes, node.shape[1]), 0)
+    for k in range(n_classes):
+        mine = at == node[k][None, :]  # [n, R]; node < 0 never matches
+        for ch in range(3):
+            row = (3 * k + ch) * n_nodes
+            vk_ref[row : row + n_nodes, :] = jnp.where(
+                mine, vals[ch * n_classes + k][None, :], 0.0).astype(dtype)
+
+    # [3K*n, Fb*B1] = vk ⊗ onehotᵀ — contraction over the tile's rows
+    slab = jax.lax.dot_general(
+        vk_ref[...], oh_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )[None]
+
+    @pl.when(rt == 0)
+    def _():
+        out_ref[...] = slab
+
+    @pl.when(rt != 0)
+    def _():
+        out_ref[...] = out_ref[...] + slab
+
+
+def _build_classes_nodematmul(
+    bins, nodes, g, h, n_nodes: int, n_bins1: int,
+    row_tile: int, feat_block: int, interpret: bool, vma: tuple,
+    bins_fm=None, rw=None, dtype=jnp.float32,
+):
+    """The node-matmul histogram of K class trees in one launch: nodes, g, h
+    [K, N] class-major (the rows in lanes, no 128-lane padding). Returns
+    [K * n_nodes, F, n_bins1, 3] float32, class-major."""
+    n_classes, n = nodes.shape
+    n_feat = bins.shape[1]
+    r = row_tile
+    fb = min(feat_block, n_feat)
+    padf = (-n_feat) % fb
+    n_feat_p = n_feat + padf
+    if not (bins_fm is not None and bins_fm.shape == (n_feat_p, n) and n % r == 0):
+        if n % r:
+            pad = (-n) % r
+            bins = jnp.pad(bins, ((0, pad), (0, 0)))
+            nodes = jnp.pad(nodes, ((0, 0), (0, pad)), constant_values=-1)
+            g = jnp.pad(g, ((0, 0), (0, pad)))
+            h = jnp.pad(h, ((0, 0), (0, pad)))
+            if rw is not None:
+                rw = jnp.pad(rw, (0, pad))
+            n = n + pad
+        if padf:
+            bins = jnp.pad(bins, ((0, 0), (0, padf)))
+        bins_fm = bins.T
+
+    w = (nodes >= 0).astype(jnp.float32)
+    cw = w if rw is None else w * rw.astype(jnp.float32)[None, :]
+    # row ch * K + k: channel ch of class k, bf16 mode rounds the inputs.
+    # A concatenation: stacking the channels inside each class instead
+    # ([K, 3, N] reshaped to [3K, N]) took the TPU compiler 85 s at 4.9M rows
+    vals = jnp.concatenate(
+        [g.astype(jnp.float32) * w, h.astype(jnp.float32) * w, cw], axis=0
+    ).astype(dtype)
+
+    n_ftiles = n_feat_p // fb
+    slots = 3 * n_classes * n_nodes
+    jmod = jnp.asarray(np.arange(n_bins1)[:, None], dtype=jnp.float32)
+    if vma:
+        jmod = jax.lax.pcast(jmod, tuple(vma), to="varying")
+
+    out = pl.pallas_call(
+        partial(_nm_classes_kernel, n_feat_b=fb, n_bins1=n_bins1,
+                n_nodes=n_nodes, n_classes=n_classes),
+        grid=(n_ftiles, n // r),
+        in_specs=[
+            pl.BlockSpec((n_bins1, 1), lambda f, t: (0, 0)),
+            pl.BlockSpec((fb, r), lambda f, t: (f, t)),
+            pl.BlockSpec((n_classes, r), lambda f, t: (0, t)),
+            pl.BlockSpec((3 * n_classes, r), lambda f, t: (0, t)),
+        ],
+        scratch_shapes=[pltpu.VMEM((fb * n_bins1, r), dtype),
+                        pltpu.VMEM((slots, r), dtype)],
+        out_specs=pl.BlockSpec((1, slots, fb * n_bins1), lambda f, t: (f, 0, 0)),
+        out_shape=_out_sds((n_ftiles, slots, fb * n_bins1), jnp.float32, vma),
+        interpret=interpret,
+    )(jmod, bins_fm, nodes, vals)
+
+    # [Ft, (K, 3, n), Fb*B1] -> [K*n, F, B1, 3]
+    out = out.reshape(n_ftiles, n_classes, 3, n_nodes, fb, n_bins1)
+    out = jnp.transpose(out, (1, 3, 0, 4, 5, 2)).reshape(
+        n_classes * n_nodes, n_feat_p, n_bins1, 3)
+    return out[:, :n_feat]
+
+
 def _build_histogram_nodematmul(
     bins, nodes, g, h, n_nodes: int, n_bins1: int,
     row_tile: int, feat_block: int, interpret: bool, vma: tuple,
@@ -583,7 +702,22 @@ def build_histogram_pallas(
     way; bf16 rounds g/h/w inputs to 8 mantissa bits, accumulation stays
     f32). ``_kernel_choice`` resolves both.
     Returns [n_nodes, F, n_bins1, 3] float32 of (Σg, Σh, Σw).
+
+    K class trees: nodes, g, h [K, N], a row's node and channels in each
+    tree; returns [K * n_nodes, F, n_bins1, 3], class-major. While the
+    node-matmul kernel holds the K * n_nodes slots (``_kernel_choice``) they
+    are ONE launch (``_build_classes_nodematmul``), past it a launch a class
+    (``histogram.class_launches`` states which).
     """
+    if nodes.ndim == 2:
+        # K class trees: one launch over their (class, node) slots while the
+        # node-matmul kernel holds them all, else a launch a class
+        n_classes = nodes.shape[0]
+        if _kernel_choice(kernel, dtype, n_classes * n_nodes)[0] != "nodematmul":
+            return jnp.concatenate([build_histogram_pallas(
+                bins, nodes[c], g[c], h[c], n_nodes, n_bins1, row_tile, interpret,
+                vma, kernel, bins_fm, rw, dtype) for c in range(n_classes)])
+        n_nodes = n_classes * n_nodes
     kernel, dtype = _kernel_choice(kernel, dtype, n_nodes)
     # the scope sits OUTSIDE the jit: XLA names the kernel's custom-call
     # after the innermost component of its name stack, and the benchmark's
@@ -606,6 +740,16 @@ def _build_histogram_pallas_jit(
     row_tile, interpret: bool, vma: tuple,
     kernel: str, bins_fm, rw, dtype: str,
 ):
+    if nodes.ndim == 2:
+        # K class trees (``build_histogram_pallas``): n_nodes counts the
+        # slots of all of them
+        kernel, dtype = _kernel_choice(kernel, dtype, n_nodes)
+        return _build_classes_nodematmul(
+            bins, nodes, g, h, n_nodes // nodes.shape[0], n_bins1,
+            row_tile=row_tile or _ROW_TILE, feat_block=_FEAT_BLOCK,
+            interpret=interpret, vma=vma, bins_fm=bins_fm, rw=rw,
+            dtype=_DTYPES[dtype],
+        )
     kernel, dtype = _kernel_choice(kernel, dtype, n_nodes)
     if kernel == "nodematmul":
         return _build_histogram_nodematmul(

@@ -163,3 +163,42 @@ def test_training_block_fits_one_chip(v5e, capsys):
     # 128 lanes they made 14,112,391,680 bytes of temporaries at this very
     # row count (ROADMAP S4b), 1,580,557,312 since
     assert mem.temp_size_in_bytes < 2 * 10**9
+
+
+def test_a_round_of_five_class_trees_is_one_kernel_a_level(v5e, capsys):
+    """The KDD cell's block (``gbm-kdd99-5class-d5``): 5 class trees a round
+    at depth 5, 41 features, a bin axis of 71, 4 rounds. A
+    level of the 5 trees is ONE node-matmul kernel (5 in the round's program;
+    a launch a class tree a level made 25), and the class-major state keeps
+    the temporaries near a binomial block's, not 5 x [N, 1]-padded rows:
+    compiled at 2,000,384 rows."""
+    from h2o3_tpu.models.tree import booster
+
+    mesh = _mesh(v5e, 1)
+    n, f, fp = 2_000_384, 41, 48
+    row = NamedSharding(mesh, P(DATA_AXIS))
+    row2 = NamedSharding(mesh, P(DATA_AXIS, None))
+    S = jax.ShapeDtypeStruct
+    fn = booster._make_block_fn(
+        "multinomial", 5, 4,
+        booster.TreeParams(ntrees=0, max_depth=5, learn_rate=0.1, nbins=20,
+                           min_rows=10.0, reg_lambda=0.0, seed=0,
+                           cat_levels=(0, 3, 70, 11) + (0,) * 37,
+                           max_abs_leaf=booster.MULTINOMIAL_LEAF_MAX),
+        mesh, subtract=True)
+    compiled = fn.lower(
+        S((n, f), jnp.int32, sharding=row2), S((n,), jnp.float32, sharding=row),
+        S((n,), jnp.bool_, sharding=row), S((n, 5), jnp.float32, sharding=row2),
+        S((4, 2), jnp.uint32, sharding=NamedSharding(mesh, P())),
+        S((fp, n), jnp.int32, sharding=NamedSharding(mesh, P(None, DATA_AXIS))),
+        None, None).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[tpu-compile] 5-class block {n}x{f}, 4 rounds depth 5: temp "
+              f"{mem.temp_size_in_bytes:,} bytes ({mem.temp_size_in_bytes / n:.0f} a row)")
+    # 1,285,899,264 B at 4,898,816 rows for the same block (262 a row, with
+    # ~0.55 GB of split-search temporaries that do not grow with the rows);
+    # the parent's [N, 5] state took 5,347,346,944 B (1,092 a row)
+    assert mem.temp_size_in_bytes < 1.5 * 10**9

@@ -476,11 +476,14 @@ def numeric_block_text(objective, C, block, p, impl, subtract, monkeypatch, n=10
 #: mtries), and ``_predict_stacked`` as ``programs.build_scoring_programs``
 #: lowers it. The DRF block's was recorded again when a node's mtries draw
 #: came to be keyed by its heap id (``booster._node_candidates``): the one
-#: thing that draw changed in its program
+#: thing that draw changed in its program. Its two class trees a round came
+#: to be grown as one level program (``booster._grow_trees``), so it was
+#: recorded once more then; a block of one class tree a round is held to the
+#: parent of that change in ``tests/test_tree_classes.py``
 PARENT_PROGRAMS = {
     "block_scatter": "3a50c57ac37cb62af4195df6a9fb9e1a45e227b50424d43b9a14542bb9c4a396",
     "block_pallas_subtract": "dde7eaf032dd91a0b2a2101646c6cdac1d5e0f0524496e52a4e7c6434c61072e",
-    "block_drf": "4ab0943d87427e2f923a175aa20d9a1f7606a5c19e9d71c4691218c8ecaa2eb2",
+    "block_drf": "fa4e2263fc7a1aed789507287a64510fa0762a9aadf7e04873909b63910f0ca6",
     "predict_stacked": "65fd78c589b300f01a4dcaa7e757aab45a4deccda6f745f4d125e892cf5d66db",
     # a sorted level (256 node slots), which no block above reaches at depth 3:
     # recorded from commit b201c72, before the level plan moved into one place
